@@ -283,21 +283,15 @@ def _pallas_vmem(eqn) -> dict:
     """Block shapes x dtype + scratch operands of one ``pallas_call`` —
     the VMEM residency its grid steps pin (double buffering and compiler
     temporaries ride on top; the cap leaves that headroom)."""
+    from .kernel_contracts import _block_steps, _kernel_name
     from .rules import _where
 
     gm = eqn.params.get("grid_mapping")
-    name = ""
-    nsi = eqn.params.get("name_and_src_info")
-    if nsi is not None:
-        name = getattr(nsi, "name", "") or str(nsi)
+    name = _kernel_name(eqn)
     block_bytes = 0
     for bm in getattr(gm, "block_mappings", ()) or ():
-        shape = tuple(int(d) if isinstance(d, int) else 1
-                      for d in (bm.block_shape or ()))
-        try:
-            itemsize = bm.array_shape_dtype.dtype.itemsize
-        except Exception:
-            itemsize = 4
+        shape = _block_steps(bm)
+        itemsize = bm.array_aval.dtype.itemsize
         block_bytes += int(np.prod(shape, dtype=np.int64)) * itemsize
     scratch_bytes = 0
     n_scratch = int(getattr(gm, "num_scratch_operands", 0) or 0)

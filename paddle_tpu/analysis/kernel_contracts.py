@@ -116,9 +116,9 @@ def _pallas_eqns(closed):
 
 
 def _kernel_name(eqn) -> str:
-    nsi = eqn.params.get("name_and_src_info")
-    name = getattr(nsi, "name", "") or (str(nsi) if nsi is not None else "")
-    return name or "<unnamed>"
+    """The ``name=`` given to pallas_call, else the kernel function's."""
+    return (eqn.params.get("name")
+            or eqn.params["jaxpr"].debug_info.func_name or "<unnamed>")
 
 
 def _dim_semantics(eqn, ngrid: int) -> tuple:
@@ -127,16 +127,13 @@ def _dim_semantics(eqn, ngrid: int) -> tuple:
     (sequential) — the conservative direction for the race check: a
     revisit on an undeclared axis is judged by the consecutive-run rule,
     not condemned as a parallel race."""
-    cp = eqn.params.get("compiler_params") or {}
-    sem = None
-    mosaic = cp.get("mosaic") if isinstance(cp, dict) else None
-    if isinstance(mosaic, dict):
-        sem = mosaic.get("dimension_semantics")
-    elif mosaic is not None:
-        sem = getattr(mosaic, "dimension_semantics", None)
+    # pallas_call(compiler_params=pltpu.CompilerParams(...)) arrives keyed
+    # by backend: {'mosaic_tpu': CompilerParams}
+    mosaic = (eqn.params.get("compiler_params") or {}).get("mosaic_tpu")
+    sem = getattr(mosaic, "dimension_semantics", None)
     if sem is None:
         return ("arbitrary",) * ngrid
-    sem = tuple(str(s) for s in sem)
+    sem = tuple(getattr(s, "value", None) or str(s) for s in sem)
     return sem + ("arbitrary",) * (ngrid - len(sem))
 
 
@@ -231,9 +228,8 @@ def _eval_index_map(bm, pts: np.ndarray, prefetch_vals):
 def _block_steps(bm):
     """Per-dim (step, extent-valid?) multipliers: a Blocked dim's index is
     in block units (element offset = idx * size); squeezed/mapped dims
-    (non-int block entries) index single elements (step 1)."""
-    return tuple(int(d) if isinstance(d, int) else 1
-                 for d in (bm.block_shape or ()))
+    (entries without a ``block_size``) index single elements (step 1)."""
+    return tuple(int(getattr(d, "block_size", 1)) for d in bm.block_shape)
 
 
 def _operand_label(bms, k: int, n_inputs: int) -> str:
@@ -305,7 +301,7 @@ def _check_bounds(kname, where, target, label, bm, vname, idx, pts,
     or None.  Blocked dims: block index b is valid iff 0 <= b and
     b * block_size < dim (partial edge blocks are legal — pallas pads)."""
     steps = _block_steps(bm)
-    shape = tuple(getattr(bm.array_shape_dtype, "shape", ()))
+    shape = tuple(bm.array_aval.shape)
     # rank agreement is guaranteed by the caller: _verify_eqn pre-filters
     # rank-mismatched operands into the eval_failed/'unchecked' path
     # before this runs, and _eval_index_map emits exactly
@@ -328,7 +324,7 @@ def _check_bounds(kname, where, target, label, bm, vname, idx, pts,
         message=(f"pallas kernel {kname}: index map of {label} leaves the "
                  f"operand at grid point {pt}: block index "
                  f"{tuple(int(x) for x in idx[r])} x block "
-                 f"{tuple(bm.block_shape)} exceeds operand shape "
+                 f"{_block_steps(bm)} exceeds operand shape "
                  f"{shape} on axis {d}{via}"),
         where=where, target=target)
 
@@ -431,13 +427,13 @@ def _check_alias_pair(kname, where, target, eqn, gm, bms, gi, oj,
                      f"to {a_out.str_short()} — in-place write through a "
                      f"different shape/dtype corrupts the buffer"),
             where=where, target=target))
-    if tuple(bm_in.block_shape) != tuple(bm_out.block_shape):
+    if _block_steps(bm_in) != _block_steps(bm_out):
         findings.append(Finding(
             rule="kernel_alias", severity=Severity.ERROR,
             message=(f"pallas kernel {kname}: alias pair {in_label} -> "
                      f"{out_label} block geometry drifted: input blocks "
-                     f"{tuple(bm_in.block_shape)} vs output blocks "
-                     f"{tuple(bm_out.block_shape)} — the in-place write "
+                     f"{_block_steps(bm_in)} vs output blocks "
+                     f"{_block_steps(bm_out)} — the in-place write "
                      f"lands on different elements than the read fetched"),
             where=where, target=target))
         return findings
@@ -576,7 +572,7 @@ def _verify_eqn(eqn, target: str, cap: int):
         if k in eval_failed:
             continue
         steps = _block_steps(bm)
-        shape = tuple(getattr(bm.array_shape_dtype, "shape", ()))
+        shape = tuple(bm.array_aval.shape)
         if len(steps) != len(shape):
             findings.append(Finding(
                 rule="kernel_bounds", severity=Severity.INFO,

@@ -51,6 +51,7 @@ LOW_PRECISION = {"bfloat16", "float16", "int8", "uint8", "int4", "uint4",
 _MXU_PRIMS = {"dot_general", "conv_general_dilated", "ragged_dot"}
 # host-synchronizing primitives (callback family + infeed/outfeed)
 _HOST_SYNC_PRIMS = {"pure_callback", "io_callback", "debug_callback",
+                    "debug_print",
                     "callback", "infeed", "outfeed"}
 # control-flow primitives that define "inside a hot loop"
 _LOOP_PRIMS = {"scan", "while", "fori"}
@@ -76,17 +77,16 @@ def _sub_jaxprs(eqn):
 
 
 def _where(eqn) -> str:
-    """``file.py:line (fn)`` provenance of an eqn, best-effort."""
-    try:
-        from jax._src import source_info_util
+    """``file.py:line (fn)`` provenance of an eqn."""
+    from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
-            return f"{frame.file_name.split('/')[-1]}:{frame.start_line} " \
-                   f"({frame.function_name})"
-        return source_info_util.summarize(eqn.source_info)
-    except Exception:
-        return ""
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is not None:
+        # jax reports the qualified name (outer.<locals>.fn); the allowlist
+        # matches on the bare one
+        fn = frame.function_name.rsplit(".", 1)[-1]
+        return f"{frame.file_name.split('/')[-1]}:{frame.start_line} ({fn})"
+    return source_info_util.summarize(eqn.source_info)
 
 
 def _aval(var):
@@ -112,10 +112,10 @@ def _leaf_paths(args) -> list[str]:
 
 
 def _unwrap_pjit(closed):
-    """If the traced fn was itself jit-wrapped, the whole program is one pjit
+    """If the traced fn was itself jit-wrapped, the whole program is one jit
     eqn: descend into it and surface its donation/sharding metadata."""
     jaxpr = closed.jaxpr
-    body_eqns = [e for e in jaxpr.eqns if e.primitive.name == "pjit"]
+    body_eqns = [e for e in jaxpr.eqns if e.primitive.name == "jit"]
     if len(jaxpr.eqns) == 1 and body_eqns:
         eqn = body_eqns[0]
         return eqn.params["jaxpr"], eqn.params.get("donated_invars")
@@ -478,7 +478,7 @@ def _mesh_devices_of(closed, args=()) -> int:
     best = 1
     jaxpr = closed.jaxpr
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name == "jit":
             for sh in tuple(eqn.params.get("in_shardings") or ()) + \
                     tuple(eqn.params.get("out_shardings") or ()):
                 mesh = getattr(sh, "mesh", None)

@@ -408,7 +408,7 @@ def _t_serving_tp_step() -> AnalysisTarget:
     if jax.device_count() < 2:
         # RuntimeError, not SystemExit: lint_gate.py's per-target handler
         # must classify this as "FAILED to build/trace" (exit 2) instead
-        # of the exception tunneling past it — both CLI entry points force
+        # of the exception escaping past it — both CLI entry points force
         # an 8-device host platform pre-init, so this only fires when the
         # backend initialized single-device before the gate ran
         raise RuntimeError(

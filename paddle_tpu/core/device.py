@@ -54,10 +54,11 @@ def _parse(device: str) -> jax.Device:
         platform, idx = device, 0
     if platform == "gpu":  # accepted for script compatibility
         platform = "tpu"
-    devs = [d for d in jax.devices() if d.platform.startswith(platform)]
-    if not devs:
-        devs = jax.devices()  # fall back to whatever exists (e.g. cpu-only CI)
-    return devs[min(idx, len(devs) - 1)]
+    devs = [d for d in jax.devices() if d.platform == platform]
+    if not 0 <= idx < len(devs):
+        have = sorted({f"{d.platform}:{d.id}" for d in jax.devices()})
+        raise ValueError(f"no device {device!r} here (visible: {have})")
+    return devs[idx]
 
 
 def set_device(device: str) -> Place:
@@ -97,10 +98,7 @@ def is_compiled_with_tpu() -> bool:
 
 def memory_stats(device: jax.Device | None = None) -> dict:
     d = device or current_device()
-    try:
-        return dict(d.memory_stats() or {})
-    except Exception:
-        return {}
+    return dict(d.memory_stats() or {})     # the CPU backend reports None
 
 
 def max_memory_allocated(device=None) -> int:
@@ -124,11 +122,8 @@ def memory_reserved(device=None) -> int:
 
 
 def empty_cache() -> None:
-    """Best-effort allocator release (XLA owns the allocator; no-op if unsupported)."""
-    try:
-        jax.clear_caches()
-    except Exception:
-        pass
+    """Drop jax's compiled-program caches (XLA owns the allocator itself)."""
+    jax.clear_caches()
 
 
 def synchronize(device=None) -> None:

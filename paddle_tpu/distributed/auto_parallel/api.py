@@ -70,7 +70,7 @@ def shard_tensor(data, mesh: ProcessMesh, placements=None, dtype=None, place=Non
     sharding = NamedSharding(mesh.jax_mesh, spec)
     if not isinstance(v, jax.core.Tracer):
         v = jax.device_put(v, sharding)
-    elif hasattr(jax.lax, "with_sharding_constraint"):
+    else:
         v = jax.lax.with_sharding_constraint(v, sharding)
     if isinstance(t, Parameter):
         out = t

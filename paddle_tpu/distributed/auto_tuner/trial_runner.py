@@ -84,7 +84,7 @@ def make_llama_trial_runner(model_cfg=None, seq: int = 64,
                 jnp.asarray(rs.randint(0, cfg.vocab_size, (batch, seq))), dshard)
             for _ in range(max(1, warmup)):  # >=1: compile must stay untimed
                 loss, params, opt_state = step_fn(params, opt_state, ids, labels)
-            float(loss)  # host fetch = the only reliable barrier on the relay
+            float(loss)  # host fetch = barrier (compile stays untimed)
             n_steps = max(1, steps)
             t0 = time.perf_counter()
             for _ in range(n_steps):

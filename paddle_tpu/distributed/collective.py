@@ -606,7 +606,7 @@ def batch_isend_irecv(p2p_op_list):
 
 
 def barrier(group=None):
-    jax.effects_barrier() if hasattr(jax, "effects_barrier") else None
+    jax.effects_barrier()
     for d in jax.local_devices():
         jax.device_put(jnp.zeros(()), d).block_until_ready()
 

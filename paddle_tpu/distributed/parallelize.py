@@ -32,7 +32,7 @@ class SplitPoint(Enum):
 
 
 class ParallelMode:
-    """Parallelism taxonomy constants (reference:
+    """Parallelism classification constants (reference:
     auto_parallel/static/operators/common.py:64)."""
     DataParallel = "auto_parallel/data_parallel"
     TensorParallel = "auto_parallel/tensor_parallel"

@@ -1,5 +1,5 @@
 """Bijective transforms (reference: python/paddle/distribution/transform.py
-— Transform taxonomy with forward/inverse/log_det_jacobian, consumed by
+— Transform hierarchy with forward/inverse/log_det_jacobian, consumed by
 TransformedDistribution)."""
 
 from __future__ import annotations

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..ops import pallas
 from ..ops.pallas import flash_attention as fa
 from ..ops.pallas import rms_norm as rms
 from ..ops.pallas import rope as rope_mod
@@ -728,7 +729,21 @@ def build_train_step(cfg: LlamaConfig, mesh: Mesh, lr=3e-4, weight_decay=0.1,
                   if schedule in ("vpp", "interleave") else 1)
 
     def train_step(params, opt_state, input_ids, labels):
-        if use_1f1b:
+        if pp == 1 and sep == 1:
+            # plain GSPMD program: Mosaic kernels cannot be partitioned
+            # automatically, they run per shard (ops.pallas.spmd_kernels)
+            def lfn(p):
+                # the first kernel wants its rows split by batch: gather the
+                # embedding's ZeRO-split hidden dim BEFORE the lookup (what
+                # ZeRO does anyway) instead of leaving the partitioner to
+                # move 'sharding' from hidden to batch on the activations
+                embed = jax.lax.with_sharding_constraint(
+                    p["embed"], NamedSharding(mesh, P("mp", None)))
+                return loss_fn(cfg, dict(p, embed=embed), input_ids, labels)
+
+            with pallas.spmd_kernels(mesh, ("dp", "sharding"), "mp"):
+                loss, grads = jax.value_and_grad(lfn)(params)
+        elif use_1f1b:
             loss, grads = loss_and_grads_1f1b(cfg, params, input_ids, labels,
                                               mesh, num_microbatches,
                                               num_chunks=vpp_chunks,

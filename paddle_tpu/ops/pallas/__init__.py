@@ -13,19 +13,56 @@ through XLA.  Each kernel here:
 
 from __future__ import annotations
 
+import contextlib
+
 import jax
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    # a backend that fails to initialize raises here: trouble with the
+    # device must never turn the kernels into their interpreter quietly
+    return jax.default_backend() == "tpu"
 
 
 def interpret_mode() -> bool:
     """Pallas interpret=True off-TPU so kernels stay testable on CPU CI."""
     return not on_tpu()
+
+
+# GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+# automatically partitioned. Please wrap the call in a shard_map"): a jitted
+# program for a mesh of more than one device has to run each kernel per
+# shard.  The program that owns the mesh (models/llama.build_train_step)
+# traces under ``spmd_kernels`` and the kernel entries (rms_norm,
+# flash_attention_bshd) wrap their launch with ``per_shard``.  Serving's TP
+# path is already one explicit shard_map region and never sets this.
+_SPMD = None    # (mesh, batch_axes, head_axis) while such a program traces
+
+
+@contextlib.contextmanager
+def spmd_kernels(mesh, batch_axes, head_axis):
+    """While tracing inside: activations are split over ``batch_axes`` on
+    their leading (batch) dim and over ``head_axis`` on their heads dim."""
+    global _SPMD
+    prev = _SPMD
+    _SPMD = (mesh, batch_axes, head_axis) if mesh.size > 1 else None
+    try:
+        yield
+    finally:
+        _SPMD = prev
+
+
+def per_shard(fn, specs_of):
+    """``fn`` as it must be launched: itself on one device, or inside a
+    ``shard_map`` over the ``spmd_kernels`` mesh with the
+    ``(in_specs, out_specs)`` that ``specs_of(batch_axes, head_axis)``
+    gives."""
+    if _SPMD is None:
+        return fn
+    mesh, batch_axes, head_axis = _SPMD
+    in_specs, out_specs = specs_of(batch_axes, head_axis)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # the full opt-out vocabulary: every kernel_disabled() dispatch site in the
@@ -55,10 +92,9 @@ def kernel_disabled(name: str) -> bool:
     """Operational escape hatch: route around a Pallas kernel at runtime.
 
     ``PADDLE_TPU_DISABLE_PALLAS="flash_attention,rms_norm"`` (or ``"all"``)
-    switches the named kernels to their XLA-composed fallbacks.  bench.py's
-    kernel probe sets this when a kernel fails to compile standalone, so a
-    Mosaic regression in one kernel degrades throughput instead of hanging
-    the whole measurement.  Values outside :data:`KNOWN_KERNELS` warn once
+    switches the named kernels to their XLA-composed fallbacks — an
+    explicit operator opt-out; nothing in the repo sets it on its own.
+    Values outside :data:`KNOWN_KERNELS` warn once
     (typo guard) but are still honored as opt-outs.  The queried ``name``
     is always accepted as known — a future kernel that guards itself with
     ``kernel_disabled("new_kernel")`` must not make its own legitimate

@@ -38,15 +38,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu only resolves on TPU-capable installs
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import tpu as pltpu
 
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.sharding import PartitionSpec as P
 
-from . import interpret_mode, kernel_disabled
+from . import interpret_mode, kernel_disabled, per_shard
+
+_VMEM = pltpu.VMEM
 
 NEG_INF = -1e30
 
@@ -284,9 +282,7 @@ def _flash_fwd(q, k, v, scale, causal, *, rep=1, kv_len=None, mask=None,
             _VMEM((bq_sz, 1), jnp.float32),
             _VMEM((bq_sz, 1), jnp.float32),
             _VMEM((bq_sz, d), jnp.float32),
-        ]
-        if _VMEM is not None
-        else [],
+        ],
         interpret=interpret_mode(),
     )(q, k, v, *opt_arrays)
     return out, lse[..., 0]
@@ -422,8 +418,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, *, rep=1, kv_len=None,
         out_shape=[jax.ShapeDtypeStruct((bhkv, skv, d), k.dtype),
                    jax.ShapeDtypeStruct((bhkv, skv, d), v.dtype)],
         scratch_shapes=[_VMEM((bkv_sz, d), jnp.float32),
-                        _VMEM((bkv_sz, d), jnp.float32)]
-        if _VMEM is not None else [],
+                        _VMEM((bkv_sz, d), jnp.float32)],
         interpret=interpret_mode(),
     )(q, k, v, do, lse3, delta, *opt_arrays)
 
@@ -442,8 +437,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, *, rep=1, kv_len=None,
                   row_spec_i, *opt_specs_q],
         out_specs=[q_spec_i],
         out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype)],
-        scratch_shapes=[_VMEM((bq_sz, d), jnp.float32)]
-        if _VMEM is not None else [],
+        scratch_shapes=[_VMEM((bq_sz, d), jnp.float32)],
         interpret=interpret_mode(),
     )(q, k, v, do, lse3, delta, *opt_arrays_q)
     return dq, dk, dv
@@ -561,11 +555,26 @@ def flash_attention_bshd(q, k, v, attn_mask=None, causal=False, scale=None,
     (a [b, s] int array, or a (q_ids, kv_ids) pair) implements packed/varlen
     attention (reference: flash_attn_varlen cu_seqlens).  Arbitrary sequence
     lengths are padded to the block grid and masked in-kernel."""
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
+    if attn_mask is None and segment_ids is None:
+        # on a mesh of several devices (ops.pallas.spmd_kernels) every
+        # (batch, head) pair is independent: each device attends its own
+        # batch slice and head group
+        bshd = lambda batch, heads: P(batch, None, heads, None)
+        return per_shard(
+            functools.partial(_flash_attention_bshd, causal=causal,
+                              scale=scale),
+            lambda b, h: ((bshd(b, h),) * 3, bshd(b, h)))(q, k, v)
+    return _flash_attention_bshd(q, k, v, attn_mask, causal, scale,
+                                 segment_ids)
+
+
+def _flash_attention_bshd(q, k, v, attn_mask=None, causal=False, scale=None,
+                          segment_ids=None):
     b, sq, hq, d = q.shape
     hkv = k.shape[2]
     skv = k.shape[1]
-    if scale is None:
-        scale = 1.0 / math.sqrt(d)
     global KERNEL_CALLS, FALLBACK_CALLS
     if d % 8 != 0 or hq % hkv != 0 or kernel_disabled("flash_attention"):
         FALLBACK_CALLS += 1

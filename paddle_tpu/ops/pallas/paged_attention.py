@@ -147,15 +147,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu only resolves on TPU-capable installs
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from . import interpret_mode, kernel_disabled
+
+_VMEM = pltpu.VMEM
 
 NEG_INF = -1e30
 
@@ -219,17 +215,30 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
+def kernel_shape_problem(num_heads: int, num_kv_heads: int, head_dim: int,
+                         block_size: int) -> str | None:
+    """Why the paged kernel family cannot take these shapes (None: it can).
+    The serving engine names this when it has to take the gather reference
+    on a TPU."""
+    if head_dim % 8:
+        return f"head_dim={head_dim} is not a multiple of 8"
+    if block_size % 8:
+        return f"block_size={block_size} is not a multiple of 8"
+    if num_heads % num_kv_heads:
+        return (f"num_heads={num_heads} is not a multiple of "
+                f"num_kv_heads={num_kv_heads}")
+    return None
+
+
 def kernel_supported(num_heads: int, num_kv_heads: int, head_dim: int,
                      block_size: int) -> bool:
-    """Trace-time dispatch predicate: shapes the kernel handles, pltpu
-    availability, AND the operational opt-out.  The single home of the
-    decision — callers (the CB engine, the op layer) consult this once at
-    trace time, so a hung Mosaic compile can be routed around via
+    """Trace-time dispatch predicate: shapes the kernel handles AND the
+    operational opt-out.  The single home of the decision — callers (the
+    CB engine, the op layer) consult this once at trace time, so a hung
+    Mosaic compile can be routed around via
     ``PADDLE_TPU_DISABLE_PALLAS=paged_attention`` without a redeploy."""
-    return (_VMEM is not None
-            and head_dim % 8 == 0
-            and block_size % 8 == 0
-            and num_heads % num_kv_heads == 0
+    return (kernel_shape_problem(num_heads, num_kv_heads, head_dim,
+                                 block_size) is None
             and not kernel_disabled("paged_attention"))
 
 
@@ -297,20 +306,33 @@ def _quant_encode_page(x, kv_quant: str):
     fused kernel's in-register requantize both call it, which is what
     makes the two arms byte-identical by construction rather than by
     tolerance."""
+    codes, scale = _quant_encode_page_tile(x, kv_quant)
+    return codes, scale[..., 0, 0]
+
+
+def _quant_encode_page_tile(x, kv_quant: str):
+    """:func:`_quant_encode_page` with the scale left as a ``[..., 1, 1]``
+    tile — the form a kernel body can keep in vector registers and
+    broadcast back over the page."""
     bound = _QUANT_BOUND[kv_quant]
-    absmax = jnp.max(jnp.abs(x), axis=(-2, -1))
+    absmax = jnp.max(jnp.abs(x), axis=(-2, -1), keepdims=True)
     scale = (absmax / bound).astype(jnp.float32)
-    q = jnp.clip(jnp.round(x / jnp.maximum(scale, 1e-10)[..., None, None]),
-                 -bound, bound)
+    q = jnp.clip(jnp.round(x / jnp.maximum(scale, 1e-10)), -bound, bound)
     if kv_quant == "int8":
         return q.astype(jnp.int8), scale
     # pack adjacent head-dim pairs two-nibbles-per-byte (element 2i low,
-    # 2i+1 high — quantize_kv_cache's layout, inverted by _unpack_int4);
-    # expressed as a reshape+index rather than strided slices so the same
-    # expression lowers inside a Pallas kernel body
-    qi = q.astype(jnp.int32)
-    pairs = qi.reshape(*qi.shape[:-1], qi.shape[-1] // 2, 2)
-    packed = (pairs[..., 0] & 0xF) | ((pairs[..., 1] & 0xF) << 4)
+    # 2i+1 high — quantize_kv_cache's layout, inverted by _unpack_int4).
+    # Mosaic lowers neither a lane-strided slice nor the [.., hd/2, 2]
+    # reshape, so the even/odd lanes are compacted by a dot with a 0/1
+    # selection matrix — exact at any matmul precision (codes are integers
+    # in [-7, 7]) and the same expression in the XLA scatter arm
+    hd = q.shape[-1]
+    src = jax.lax.broadcasted_iota(jnp.int32, (hd, hd // 2), 0)
+    dst = jax.lax.broadcasted_iota(jnp.int32, (hd, hd // 2), 1)
+    lo, hi = (jnp.dot(q, (src == 2 * dst + odd).astype(jnp.float32),
+                      preferred_element_type=jnp.float32).astype(jnp.int32)
+              for odd in (0, 1))
+    packed = (lo & 0xF) | ((hi & 0xF) << 4)
     return packed.astype(jnp.int8), scale
 
 
@@ -420,6 +442,7 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
         rest = rest[2:]
     o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
+    h = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -435,9 +458,11 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(j * bs < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                   # [group, hd]
-        k = _dequant_page(k_ref[0, 0], ks_ref[0, 0] if kv_quant else None,
+        k = _dequant_page(k_ref[0, 0],
+                          _head_scale(ks_ref, h) if kv_quant else None,
                           kv_quant)                           # [bs, hd]
-        v = _dequant_page(v_ref[0, 0], vs_ref[0, 0] if kv_quant else None,
+        v = _dequant_page(v_ref[0, 0],
+                          _head_scale(vs_ref, h) if kv_quant else None,
                           kv_quant)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -491,11 +516,32 @@ def _page_index_map(bs: int, num_blocks: int):
     return idx
 
 
-def _scale_index_map(bs: int, num_blocks: int):
-    def idx(b, h, j, tables_ref, lens_ref):
-        return (_resolve_page(b, j, tables_ref, lens_ref, bs, num_blocks), h)
+def _scale_operand(scale):
+    """Per-(page, head) scales ``[nb, nkv]`` as the kernels take them:
+    ``[nb, 1, nkv]`` f32.  A one-scale block ``(1, 1)`` of the 2-d array
+    is refused by the TPU tiling (a block's last two dims must be (8, 128)
+    multiples or the array's own); a page's whole head row ``(1, 1, nkv)``
+    of this view is legal, and the view is the same bytes in HBM."""
+    return scale.astype(jnp.float32)[:, None, :]
 
-    return idx
+
+def _scale_spec(nkv: int, page_index_map):
+    """BlockSpec for a :func:`_scale_operand`: the head row of the page the
+    payload's own index map resolves — codes and scale cannot diverge."""
+    return pl.BlockSpec((1, 1, nkv),
+                        lambda *a: (page_index_map(*a)[0], 0, 0))
+
+
+def _head_lane(row_shape, h):
+    return jax.lax.broadcasted_iota(jnp.int32, row_shape, 1) == h
+
+
+def _head_scale(sc_ref, h):
+    """Head ``h``'s scale out of a ``(1, 1, nkv)`` scale block, as a
+    ``[1, 1]`` tile that broadcasts over the page."""
+    row = sc_ref[0]                                       # [1, nkv]
+    return jnp.sum(jnp.where(_head_lane(row.shape, h), row, 0.0), axis=-1,
+                   keepdims=True)
 
 
 def _paged_attention_kernel_call(q, key_cache, value_cache, block_tables,
@@ -517,9 +563,9 @@ def _paged_attention_kernel_call(q, key_cache, value_cache, block_tables,
     ]
     args = [q, key_cache, value_cache]
     if kv_quant:
-        sc_spec = pl.BlockSpec((1, 1), _scale_index_map(bs, num_blocks))
+        sc_spec = _scale_spec(nkv, _page_index_map(bs, num_blocks))
         in_specs += [sc_spec, sc_spec]
-        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        args += [_scale_operand(k_scale), _scale_operand(v_scale)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -606,6 +652,7 @@ def _flash_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
         rest = rest[2:]
     m_ref, l_ref, acc_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
+    h = pl.program_id(1)
     s_id = pl.program_id(2)
     p = pl.program_id(3)
     j = s_id * pages_per_shard + p                        # logical page
@@ -621,9 +668,11 @@ def _flash_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(j * bs < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)               # [group, hd]
-        k = _dequant_page(k_ref[0, 0], ks_ref[0, 0] if kv_quant else None,
+        k = _dequant_page(k_ref[0, 0],
+                          _head_scale(ks_ref, h) if kv_quant else None,
                           kv_quant)                       # [bs, hd]
-        v = _dequant_page(v_ref[0, 0], vs_ref[0, 0] if kv_quant else None,
+        v = _dequant_page(v_ref[0, 0],
+                          _head_scale(vs_ref, h) if kv_quant else None,
                           kv_quant)
         _online_softmax_update(q, k, v, j, bs, length, m_scr, l_scr,
                                acc_scr, scale)
@@ -643,14 +692,6 @@ def _flash_page_index_map(bs: int, num_blocks: int, pages_per_shard: int):
         j = s * pages_per_shard + p
         return (_resolve_page(b, j, tables_ref, lens_ref, bs, num_blocks),
                 h, 0, 0)
-
-    return idx
-
-
-def _flash_scale_index_map(bs: int, num_blocks: int, pages_per_shard: int):
-    def idx(b, h, s, p, tables_ref, lens_ref):
-        j = s * pages_per_shard + p
-        return (_resolve_page(b, j, tables_ref, lens_ref, bs, num_blocks), h)
 
     return idx
 
@@ -695,10 +736,9 @@ def _flash_decode_kernel_call(q, key_cache, value_cache, block_tables,
     ]
     args = [q, key_cache, value_cache]
     if kv_quant:
-        sc_spec = pl.BlockSpec((1, 1), _flash_scale_index_map(bs, num_blocks,
-                                                              P))
+        sc_spec = _scale_spec(nkv, _flash_page_index_map(bs, num_blocks, P))
         in_specs += [sc_spec, sc_spec]
-        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        args += [_scale_operand(k_scale), _scale_operand(v_scale)]
 
     part_spec = pl.BlockSpec((1, 1, 1, group, 1),
                              lambda b, h, s, p, t, l: (b, h, s, 0, 0))
@@ -1140,6 +1180,7 @@ def _prefill_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
         rest = rest[2:]
     o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
+    h = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -1154,9 +1195,11 @@ def _prefill_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
     @pl.when(j * bs < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                   # [R, hd]
-        k = _dequant_page(k_ref[0, 0], ks_ref[0, 0] if kv_quant else None,
+        k = _dequant_page(k_ref[0, 0],
+                          _head_scale(ks_ref, h) if kv_quant else None,
                           kv_quant)                           # [bs, hd]
-        v = _dequant_page(v_ref[0, 0], vs_ref[0, 0] if kv_quant else None,
+        v = _dequant_page(v_ref[0, 0],
+                          _head_scale(vs_ref, h) if kv_quant else None,
                           kv_quant)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -1189,16 +1232,6 @@ def _prefill_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
         o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
 
 
-def _prefill_scale_index_map(bs: int, num_blocks: int):
-    # the decode kernel's scale fetch, arity-adjusted for the third (qlens)
-    # scalar-prefetch operand; same _resolve_page so KV and scale fetches
-    # can never diverge
-    def idx(b, h, j, tables_ref, lens_ref, qlens_ref):
-        return (_resolve_page(b, j, tables_ref, lens_ref, bs, num_blocks), h)
-
-    return idx
-
-
 def _prefill_kernel_call(q, key_cache, value_cache, block_tables, seq_lens,
                          q_lens, scale, rep, kv_quant, k_scale, v_scale):
     """q: [b, nkv, R, hd] (R = T*rep padded to sublane rows, t-major).
@@ -1219,9 +1252,9 @@ def _prefill_kernel_call(q, key_cache, value_cache, block_tables, seq_lens,
     ]
     args = [q, key_cache, value_cache]
     if kv_quant:
-        sc_spec = pl.BlockSpec((1, 1), _prefill_scale_index_map(bs, num_blocks))
+        sc_spec = _scale_spec(nkv, _verify_page_index_map(bs, num_blocks))
         in_specs += [sc_spec, sc_spec]
-        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        args += [_scale_operand(k_scale), _scale_operand(v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, nkv, max_blocks),
@@ -1417,8 +1450,8 @@ def _fused_decode_kernel(tables_ref, lens_ref, wblk_ref, wable_ref,
         # program must feed the score dot the same rounded values the
         # kill-switched program reads, or near-tied argmaxes could flip
         q = q_ref[0, 0]                                   # [group, hd]
-        cos = cos_ref[0][None, :]                         # [1, hd]
-        sin = sin_ref[0][None, :]
+        cos = cos_ref[0]                                  # [1, hd]
+        sin = sin_ref[0]
         q_r = (q * cos + _rotate_half_rows(q, half) * sin).astype(q.dtype)
         q_scr[:] = q_r.astype(jnp.float32)
 
@@ -1439,16 +1472,16 @@ def _fused_decode_kernel(tables_ref, lens_ref, wblk_ref, wable_ref,
         # and round through the POOL dtype before the dot: the fused score
         # must see exactly the bytes the unfused path would read back from
         # its scatter — not an unrounded f32 row
-        cos = cos_ref[0][None, :]                         # [1, hd]
-        sin = sin_ref[0][None, :]
-        k_new = k_ref[0, 0][None, :]                      # [1, hd]
+        cos = cos_ref[0]                                  # [1, hd]
+        sin = sin_ref[0]
+        k_new = k_ref[0, 0]                               # [1, hd]
         k_roped = (k_new * cos + _rotate_half_rows(k_new, half) * sin
-                   ).astype(k_new.dtype).astype(kp_ref.dtype)[0]
-        v_new = v_ref[0, 0].astype(vp_ref.dtype)          # [hd]
+                   ).astype(k_new.dtype).astype(kp_ref.dtype)
+        v_new = v_ref[0, 0].astype(vp_ref.dtype)          # [1, hd]
         rows = jax.lax.broadcasted_iota(jnp.int32, k_page.shape, 0)
         ins = is_wstep & (rows == lens_ref[b] % bs)
-        k_eff = jnp.where(ins, k_roped.astype(jnp.float32)[None, :], k_page)
-        v_eff = jnp.where(ins, v_new.astype(jnp.float32)[None, :], v_page)
+        k_eff = jnp.where(ins, k_roped.astype(jnp.float32), k_page)
+        v_eff = jnp.where(ins, v_new.astype(jnp.float32), v_page)
 
         @pl.when(is_wpage)
         def _commit():
@@ -1507,21 +1540,29 @@ def _fused_page_index_map(bs: int, nbp: int, pages_per_shard: int):
 
 def _fused_small_in_specs(group: int, hd: int):
     """The five small per-slot operands every fused decode launch streams
-    whole — q group, new k/v rows, cos/sin.  ONE spec set shared by the
-    fp and quant call builders (like ``_fused_walk_page`` for the page
-    maps): a geometry or clamp fix lands in both by construction."""
+    whole — q group, new k/v rows, cos/sin (shapes: see
+    :func:`_fused_small_operands`).  ONE spec set shared by the fp and
+    quant call builders (like ``_fused_walk_page`` for the page maps): a
+    geometry or clamp fix lands in both by construction."""
+    row = pl.BlockSpec((1, 1, 1, hd),
+                       lambda b, h, s, p, t, l, w, a: (b, h, 0, 0))
+    rope = pl.BlockSpec((1, 1, hd), lambda b, h, s, p, t, l, w, a: (b, 0, 0))
     return [
         pl.BlockSpec((1, 1, group, hd),
                      lambda b, h, s, p, t, l, w, a: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, hd),
-                     lambda b, h, s, p, t, l, w, a: (b, h, 0)),
-        pl.BlockSpec((1, 1, hd),
-                     lambda b, h, s, p, t, l, w, a: (b, h, 0)),
-        pl.BlockSpec((1, hd),
-                     lambda b, h, s, p, t, l, w, a: (b, 0)),
-        pl.BlockSpec((1, hd),
-                     lambda b, h, s, p, t, l, w, a: (b, 0)),
+        row, row, rope, rope,
     ]
+
+
+def _fused_small_operands(qg, k_new, v_new, cos, sin):
+    """The operands :func:`_fused_small_in_specs` describes.  A one-row
+    block is only legal on the TPU when the block's last two dims equal the
+    array's (Mosaic tiles them (8, 128)), so the per-(slot, head) k/v rows
+    ride as ``[b, nkv, 1, hd]`` and the per-slot cos/sin rows as
+    ``[b, 1, hd]`` — trailing-singleton views of a few KB, the same trick
+    flash_attention's segment ids use."""
+    return [qg, k_new[:, :, None, :], v_new[:, :, None, :],
+            cos[:, None, :], sin[:, None, :]]
 
 
 def _fused_partials(b: int, nkv: int, S: int, group: int, hd: int):
@@ -1546,22 +1587,15 @@ def _fused_partials(b: int, nkv: int, S: int, group: int, hd: int):
     return [part_spec, part_spec, acc_spec], out_shapes, scratch
 
 
-def _fused_write_page_spec(nbp: int, block: tuple):
-    """ALIASED-output spec pinned to the slot's write page (pool payload
-    when ``block`` is 4-d, per-(page, head) scale when 2-d).  The page id
-    is runtime data: clamp it to the pool like every other data-dependent
-    index — the engine always passes a valid page (own page or spill),
-    but the kernel-contract bounds rule (analysis/kernel_contracts.py)
-    requires the map itself to be safe for ALL prefetch values, not
-    safe-by-caller-convention."""
-    if len(block) == 4:
-        return pl.BlockSpec(
-            block,
-            lambda b, h, s, p, t, l, w, a: (jnp.clip(w[b], 0, nbp - 1),
-                                            h, 0, 0))
-    return pl.BlockSpec(
-        block,
-        lambda b, h, s, p, t, l, w, a: (jnp.clip(w[b], 0, nbp - 1), h))
+def _fused_write_page_map(nbp: int):
+    """Index map of the ALIASED pool output, pinned to the slot's write
+    page.  The page id is runtime data: clamp it to the pool like every
+    other data-dependent index — the engine always passes a valid page
+    (own page or spill), but the kernel-contract bounds rule
+    (analysis/kernel_contracts.py) requires the map itself to be safe for
+    ALL prefetch values, not safe-by-caller-convention."""
+    return lambda b, h, s, p, t, l, w, a: (jnp.clip(w[b], 0, nbp - 1),
+                                           h, 0, 0)
 
 
 def _fused_decode_kernel_call(qg, k_new, v_new, cos, sin, key_cache,
@@ -1579,7 +1613,7 @@ def _fused_decode_kernel_call(qg, k_new, v_new, cos, sin, key_cache,
     kernel = functools.partial(_fused_decode_kernel, scale=scale, bs=bs,
                                pages_per_shard=P)
     kv_spec = pl.BlockSpec((1, 1, bs, hd), _fused_page_index_map(bs, nbp, P))
-    pool_out_spec = _fused_write_page_spec(nbp, (1, 1, bs, hd))
+    pool_out_spec = pl.BlockSpec((1, 1, bs, hd), _fused_write_page_map(nbp))
     part_specs, part_shapes, scratch = _fused_partials(b, nkv, S, group, hd)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -1602,7 +1636,8 @@ def _fused_decode_kernel_call(qg, k_new, v_new, cos, sin, key_cache,
         interpret=interpret_mode(),
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
       write_blk.astype(jnp.int32), writeable.astype(jnp.int32),
-      qg, k_new, v_new, cos, sin, key_cache, value_cache)
+      *_fused_small_operands(qg, k_new, v_new, cos, sin),
+      key_cache, value_cache)
 
 
 def fused_decode_step_reference(q, k_new, v_new, cos, sin, key_cache,
@@ -1707,16 +1742,6 @@ def fused_decode_step(q, k_new, v_new, cos, sin, key_cache, value_cache,
 # int8/packed-int4 pools take the fused path instead of requant scatters)
 # ---------------------------------------------------------------------------
 
-def _fused_quant_scale_index_map(bs: int, nbp: int, pages_per_shard: int):
-    # the per-(page, head) scale operands resolve through the SAME
-    # _fused_walk_page as the payload map
-    def idx(b, h, s, p, tables_ref, lens_ref, wblk_ref, wable_ref):
-        return (_fused_walk_page(b, s, p, tables_ref, lens_ref, bs, nbp,
-                                 pages_per_shard), h)
-
-    return idx
-
-
 def _fused_quant_decode_kernel(tables_ref, lens_ref, wblk_ref, wable_ref,
                                q_ref, k_ref, v_ref, cos_ref, sin_ref,
                                kp_ref, vp_ref, ks_ref, vs_ref,
@@ -1736,7 +1761,11 @@ def _fused_quant_decode_kernel(tables_ref, lens_ref, wblk_ref, wable_ref,
       the page requantized (:func:`_quant_encode_page` — the same encode
       the XLA scatter arm uses, so the committed bytes are identical),
       then codes AND new scale commit through ALIASED outputs pinned to
-      the write page;
+      the write page.  A scale output block is the write page's whole head
+      row ``(1, 1, nkv)`` (see :func:`_scale_operand`): its index depends
+      on the slot only, so it stays resident in VMEM across the slot's
+      heads, each head's write step fills its own lane, and the row is
+      flushed complete when the walk moves to the next slot;
     - attention at the write step reads the requantize→dequantize round
       trip — exactly the bytes the scatter arm's dequant-on-read would
       see, which is what makes fused vs kill-switched token-identical;
@@ -1744,6 +1773,7 @@ def _fused_quant_decode_kernel(tables_ref, lens_ref, wblk_ref, wable_ref,
       the caller's SPILL page/scale entry (deterministic trash can, like
       the fp kernel)."""
     b = pl.program_id(0)
+    h = pl.program_id(1)
     s_id = pl.program_id(2)
     p = pl.program_id(3)
     j = s_id * pages_per_shard + p                        # logical page
@@ -1755,8 +1785,8 @@ def _fused_quant_decode_kernel(tables_ref, lens_ref, wblk_ref, wable_ref,
         # rope in the INPUT dtype, exactly like the fp fused kernel (and
         # the unfused arm's apply_rotary_pos_emb)
         q = q_ref[0, 0]                                   # [group, hd]
-        cos = cos_ref[0][None, :]
-        sin = sin_ref[0][None, :]
+        cos = cos_ref[0]                                  # [1, hd]
+        sin = sin_ref[0]
         q_r = (q * cos + _rotate_half_rows(q, half) * sin).astype(q.dtype)
         q_scr[:] = q_r.astype(jnp.float32)
 
@@ -1771,10 +1801,10 @@ def _fused_quant_decode_kernel(tables_ref, lens_ref, wblk_ref, wable_ref,
         w_on = wable_ref[b] == 1
         is_wpage = j == lens_ref[b] // bs
         is_wstep = w_on & is_wpage
-        sc_k = ks_ref[0, 0]                               # scalar f32
-        sc_v = vs_ref[0, 0]
-        k_deq = _dequant_page_content(kp_ref[0, 0], sc_k, kv_quant)
-        v_deq = _dequant_page_content(vp_ref[0, 0], sc_v, kv_quant)
+        k_deq = _dequant_page(kp_ref[0, 0], _head_scale(ks_ref, h),
+                              kv_quant)
+        v_deq = _dequant_page(vp_ref[0, 0], _head_scale(vs_ref, h),
+                              kv_quant)
 
         @pl.when(is_wpage)
         def _append_commit():
@@ -1786,33 +1816,33 @@ def _fused_quant_decode_kernel(tables_ref, lens_ref, wblk_ref, wable_ref,
             # rope the new k in the input dtype (matching the scatter
             # arm's apply_rotary_pos_emb); the f32 cast below mirrors
             # quant_append_decode's rows.astype(f32) insert
-            cos = cos_ref[0][None, :]
-            sin = sin_ref[0][None, :]
-            k_new = k_ref[0, 0][None, :]                  # [1, hd]
+            cos = cos_ref[0]                              # [1, hd]
+            sin = sin_ref[0]
+            k_new = k_ref[0, 0]                           # [1, hd]
             k_roped = (k_new * cos + _rotate_half_rows(k_new, half) * sin
-                       ).astype(k_new.dtype)[0]
+                       ).astype(k_new.dtype)
             rows = jax.lax.broadcasted_iota(jnp.int32, k_deq.shape, 0)
             ins = rows == lens_ref[b] % bs
-            k_ins = jnp.where(ins, k_roped.astype(jnp.float32)[None, :],
-                              k_deq)
-            v_ins = jnp.where(ins,
-                              v_ref[0, 0].astype(jnp.float32)[None, :],
-                              v_deq)
-            k_q, k_nsc = _quant_encode_page(k_ins, kv_quant)
-            v_q, v_nsc = _quant_encode_page(v_ins, kv_quant)
+            k_ins = jnp.where(ins, k_roped.astype(jnp.float32), k_deq)
+            v_ins = jnp.where(ins, v_ref[0, 0].astype(jnp.float32), v_deq)
+            k_q, k_nsc = _quant_encode_page_tile(k_ins, kv_quant)
+            v_q, v_nsc = _quant_encode_page_tile(v_ins, kv_quant)
             # dropped lanes flush zero codes + zero scale at the spill
             # page (deterministic — uninitialized VMEM bits must never
             # park on the spill page, same contract as the fp kernel)
             zq = jnp.zeros_like(k_q)
             kp_out_ref[0, 0] = jnp.where(w_on, k_q, zq)
             vp_out_ref[0, 0] = jnp.where(w_on, v_q, zq)
-            ks_out_ref[0, 0] = jnp.where(w_on, k_nsc, jnp.float32(0.0))
-            vs_out_ref[0, 0] = jnp.where(w_on, v_nsc, jnp.float32(0.0))
+            mine = _head_lane(ks_out_ref.shape[1:], h)
+            ks_out_ref[0] = jnp.where(mine, jnp.where(w_on, k_nsc, 0.0),
+                                      ks_out_ref[0])
+            vs_out_ref[0] = jnp.where(mine, jnp.where(w_on, v_nsc, 0.0),
+                                      vs_out_ref[0])
             # stage the requantize→dequantize round trip for the score
             # dot — exactly the bytes the scatter arm's dequant-on-read
             # would see (fused vs kill-switched token identity)
-            kw_scr[:] = _dequant_page_content(k_q, k_nsc, kv_quant)
-            vw_scr[:] = _dequant_page_content(v_q, v_nsc, kv_quant)
+            kw_scr[:] = _dequant_page(k_q, k_nsc, kv_quant)
+            vw_scr[:] = _dequant_page(v_q, v_nsc, kv_quant)
 
         # non-write steps select the plain dequant; the scratch operand
         # is only ever READ at the write step (where select — garbage in
@@ -1847,9 +1877,10 @@ def _fused_quant_decode_kernel_call(qg, k_new, v_new, cos, sin, kq, ksc,
                                bs=bs, pages_per_shard=P, kv_quant=kv_quant)
     kv_spec = pl.BlockSpec((1, 1, bs, hd_store),
                            _fused_page_index_map(bs, nbp, P))
-    sc_spec = pl.BlockSpec((1, 1), _fused_quant_scale_index_map(bs, nbp, P))
-    pool_out_spec = _fused_write_page_spec(nbp, (1, 1, bs, hd_store))
-    scale_out_spec = _fused_write_page_spec(nbp, (1, 1))
+    sc_spec = _scale_spec(nkv, _fused_page_index_map(bs, nbp, P))
+    pool_out_spec = pl.BlockSpec((1, 1, bs, hd_store),
+                                 _fused_write_page_map(nbp))
+    scale_out_spec = _scale_spec(nkv, _fused_write_page_map(nbp))
     part_specs, part_shapes, scratch = _fused_partials(b, nkv, S, group, hd)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -1871,14 +1902,15 @@ def _fused_quant_decode_kernel_call(qg, k_new, v_new, cos, sin, kq, ksc,
             _VMEM((bs, hd), jnp.float32),       # write-page v round trip
         ],
     )
-    return pl.pallas_call(
+    ks3, vs3 = _scale_operand(ksc), _scale_operand(vsc)
+    *parts, kq2, vq2, ks2, vs2 = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=part_shapes + [
             jax.ShapeDtypeStruct(kq.shape, kq.dtype),
             jax.ShapeDtypeStruct(vq.shape, vq.dtype),
-            jax.ShapeDtypeStruct(ksc.shape, ksc.dtype),
-            jax.ShapeDtypeStruct(vsc.shape, vsc.dtype),
+            jax.ShapeDtypeStruct(ks3.shape, ks3.dtype),
+            jax.ShapeDtypeStruct(vs3.shape, vs3.dtype),
         ],
         # pool codes + scales (global operand indices 9-12: four scalar-
         # prefetch refs then five small operands precede them) alias their
@@ -1887,8 +1919,9 @@ def _fused_quant_decode_kernel_call(qg, k_new, v_new, cos, sin, kq, ksc,
         interpret=interpret_mode(),
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
       write_blk.astype(jnp.int32), writeable.astype(jnp.int32),
-      qg, k_new, v_new, cos, sin, kq, vq,
-      ksc.astype(jnp.float32), vsc.astype(jnp.float32))
+      *_fused_small_operands(qg, k_new, v_new, cos, sin), kq, vq, ks3, vs3)
+    return (*parts, kq2, vq2, ks2[:, 0, :].astype(ksc.dtype),
+            vs2[:, 0, :].astype(vsc.dtype))
 
 
 def fused_quant_decode_step_reference(q, k_new, v_new, cos, sin, kq, ksc,
@@ -2007,13 +2040,20 @@ def fused_mlp_block_cols(inter: int) -> int:
     return inter
 
 
+def fused_mlp_shape_problem(hidden: int, inter: int) -> str | None:
+    """Why :func:`fused_layer_mlp` cannot take these widths (None: it
+    can)."""
+    if hidden % 8 or inter % 8:
+        return (f"hidden={hidden} / ffn={inter} are not both multiples "
+                f"of 8")
+    return None
+
+
 def fused_mlp_supported(hidden: int, inter: int) -> bool:
-    """Dispatch predicate for :func:`fused_layer_mlp` — pltpu
-    availability, sublane-aligned dims, and the operational opt-out
+    """Dispatch predicate for :func:`fused_layer_mlp` — sublane-aligned
+    dims and the operational opt-out
     (``PADDLE_TPU_DISABLE_PALLAS=fused_layer_mlp``)."""
-    return (_VMEM is not None
-            and hidden % 8 == 0
-            and inter % 8 == 0
+    return (fused_mlp_shape_problem(hidden, inter) is None
             and not kernel_disabled("fused_layer_mlp"))
 
 
@@ -2049,8 +2089,13 @@ def _fused_mlp_kernel(x_ref, ay_ref, w_ref, wg_ref, wu_ref, wd_ref,
     # this cast is an exact round trip: the gate/up dots see the same
     # operand bytes the unfused xn @ w_gate reads
     xn = xn_scr[:].astype(h1.dtype)
-    g = xn @ wg_ref[:]                            # [B, F], input dtype
-    u = xn @ wu_ref[:]
+    # the MXU accumulates in f32 (Mosaic refuses a narrower accumulator);
+    # rounding the result once to the input dtype is what XLA's own
+    # bf16-output dot does on the chip
+    g = jnp.dot(xn, wg_ref[:],
+                preferred_element_type=jnp.float32).astype(h1.dtype)
+    u = jnp.dot(xn, wu_ref[:],
+                preferred_element_type=jnp.float32).astype(h1.dtype)
     act = (jax.nn.silu(g.astype(jnp.float32))
            * u.astype(jnp.float32)).astype(h1.dtype)   # swiglu's math
     acc_scr[:] += jax.lax.dot_general(

@@ -14,7 +14,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from . import interpret_mode, kernel_disabled
+from jax.sharding import PartitionSpec as P
+
+from . import interpret_mode, kernel_disabled, per_shard
 
 
 def _rms_kernel(x_ref, w_ref, o_ref, *, eps):
@@ -61,12 +63,20 @@ def _rms_fwd_pallas(x2d, w, eps):
     return out[:rows] if pad else out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def rms_norm(x, weight, eps=1e-6):
-    """x: [..., d], weight: [d]."""
+def _rms_rows(x, weight, eps):
     shape = x.shape
     out = _rms_fwd_pallas(x.reshape(-1, shape[-1]), weight, eps)
     return out.reshape(shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def rms_norm(x, weight, eps=1e-6):
+    """x: [..., d], weight: [d]."""
+    # on a mesh of several devices (ops.pallas.spmd_kernels) rows are
+    # independent, so each device norms its own slice of the batch
+    xs = lambda batch, _: P(batch, *([None] * (x.ndim - 1)))
+    return per_shard(functools.partial(_rms_rows, eps=eps),
+                     lambda b, h: ((xs(b, h), P()), xs(b, h)))(x, weight)
 
 
 def _rms_vjp_fwd(x, weight, eps):

@@ -251,7 +251,11 @@ def test_spec_skips_prefilling_then_resumes():
     fires on the decode-ready slots, and the streams still match the plain
     engine token for token."""
     cfg, params = _tiny()
-    rs = np.random.RandomState(7)
+    # the seed picks prompts whose greedy continuation repeats an n-gram, so
+    # the drafter has something to match once prefill drains (the random
+    # model's continuation depends on the installed jax's numerics: seed 7
+    # stopped repeating on jax 0.9 and the drafter never fired)
+    rs = np.random.RandomState(4)
     prompts = [np.tile(rs.randint(0, 128, (6,)).astype(np.int32), 4),
                np.tile(rs.randint(0, 128, (5,)).astype(np.int32), 4)]
 

@@ -276,7 +276,9 @@ def test_failover_token_identity_mid_prefill_chunk(monkeypatch):
     # prompt, 8-token chunks) — the journal's cursor is set
     eng0 = fleet.replicas[0]
     assert eng0._prefill_ids[0] is not None
-    assert fleet._journal[0]["running"][0]["prefilled"] > 0
+    # (the async host runtime pulls the router's copy only when a death or
+    # a hedge consumes it, so read the replica's own journal)
+    assert eng0.journal()["running"][0]["prefilled"] > 0
     while fleet.step():
         pass
     assert fleet.stats["failovers"] == 1

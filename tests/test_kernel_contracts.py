@@ -156,7 +156,8 @@ def test_race_positive_parallel_axis_collision():
     one output block is a race — the megakernel failure mode."""
     closed = _trace_call(
         lambda i: (i, 0), lambda i: (0, 0),
-        compiler_params=dict(mosaic=dict(dimension_semantics=("parallel",))))
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)))
     findings, sections = check_kernel_contracts(closed, target="t")
     hits = [f for f in findings if f.rule == "kernel_race"]
     assert hits and hits[0].severity == Severity.ERROR
@@ -173,7 +174,8 @@ def test_race_multiple_parallel_collisions_never_mislabel_lost_write():
     closed = _trace_call(
         lambda i: (i, 0), lambda i: (i % 2, 0),
         shape=(4, 8), out_shape=(2, 8),
-        compiler_params=dict(mosaic=dict(dimension_semantics=("parallel",))))
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)))
     findings, _ = check_kernel_contracts(closed)
     assert [f for f in findings if f.rule == "kernel_race"]
     assert [f for f in findings if f.rule == "kernel_lost_write"] == []
@@ -515,8 +517,8 @@ def _race_program():
             in_specs=[pl.BlockSpec((1, 8), lambda i: (i, 0))],
             out_specs=pl.BlockSpec((1, 8), lambda i: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((4, 8), jnp.float32),
-            compiler_params=dict(
-                mosaic=dict(dimension_semantics=("parallel",))),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",)),
             interpret=True)(x)
 
     return f, (x,)
