@@ -63,6 +63,8 @@ Also here: :func:`registry_drift_findings`, the KNOWN_KERNELS drift lint
 (AST-level, so docstrings/comments don't count), in both directions: a
 renamed or retired kernel must not leave a dead kill switch behind, and
 a new kernel's opt-out must be registered so typos get the did-you-mean.
+And :func:`check_kernel_names`: every launch carries a ``name=`` (what a
+device trace calls its events), one per kernel body.
 """
 
 from __future__ import annotations
@@ -74,9 +76,9 @@ import numpy as np
 
 from .report import Finding, Severity
 
-__all__ = ["check_kernel_contracts", "contracts_summary",
-           "registry_drift_findings", "verify_samples_cap",
-           "DEFAULT_SAMPLES_CAP"]
+__all__ = ["check_kernel_contracts", "check_kernel_names",
+           "contracts_summary", "registry_drift_findings",
+           "verify_samples_cap", "DEFAULT_SAMPLES_CAP"]
 
 #: default grid-point enumeration cap (full enumeration at or below it);
 #: override with PADDLE_TPU_KERNEL_VERIFY_SAMPLES (validated env_int)
@@ -663,6 +665,48 @@ def contracts_summary(sections: list) -> dict:
             "unchecked_operands": sum(s.get("unchecked_operands", 0)
                                       for s in sections),
             "violations": sum(s.get("findings", 0) for s in sections)}
+
+
+# ---------------------------------------------------------------------------
+# kernel names (what a device trace calls a kernel's events)
+# ---------------------------------------------------------------------------
+
+def check_kernel_names(programs) -> list[Finding]:
+    """Every ``pallas_call`` of the traced ``programs`` carries a non-empty
+    ``name=``, and no two kernel bodies share one.  The TPU runtime names
+    a kernel's device events by it (``%<name>.N = ... custom-call(...)``);
+    without one they carry the JAX transformation the call was traced
+    under (``closed_call.20``, ``checkpoint.18``), which any refactor
+    moves, and two bodies under one name would be summed as one kernel."""
+    from .rules import _where
+
+    bodies: dict[str, dict[str, str]] = {}   # name -> {body: where}
+    findings = []
+    for closed in programs:
+        for eqn in _pallas_eqns(closed):
+            name = eqn.params.get("name")
+            di = eqn.params["jaxpr"].debug_info
+            body = (f"{os.path.basename(di.func_filename or '?')}:"
+                    f"{di.func_lineno}")
+            if not name:
+                findings.append(Finding(
+                    rule="kernel_name", severity=Severity.ERROR,
+                    message=(f"pallas kernel {_kernel_name(eqn)} ({body}) "
+                             f"is launched without name=: a device trace "
+                             f"cannot tell its events from another "
+                             f"kernel's"),
+                    where=_where(eqn)))
+                continue
+            bodies.setdefault(name, {}).setdefault(body, _where(eqn))
+    for name, seen in sorted(bodies.items()):
+        if len(seen) > 1:
+            findings.append(Finding(
+                rule="kernel_name", severity=Severity.ERROR,
+                message=(f"pallas kernel name {name!r} is shared by "
+                         f"{len(seen)} kernel bodies "
+                         f"({', '.join(sorted(seen))}): give each its own"),
+                where=sorted(seen.values())[0]))
+    return findings
 
 
 # ---------------------------------------------------------------------------

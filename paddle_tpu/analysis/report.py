@@ -42,8 +42,8 @@ class Finding:
 
     ``rule``: dtype_upcast | donation | recompile | host_sync | resharding |
     engine_audit | program_card | budget | kernel_bounds | kernel_race |
-    kernel_lost_write | kernel_alias | kernel_registry (the last five:
-    kernel_contracts.py).  ``where`` is eqn provenance
+    kernel_lost_write | kernel_alias | kernel_registry | kernel_name (the
+    last six: kernel_contracts.py).  ``where`` is eqn provenance
     (``file.py:line (fn)``) when the jaxpr carries source info, else a
     structural path (``params/layers/wq``).
     """

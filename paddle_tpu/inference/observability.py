@@ -336,6 +336,46 @@ ENGINE_STAT_SCHEMA = {
                            "Steps whose token-independent host work "
                            "overlapped the in-flight device step (async "
                            "host runtime)"),
+    # ---- the step measured from inside (docs/observability.md "Step
+    # accounting"): plain counters, one increment per compiled launch
+    # (_count_launch) or per step() that found work, so a mean over any
+    # window is the ratio of two deltas taken at the same boundary
+    "step_rows_computed": ("counter",
+                           "Rows the launched step programs computed "
+                           "whatever was live (mixed: max_batch x "
+                           "prefill_chunk; decode: max_batch x chunk; "
+                           "verify: max_batch x (1 + draft tokens))"),
+    "step_rows_live": ("counter",
+                       "Rows of those that carried a token someone waits "
+                       "for: decode slots' rows plus packed prompt rows "
+                       "(over step_rows_computed = the step's live share)"),
+    "prefill_rows_packed": ("counter",
+                            "Prompt rows packed into launches, counted "
+                            "when launched after the preemption pass "
+                            "(whole-prompt engines: the uncached prompt "
+                            "length at each prefill launch)"),
+    "slot_steps_live": ("counter",
+                        "Slots seated at a launch (after the preemption "
+                        "pass), summed over launches (over "
+                        "slot_steps_total = mean slot occupancy)"),
+    "slot_steps_total": ("counter", "max_batch summed over launches"),
+    "kv_page_steps_in_use": ("counter",
+                             "KV pool pages in use (num_blocks - free) "
+                             "when a launched step has banked its tokens, "
+                             "summed over launches (paged engines; over "
+                             "kv_page_steps_total = mean pool occupancy)"),
+    "kv_page_steps_total": ("counter",
+                            "num_blocks summed over launches (paged "
+                            "engines)"),
+    "step_total_s": ("counter",
+                     "Wall seconds inside step() calls that found work "
+                     "(an idle poll returns before the clock starts)"),
+    "step_host_s": ("counter",
+                    "The part of step_total_s outside the interval from "
+                    "the compiled call's return to the end of the host "
+                    "fetch: admission, packing, operand staging and "
+                    "dispatch, token banking (_host_overlap() runs while "
+                    "the device works and is not host time)"),
 }
 
 #: fleet router ``stats`` keys -> (metric kind, help); same contract.

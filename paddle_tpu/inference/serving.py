@@ -913,6 +913,12 @@ class ContinuousBatchingEngine:
             self.stats = {k: (0.0 if kind == "gauge" else 0)
                           for k, (kind, _) in ENGINE_STAT_SCHEMA.items()}
         self._last_step_end = None     # host-gap histogram anchor
+        # the step's open phase span (serving/admit ... serving/bank), when
+        # it began, and the seconds this step has spent waiting on the
+        # device (the host_overlap + fetch phases): see _phase
+        self._phase_ev = None
+        self._phase_t0 = 0.0
+        self._device_wait_s = 0.0
         # flight recorder: bounded ring of recent engine events, dumped
         # (with a metrics snapshot) on request failure / audit error —
         # chaos triage without a rerun.  Independent kill switch.
@@ -955,10 +961,11 @@ class ContinuousBatchingEngine:
         shard_map (``_tp_shard``); ``n_rep`` is the number of leading
         replicated outputs before the two cache pools."""
         body = functools.partial(impl, **statics)
-        donate = self._STEP_DONATE_ARGNUMS
-        if self.tp == 1:
-            return jax.jit(body, donate_argnums=donate)
-        return jax.jit(self._tp_shard(body, n_rep), donate_argnums=donate)
+        step = body if self.tp == 1 else self._tp_shard(body, n_rep)
+        # the step's name in a trace: a partial has none, and its XLA
+        # module would print as jit__unknown(<fingerprint>)
+        step.__name__ = impl.__name__
+        return jax.jit(step, donate_argnums=self._STEP_DONATE_ARGNUMS)
 
     def _tp_shard(self, body, n_rep: int):
         """shard_map a compiled-step body over the 1-D ``("tp",)`` mesh.
@@ -2421,6 +2428,7 @@ class ContinuousBatchingEngine:
                     self.cache_v, slot_arg, jnp.asarray(s0 - 1, jnp.int32),
                     bucket)
                 self.stats["prefills"] += 1
+                self.stats["prefill_rows_packed"] += plen
                 self.stats["decode_stall_steps"] += int(stalls)
                 self._tracer.span(req.rid, "prefill", t_pf,
                                   time.perf_counter(),
@@ -2438,6 +2446,7 @@ class ContinuousBatchingEngine:
                         jnp.asarray(start, jnp.int32),
                         jnp.asarray(s0 - 1, jnp.int32), bucket)
                 self.stats["prefills"] += 1
+                self.stats["prefill_rows_packed"] += plen
                 self.stats["decode_stall_steps"] += int(stalls)
                 self._tracer.span(req.rid, "prefill", t_pf,
                                   time.perf_counter(),
@@ -3011,6 +3020,45 @@ class ContinuousBatchingEngine:
             self._h_step.observe(end - t0)
         self._last_step_end = end
 
+    def _count_launch(self, rows_computed: int, rows_live: int,
+                      slots_seated: int, prefill_rows: int = 0):
+        """Called once by each launch path (mixed, decode, verify) when its
+        step has banked: what the program computed against what was live
+        and the slots seated at the launch, with the numbers packing had
+        in hand; the pool as banking leaves it.  Plain counters, so a mean
+        over any window is a ratio of two deltas (docs/observability.md
+        "Step accounting")."""
+        st = self.stats
+        st["step_rows_computed"] += rows_computed
+        st["step_rows_live"] += rows_live
+        st["prefill_rows_packed"] += prefill_rows
+        st["slot_steps_live"] += slots_seated
+        st["slot_steps_total"] += self.max_batch
+        if self.paged:
+            st["kv_page_steps_in_use"] += self.num_blocks - len(self._free)
+            st["kv_page_steps_total"] += self.num_blocks
+
+    #: the phases in which the host waits on (or works beside) the device
+    _DEVICE_PHASES = ("serving/host_overlap", "serving/fetch")
+
+    def _phase(self, name: str | None = None, **args):
+        """Close the step's open phase span and open the next (``None``
+        closes only).  The phases follow one another inside
+        ``serving/step`` on the profiler's clock (``RecordEvent`` ->
+        ``TraceAnnotation``); the time spent in the device phases is what
+        ``step_host_s`` leaves out of ``step_total_s``."""
+        now = time.perf_counter()
+        ev = self._phase_ev
+        if ev is not None:
+            ev.end()
+            if ev.name in self._DEVICE_PHASES:
+                self._device_wait_s += now - self._phase_t0
+        self._phase_ev = None
+        if name is not None:
+            self._phase_t0 = now
+            self._phase_ev = RecordEvent(name, **args)
+            self._phase_ev.begin()
+
     def step(self) -> bool:
         """One admit + decode iteration (a chunked decode scan; with
         speculation on and at least one slot drafting, a single multi-token
@@ -3024,6 +3072,29 @@ class ContinuousBatchingEngine:
         contained it (each slot's stream depends only on its own
         (seed, position) keys and its own pages)."""
         self._step_no += 1          # fault-plan step key (1-based)
+        if not self._queue and all(r is None for r in self._slot_req):
+            # an idle poll: nothing to expire, admit or launch — and no
+            # span or clock, or a polling caller fills the span buffer
+            self._admit_stalls = 0
+            self._maybe_audit()
+            return False
+        t_in = time.perf_counter()
+        self._device_wait_s = 0.0
+        span = RecordEvent("serving/step", step=self._step_no)
+        span.begin()
+        try:
+            return self._step()
+        finally:
+            self._phase()       # the last phase closes inside its parent
+            span.end()
+            total = time.perf_counter() - t_in
+            self.stats["step_total_s"] += total
+            self.stats["step_host_s"] += total - self._device_wait_s
+
+    def _step(self) -> bool:
+        """``step()``'s body, in phases: admit, pack, then one launch path
+        (dispatch, host_overlap, fetch, bank)."""
+        self._phase("serving/admit")
         if self._graceful:
             self._expire_overdue()
         self._admit()
@@ -3053,6 +3124,7 @@ class ContinuousBatchingEngine:
         else:
             self._admit_stalls = 0
         self._maybe_audit()
+        self._phase("serving/pack")
         if self._chunked and any(i is not None for i in self._prefill_ids):
             # at least one prompt is streaming in: ONE mixed launch advances
             # every decode slot a token AND moves the prompts forward under
@@ -3092,6 +3164,10 @@ class ContinuousBatchingEngine:
         active_np = np.asarray([r is not None for r in self._slot_req])
         if not active_np.any():
             return False
+        n_live = int(active_np.sum())
+        self._phase("serving/dispatch", program="decode",
+                    decode_rows=n_live * k, prefill_rows=0,
+                    rows_computed=self.max_batch * k)
         t0 = time.perf_counter()
         self._note_launch(t0)
         extra = (jnp.asarray(self._table),) if self.paged else ()
@@ -3112,7 +3188,9 @@ class ContinuousBatchingEngine:
                 # (journal upkeep) runs while the device executes the
                 # launch above — the guard/token fetches below block as
                 # late as possible (docs/async_runtime.md)
+                self._phase("serving/host_overlap")
                 self._host_overlap()
+                self._phase("serving/fetch")
                 bad_np = np.asarray(bad)    # [k, B] guard flags
             else:
                 toks, self.cache_k, self.cache_v = decode(
@@ -3120,12 +3198,15 @@ class ContinuousBatchingEngine:
                     jnp.asarray(self._last_tok), jnp.asarray(self._pos),
                     jnp.asarray(active_np), jnp.asarray(self._temp),
                     jnp.asarray(self._topp), jnp.asarray(self._seed), *extra)
+                self._phase("serving/host_overlap")
                 self._host_overlap()
+                self._phase("serving/fetch")
         except FaultInjected as e:
             return self._retry_launch(e)
         self._kernel_err_streak = 0
         self._poison[:] = False
         toks_np = np.asarray(toks)  # [k, B] — ONE host round-trip per chunk
+        self._phase("serving/bank")
         self.stats["decode_time_s"] += time.perf_counter() - t0
         self._note_step_done(t0)
         now = self._last_step_end   # banking-event timestamp (SLO tracker)
@@ -3193,6 +3274,7 @@ class ContinuousBatchingEngine:
             self._jmark(req.rid)   # token bank advanced the journal entry
             if done or old_pos + k >= self.max_seq:
                 self._retire(slot)
+        self._count_launch(self.max_batch * k, n_live * k, n_live)
         self._maybe_audit()
         return True
 
@@ -3306,6 +3388,12 @@ class ContinuousBatchingEngine:
             # deferred or drained (a restore-only step must keep the serve
             # loop spinning until the plan finishes draining)
             return bool(self._queue) or tier_progress
+        n_decode = sum(1 for s in decode_slots if active[s])
+        n_seated = sum(r is not None for r in self._slot_req)
+        prefill_rows = int(sum(chunk_rows.values()))
+        self._phase("serving/dispatch", program="mixed",
+                    decode_rows=n_decode, prefill_rows=prefill_rows,
+                    rows_computed=B * T)
         t0 = time.perf_counter()
         self._note_launch(t0)
         if self._flight is not None:
@@ -3314,7 +3402,7 @@ class ContinuousBatchingEngine:
             self._flight.record("pack", step=self._step_no,
                                 decode=len(decode_slots),
                                 prefill=len(chunk_rows),
-                                prefill_rows=int(sum(chunk_rows.values())))
+                                prefill_rows=prefill_rows)
         any_sampled = bool((self._temp * active).max() > 0)
         mixed = self._mixed_sampling if any_sampled else self._mixed_greedy
         self._arm_poison()
@@ -3328,7 +3416,9 @@ class ContinuousBatchingEngine:
                     jnp.asarray(self._temp), jnp.asarray(self._topp),
                     jnp.asarray(self._seed), jnp.asarray(self._table),
                     poison=jnp.asarray(self._poison))
+                self._phase("serving/host_overlap")
                 self._host_overlap()   # journal upkeep rides the launch
+                self._phase("serving/fetch")
                 bad_np = np.asarray(bad)    # [B] emit-row guard flags
             else:
                 nxt, self.cache_k, self.cache_v = mixed(
@@ -3337,12 +3427,15 @@ class ContinuousBatchingEngine:
                     jnp.asarray(active), jnp.asarray(q_lens),
                     jnp.asarray(self._temp), jnp.asarray(self._topp),
                     jnp.asarray(self._seed), jnp.asarray(self._table))
+                self._phase("serving/host_overlap")
                 self._host_overlap()
+                self._phase("serving/fetch")
         except FaultInjected as e:
             return self._retry_launch(e)
         self._kernel_err_streak = 0
         self._poison[:] = False
         nxt_np = np.asarray(nxt)   # [B] — ONE host round-trip for the step
+        self._phase("serving/bank")
         self.stats["decode_time_s"] += time.perf_counter() - t0
         self._note_step_done(t0)
         self.stats["decode_steps"] += 1
@@ -3414,6 +3507,8 @@ class ContinuousBatchingEngine:
                 if (self._slot_req[s] is not None
                         and new_cur >= self.max_seq):
                     self._retire(s)
+        self._count_launch(B * T, n_decode + prefill_rows, n_seated,
+                           prefill_rows)
         self._maybe_audit()
         return True
 
@@ -3497,6 +3592,11 @@ class ContinuousBatchingEngine:
                 continue  # preempted after drafting, or no proposal
             tokens[s, 1:1 + d.size] = d
             q_lens[s] = 1 + d.size
+        n_live = int(active_np.sum())
+        rows_live = int(q_lens[active_np].sum())
+        self._phase("serving/dispatch", program="verify",
+                    decode_rows=rows_live, prefill_rows=0,
+                    rows_computed=B * Q)
         t0 = time.perf_counter()
         self._note_launch(t0)
         any_sampled = bool((self._temp * active_np).max() > 0)
@@ -3512,7 +3612,9 @@ class ContinuousBatchingEngine:
                     jnp.asarray(self._temp), jnp.asarray(self._topp),
                     jnp.asarray(self._seed), jnp.asarray(self._table),
                     poison=jnp.asarray(self._poison))
+                self._phase("serving/host_overlap")
                 self._host_overlap()   # journal upkeep rides the launch
+                self._phase("serving/fetch")
                 bad_np = np.asarray(bad)    # [B] per-slot guard flags
             else:
                 out, n_acc, self.cache_k, self.cache_v = verify(
@@ -3521,13 +3623,16 @@ class ContinuousBatchingEngine:
                     jnp.asarray(active_np), jnp.asarray(q_lens),
                     jnp.asarray(self._temp), jnp.asarray(self._topp),
                     jnp.asarray(self._seed), jnp.asarray(self._table))
+                self._phase("serving/host_overlap")
                 self._host_overlap()
+                self._phase("serving/fetch")
         except FaultInjected as e:
             return self._retry_launch(e)
         self._kernel_err_streak = 0
         self._poison[:] = False
         out_np = np.asarray(out)
         n_np = np.asarray(n_acc)
+        self._phase("serving/bank")
         self.stats["decode_time_s"] += time.perf_counter() - t0
         self._note_step_done(t0)
         now = self._last_step_end   # banking-event timestamp (SLO tracker)
@@ -3587,6 +3692,7 @@ class ContinuousBatchingEngine:
             self._jmark(req.rid)   # accepted run advanced the journal
             if done or old_pos + n >= self.max_seq:
                 self._retire(slot)
+        self._count_launch(B * Q, rows_live, n_live)
         self._maybe_audit()
         return True
 

@@ -283,6 +283,7 @@ def _flash_fwd(q, k, v, scale, causal, *, rep=1, kv_len=None, mask=None,
             _VMEM((bq_sz, 1), jnp.float32),
             _VMEM((bq_sz, d), jnp.float32),
         ],
+        name="flash_attn_fwd",
         interpret=interpret_mode(),
     )(q, k, v, *opt_arrays)
     return out, lse[..., 0]
@@ -419,6 +420,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, *, rep=1, kv_len=None,
                    jax.ShapeDtypeStruct((bhkv, skv, d), v.dtype)],
         scratch_shapes=[_VMEM((bkv_sz, d), jnp.float32),
                         _VMEM((bkv_sz, d), jnp.float32)],
+        name="flash_attn_bwd_dkv",
         interpret=interpret_mode(),
     )(q, k, v, do, lse3, delta, *opt_arrays)
 
@@ -438,6 +440,7 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, *, rep=1, kv_len=None,
         out_specs=[q_spec_i],
         out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype)],
         scratch_shapes=[_VMEM((bq_sz, d), jnp.float32)],
+        name="flash_attn_bwd_dq",
         interpret=interpret_mode(),
     )(q, k, v, do, lse3, delta, *opt_arrays_q)
     return dq, dk, dv

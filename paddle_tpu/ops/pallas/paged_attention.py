@@ -583,6 +583,7 @@ def _paged_attention_kernel_call(q, key_cache, value_cache, block_tables,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, group, hd), q.dtype),
+        name="paged_decode_attn",
         interpret=interpret_mode(),
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), *args)
 
@@ -766,6 +767,7 @@ def _flash_decode_kernel_call(q, key_cache, value_cache, block_tables,
             jax.ShapeDtypeStruct((b, nkv, S, group, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, nkv, S, group, hd), jnp.float32),
         ],
+        name="paged_flash_decode_attn",
         interpret=interpret_mode(),
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), *args)
     return _flash_combine(m, l, acc).astype(q.dtype)
@@ -1061,6 +1063,7 @@ def _verify_kernel_call(q, key_cache, value_cache, block_tables, seq_lens,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, R, hd), q.dtype),
+        name="paged_verify_attn",
         interpret=interpret_mode(),
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
       q_lens.astype(jnp.int32), q, key_cache, value_cache)
@@ -1271,6 +1274,7 @@ def _prefill_kernel_call(q, key_cache, value_cache, block_tables, seq_lens,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, R, hd), q.dtype),
+        name="ragged_prefill_attn",
         interpret=interpret_mode(),
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
       q_lens.astype(jnp.int32), *args)
@@ -1633,6 +1637,7 @@ def _fused_decode_kernel_call(qg, k_new, v_new, cos, sin, key_cache,
         # refs then five small operands precede them) alias the pool
         # outputs — the append is in-place, no pool copy materializes
         input_output_aliases={9: 3, 10: 4},
+        name="fused_decode_attn",
         interpret=interpret_mode(),
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
       write_blk.astype(jnp.int32), writeable.astype(jnp.int32),
@@ -1916,6 +1921,7 @@ def _fused_quant_decode_kernel_call(qg, k_new, v_new, cos, sin, kq, ksc,
         # prefetch refs then five small operands precede them) alias their
         # outputs — the requantized append is in-place, no pool copy
         input_output_aliases={9: 3, 10: 4, 11: 5, 12: 6},
+        name="fused_quant_decode_attn",
         interpret=interpret_mode(),
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
       write_blk.astype(jnp.int32), writeable.astype(jnp.int32),
@@ -2137,6 +2143,7 @@ def _fused_mlp_kernel_call(x, attn_y, norm_w, w_gate, w_up, w_down, eps):
             jax.ShapeDtypeStruct((Bp, h), x.dtype),
             jax.ShapeDtypeStruct((Bp, h), x.dtype),
         ],
+        name="fused_mlp",
         interpret=interpret_mode(),
     )(x, attn_y, norm_w, w_gate, w_up, w_down)
 

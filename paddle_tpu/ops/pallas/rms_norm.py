@@ -58,6 +58,7 @@ def _rms_fwd_pallas(x2d, w, eps):
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows + pad, d), x2d.dtype),
+        name="rms_norm_fwd",
         interpret=interpret_mode(),
     )(x2d, w)
     return out[:rows] if pad else out

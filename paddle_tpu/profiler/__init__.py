@@ -67,6 +67,9 @@ _dropped = 0
 # (e.g. the request tracer's process_name lane labels) watch this to know
 # their metadata left with a previous export and must be re-emitted
 _generation = 0
+# spans handed to the native tracer since its last drain: its buffers are
+# plain vectors, so the cap above is kept for them here
+_native_spans = 0
 
 
 def host_events_generation() -> int:
@@ -90,7 +93,7 @@ def add_trace_event(ev: dict) -> bool:
 
 def host_events_len() -> int:
     with _events_lock:
-        return len(_events)
+        return len(_events) + _native_spans
 
 
 def host_events_dropped() -> int:
@@ -110,9 +113,12 @@ def set_host_event_capacity(n: int) -> int:
 def clear_host_events() -> None:
     """Drop buffered host events and reset the drop counter (tests and
     rung isolation; export drains implicitly)."""
-    global _dropped, _generation
+    global _dropped, _generation, _native_spans
     with _events_lock:
         _events.clear()
+    if _native_spans:
+        _native_lib().pt_trace_clear()
+        _native_spans = 0
     _dropped = 0
     _generation += 1
 
@@ -168,49 +174,61 @@ def _now_us():
 
 class RecordEvent:
     """Span marker (reference: paddle.profiler.RecordEvent ≙ C++ RecordEvent,
-    platform/profiler/host_tracer.cc).  Also forwards to jax.profiler traces so
-    spans show up inside XPlane timelines."""
+    platform/profiler/host_tracer.cc).  Also forwards to jax.profiler traces
+    so spans show up inside XPlane timelines, on the device trace's clock.
 
-    def __init__(self, name: str, event_type=None):
+    Keyword arguments annotate the span: they go to
+    ``jax.profiler.TraceAnnotation(name, **args)`` and onto the host-buffer
+    event as ``args``.  The native tracer stores a name and two timestamps,
+    so a span with arguments is buffered in Python."""
+
+    def __init__(self, name: str, event_type=None, **args):
         self.name = name
+        self.args = args
         self._t0 = None
         self._jax_ctx = None
 
     def begin(self):
-        lib = _native_lib()
-        if lib is not None:
+        global _native_spans, _dropped
+        lib = None if self.args else _native_lib()
+        if lib is None:
+            self._t0 = _now_us()
+        elif _native_spans + len(_events) < _capacity:
             lib.pt_trace_begin(_intern(self.name))
+            _native_spans += 1
             self._t0 = True  # marks an open native span
         else:
-            self._t0 = _now_us()
+            _dropped += 1   # host buffer full: the jax annotation still goes
         try:
-            self._jax_ctx = jax.profiler.TraceAnnotation(self.name)
+            self._jax_ctx = jax.profiler.TraceAnnotation(self.name,
+                                                         **self.args)
             self._jax_ctx.__enter__()
         except Exception:
             self._jax_ctx = None
 
     def end(self):
-        if self._t0 is None:
-            return
         if self._jax_ctx is not None:
             self._jax_ctx.__exit__(None, None, None)
-        lib = _native_lib()
-        if lib is not None:
-            lib.pt_trace_end()
+            self._jax_ctx = None
+        if self._t0 is None:
+            return
+        if self._t0 is True:
+            _native_lib().pt_trace_end()
             self._t0 = None
             return
         t1 = _now_us()
-        add_trace_event(
-            {
-                "name": self.name,
-                "ph": "X",
-                "ts": self._t0,
-                "dur": t1 - self._t0,
-                "pid": os.getpid(),
-                "tid": threading.get_ident() % 100000,
-                "cat": "host",
-            }
-        )
+        ev = {
+            "name": self.name,
+            "ph": "X",
+            "ts": self._t0,
+            "dur": t1 - self._t0,
+            "pid": os.getpid(),
+            "tid": threading.get_ident() % 100000,
+            "cat": "host",
+        }
+        if self.args:
+            ev["args"] = self.args
+        add_trace_event(ev)
         self._t0 = None
 
     def __enter__(self):
@@ -326,13 +344,14 @@ class Profiler:
         long-lived engine that exports periodically never hits the span
         cap.  The drop counter (spans lost while the buffer was full) is
         written as a metadata event and reset."""
-        global _dropped, _generation
+        global _dropped, _generation, _native_spans
         with _events_lock:
             events = list(_events)
             _events.clear()
             dropped, _dropped = _dropped, 0
             _generation += 1
         events += _native_events(clear=True)
+        _native_spans = 0
         if dropped:
             events.append({"name": "host_events_dropped", "ph": "M",
                            "pid": os.getpid(),

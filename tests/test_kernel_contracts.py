@@ -26,6 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.analysis import Severity, analyze
 from paddle_tpu.analysis.kernel_contracts import (check_kernel_contracts,
+                                                  check_kernel_names,
                                                   contracts_summary,
                                                   registry_drift_findings,
                                                   verify_samples_cap,
@@ -342,7 +343,7 @@ def test_sequential_and_splitk_kernels_verify_clean():
             q, kc, vc, tbl, lens)
     findings, sections = check_kernel_contracts(seq)
     assert [f for f in findings if f.severity != Severity.INFO] == []
-    assert sections[0]["kernel"] == "_paged_kernel"
+    assert sections[0]["kernel"] == "paged_decode_attn"
 
     flash = jax.make_jaxpr(lambda *a: pa._flash_decode_kernel_call(
         *a, scale=1.0, kv_quant=None, k_scale=None, v_scale=None,
@@ -428,6 +429,66 @@ def test_analyze_folds_kernel_findings_through_allowlist():
                  allowlist=[AllowRule(rule="kernel_bounds", match="",
                                       reason="test fixture")])
     assert r2.ok and len(r2.allowlisted) == 1
+
+
+# ---------------------------------------------------------------------------
+# kernel names
+# ---------------------------------------------------------------------------
+
+def test_every_pallas_kernel_carries_its_own_name():
+    """Every pallas_call of the gated targets (and the split-K decode
+    kernel, which no gated target launches) carries a name, one per kernel
+    body; the names are those the package's launch sites spell out.  An
+    unnamed launch and a name shared by two bodies are each a finding."""
+    import ast
+    import glob
+
+    from paddle_tpu.analysis import targets
+    from paddle_tpu.analysis.kernel_contracts import _pallas_eqns
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    spelled = []
+    for path in glob.glob(os.path.join(REPO, "paddle_tpu", "ops", "pallas",
+                                       "*.py")):
+        for node in ast.walk(ast.parse(open(path).read())):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "pallas_call"):
+                name = {k.arg: k.value for k in node.keywords}.get("name")
+                assert isinstance(name, ast.Constant), (
+                    f"{path}:{node.lineno}: pallas_call without a literal "
+                    f"name=")
+                spelled.append(name.value)
+    assert len(spelled) == len(set(spelled)) == 11
+    assert all(n.isidentifier() and n == n.lower() for n in spelled)
+
+    programs = []
+    for name in targets.GATE_TARGETS:
+        t = targets.build(name)
+        with targets._pinned_env(t.env):
+            programs.append(jax.make_jaxpr(t.fn)(*t.args))
+    programs.append(jax.make_jaxpr(lambda *a: pa._flash_decode_kernel_call(
+        *a, scale=1.0, kv_quant=None, k_scale=None, v_scale=None,
+        num_shards=2))(*_pool_args()))
+    assert check_kernel_names(programs) == []
+    traced = {e.params["name"] for p in programs for e in _pallas_eqns(p)}
+    assert traced == set(spelled)
+
+    def launch(kernel, name=None):
+        x = jnp.zeros((4, 8), jnp.float32)
+        return jax.make_jaxpr(lambda x: pl.pallas_call(
+            kernel, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            name=name, interpret=True)(x))(x)
+
+    unnamed = check_kernel_names([launch(_copy_kernel)])
+    assert [f.rule for f in unnamed] == ["kernel_name"]
+    assert "_copy_kernel" in unnamed[0].message
+    shared = check_kernel_names([launch(_copy_kernel, "twin"),
+                                 launch(_zero_kernel, "twin")])
+    assert len(shared) == 1 and "'twin'" in shared[0].message
+    assert check_kernel_names([launch(_copy_kernel, "one"),
+                               launch(_copy_kernel, "one"),
+                               launch(_zero_kernel, "other")]) == []
 
 
 # ---------------------------------------------------------------------------

@@ -542,6 +542,255 @@ def test_process_names_survive_drain_on_export(tmp_path):
                for e in events)
 
 
+# ---------------- the step measured from inside ----------------
+
+_STEP_COUNTS = ("step_rows_computed", "step_rows_live", "prefill_rows_packed",
+                "slot_steps_live", "slot_steps_total", "kv_page_steps_in_use",
+                "kv_page_steps_total")
+_PHASES = ["serving/admit", "serving/pack", "serving/dispatch",
+           "serving/host_overlap", "serving/fetch", "serving/bank"]
+
+
+def _drive(eng, reqs):
+    """add_request + step() to the end; the pool's fill after each step
+    that launched, as a harness outside the engine would sample it."""
+    for r in reqs:
+        eng.add_request(r)
+    fills = []
+    while eng.step():
+        fills.append((eng.num_blocks - len(eng._free)) / eng.num_blocks)
+    return fills
+
+
+class _Recorder:
+    """A recording stand-in for ``jax.profiler.TraceAnnotation``."""
+
+    log: list = []
+
+    def __init__(self, name, **kw):
+        self.name, self.kw = name, kw
+
+    def __enter__(self):
+        _Recorder.log.append(("begin", self.name, self.kw))
+        return self
+
+    def __exit__(self, *exc):
+        _Recorder.log.append(("end", self.name))
+
+
+@pytest.fixture
+def recorded(monkeypatch):
+    _Recorder.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Recorder)
+    return _Recorder.log
+
+
+def _step_trees(log):
+    """[(step args, [(child name, child args)])] of a recorded log, with
+    the nesting checked: children open and close inside their
+    ``serving/step``, one after another."""
+    trees, stack = [], []
+    for ev in log:
+        if not ev[1].startswith("serving/"):
+            continue
+        if ev[0] == "begin":
+            if ev[1] == "serving/step":
+                assert not stack
+                trees.append((ev[2], []))
+            elif ev[1] in _PHASES:
+                assert stack == ["serving/step"], stack
+                trees[-1][1].append((ev[1], ev[2]))
+            stack.append(ev[1])
+        else:
+            assert stack.pop() == ev[1]
+    assert not stack
+    return trees
+
+
+@pytest.mark.parametrize("metrics", ["1", "0"])
+def test_step_counters_after_chunked_serve(monkeypatch, metrics):
+    """Chunked prefill, no prefix cache, no preemption: every prompt row is
+    packed exactly once, and each mean over the serve is a ratio of two
+    counters — with the registry on and with the plain dict."""
+    monkeypatch.setenv("PADDLE_TPU_METRICS", metrics)
+    eng = _engine(enable_chunked_prefill=True, prefill_chunk=4,
+                  num_blocks=24)
+    reqs = _requests(3, new=5)
+    fills = _drive(eng, reqs)
+    st = dict(eng.stats)
+    assert set(st) == set(ENGINE_STAT_SCHEMA)
+    assert st["preemptions"] == 0 and len(fills) > st["mixed_steps"] > 0
+    assert st["prefill_rows_packed"] == sum(r.prompt_ids.size for r in reqs)
+    assert 0 < st["step_rows_live"] <= st["step_rows_computed"]
+    assert st["slot_steps_total"] == eng.max_batch * len(fills)
+    assert 0 < st["slot_steps_live"] <= st["slot_steps_total"]
+    assert st["kv_page_steps_total"] == eng.num_blocks * len(fills)
+    assert (st["kv_page_steps_in_use"] / st["kv_page_steps_total"]
+            == pytest.approx(np.mean(fills), rel=1e-12))
+    assert 0.0 <= st["step_host_s"] <= st["step_total_s"]
+    # a mixed step computes max_batch x prefill_chunk rows, a decode chunk
+    # max_batch x chunk, whatever is live
+    decode_launches = len(fills) - st["mixed_steps"]
+    assert st["step_rows_computed"] == eng.max_batch * (
+        4 * st["mixed_steps"] + eng.chunk * decode_launches)
+
+
+def test_step_counters_same_with_metrics_off(monkeypatch):
+    counts = {}
+    for metrics in ("1", "0"):
+        monkeypatch.setenv("PADDLE_TPU_METRICS", metrics)
+        eng = _engine(enable_chunked_prefill=True, prefill_chunk=4,
+                      num_blocks=24)
+        _drive(eng, _requests(3, new=5))
+        counts[metrics] = {k: eng.stats[k] for k in _STEP_COUNTS}
+        assert all(isinstance(v, int) for v in counts[metrics].values())
+    assert counts["1"] == counts["0"]
+
+
+def test_prefill_rows_of_a_whole_prompt_engine():
+    """Without chunked prefill the prompt goes in one ``_prefill`` launch:
+    its rows (all but the last token, which decode feeds) count there."""
+    eng = _engine()
+    reqs = _requests(3, new=4)
+    _drive(eng, reqs)
+    assert eng.stats["prefills"] == 3 and eng.stats["mixed_steps"] == 0
+    assert eng.stats["prefill_rows_packed"] == sum(
+        r.prompt_ids.size - 1 for r in reqs)
+    assert eng.stats["prefill_rows_packed"] == \
+        eng.stats["prefill_tokens_computed"]
+
+
+def test_step_counters_on_the_verify_path():
+    """A verify launch computes max_batch x (1 + draft tokens) rows; live
+    are each seated slot's token plus its drafts."""
+    eng = _engine(enable_speculation=True, num_draft_tokens=3)
+    rep = np.tile(np.arange(6, dtype=np.int32), 4)     # drafter food
+    fills = _drive(eng, [Request(rid=i, prompt_ids=rep[:20 + i],
+                                 max_new_tokens=12) for i in range(2)])
+    st = eng.stats
+    assert st["spec_steps"] > 0
+    assert st["slot_steps_total"] == eng.max_batch * len(fills)
+    assert st["step_rows_live"] <= st["step_rows_computed"]
+    decode_launches = len(fills) - st["spec_steps"]
+    assert st["step_rows_computed"] == eng.max_batch * (
+        4 * st["spec_steps"] + eng.chunk * decode_launches)
+    assert st["step_rows_live"] >= (st["spec_drafted_tokens"]
+                                    + st["slot_steps_live"]
+                                    - eng.max_batch * decode_launches)
+
+
+def test_idle_poll_opens_no_span_and_no_clock(recorded):
+    eng = _engine()
+    buffered = profiler.host_events_len()   # the tracer's lane name
+    assert eng.step() is False and eng.step() is False
+    assert recorded == []
+    assert eng.stats["step_total_s"] == 0 and eng._step_no == 2
+    assert profiler.host_events_len() == buffered
+
+
+def test_step_spans_nest_in_order_with_arguments(recorded):
+    """One mixed step and one decode step each yield ``serving/step`` with
+    its six children in order, nested, carrying their arguments."""
+    eng = _engine(enable_chunked_prefill=True, prefill_chunk=4)
+    _drive(eng, _requests(2, new=6))
+    trees = _step_trees(recorded)
+    assert [t[0]["step"] for t in trees] == list(range(1, len(trees) + 1))
+    by_program = {}
+    for args, children in trees:
+        assert [n for n, _ in children] == _PHASES
+        dispatch = dict(children)["serving/dispatch"]
+        by_program.setdefault(dispatch["program"], dispatch)
+        for name, kw in children:
+            assert kw == {} or name == "serving/dispatch"
+    mixed, decode = by_program["mixed"], by_program["decode"]
+    assert mixed == {"program": "mixed", "decode_rows": 0,
+                     "prefill_rows": 6, "rows_computed": 2 * 4}
+    assert decode == {"program": "decode", "prefill_rows": 0,
+                      "decode_rows": 2 * eng.chunk,
+                      "rows_computed": 2 * eng.chunk}
+    # the dispatch arguments are the counters' increments
+    assert sum(dict(c)["serving/dispatch"]["rows_computed"]
+               for _, c in trees) == eng.stats["step_rows_computed"]
+    assert sum(dict(c)["serving/dispatch"]["prefill_rows"]
+               for _, c in trees) == eng.stats["prefill_rows_packed"]
+
+
+def test_verify_step_spans(recorded):
+    eng = _engine(enable_speculation=True, num_draft_tokens=3)
+    rep = np.tile(np.arange(6, dtype=np.int32), 4)
+    _drive(eng, [Request(rid=0, prompt_ids=rep[:20], max_new_tokens=12)])
+    verify = [dict(c)["serving/dispatch"] for _, c in _step_trees(recorded)
+              if [n for n, _ in c] == _PHASES
+              and dict(c)["serving/dispatch"]["program"] == "verify"]
+    assert verify and all(v["rows_computed"] == eng.max_batch * 4
+                          and 1 <= v["decode_rows"] <= 4 for v in verify)
+
+
+def test_token_streams_identical_with_spans_recording(monkeypatch):
+    def serve():
+        eng = _engine(enable_chunked_prefill=True, prefill_chunk=4,
+                      enable_speculation=True, num_draft_tokens=3)
+        reqs = _requests(3, new=6)
+        reqs[1].temperature, reqs[1].seed = 0.8, 5
+        return eng.serve(reqs)
+
+    plain = serve()
+    _Recorder.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Recorder)
+    assert serve() == plain
+    assert any(ev[1] == "serving/step" for ev in _Recorder.log)
+
+
+def test_step_spans_reach_the_profiler_trace(tmp_path):
+    """The real path: a CPU ``start_trace`` read back with ``ProfileData``
+    holds ``serving/step`` and its children on one host line, children
+    inside their parent, the dispatch arguments as event stats."""
+    import glob
+
+    eng = _engine(enable_chunked_prefill=True, prefill_chunk=4)
+    eng.serve(_requests(2, new=3, seed=1))      # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.serve(_requests(2, new=3, seed=2))
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    lines = [[e for e in line.events if e.name.startswith("serving/")]
+             for plane in jax.profiler.ProfileData.from_file(path).planes
+             for line in plane.lines]
+    lines = [evs for evs in lines if evs]
+    assert len(lines) == 1
+    evs = sorted(lines[0], key=lambda e: (e.start_ns, -e.duration_ns))
+    steps = [e for e in evs if e.name == "serving/step"]
+    assert steps
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for st in steps:
+            lo, hi = st.start_ns, st.start_ns + st.duration_ns
+            inside = [e for e in evs if e is not st and lo <= e.start_ns
+                      and e.start_ns + e.duration_ns <= hi]
+            assert [e.name for e in inside] == _PHASES
+            assert all(a.start_ns + a.duration_ns <= b.start_ns
+                       for a, b in zip(inside, inside[1:]))
+            assert "step" in dict(st.stats)
+            assert dict(inside[2].stats)["program"] in ("mixed", "decode")
+
+
+def test_record_event_arguments_ride_the_host_buffer(tmp_path):
+    with profiler.RecordEvent("with_args", rows=3, program="mixed"):
+        pass
+    with profiler.RecordEvent("without_args"):
+        pass
+    assert profiler.host_events_len() == 2
+    path = tmp_path / "t.json"
+    profiler.Profiler().export(str(path))
+    events = {e["name"]: e for e in json.load(open(path))["traceEvents"]}
+    assert events["with_args"]["args"] == {"rows": 3, "program": "mixed"}
+    assert "args" not in events["without_args"]
+    assert profiler.host_events_len() == 0
+
+
 # ---------------- lint gate ----------------
 
 def test_serving_target_host_sync_clean_with_metrics_on(monkeypatch):
