@@ -94,6 +94,26 @@ def _t_moe_train_step() -> AnalysisTarget:
                           (params, opt_state, ids, labels))
 
 
+def _t_laguna_train_step() -> AnalysisTarget:
+    import jax
+
+    from ..models import laguna
+
+    # the leading dense layer and one S S S F period, a quarter of the
+    # experts held: full and windowed flash attention, the grouped expert
+    # products in chunks, the counters in the optimizer state
+    cfg = laguna.LagunaConfig.tiny(layers=5, held=(0, 4))
+    mesh = laguna.make_mesh(devices=jax.devices()[:1])
+    step_fn, opt_init, psh, dsh = laguna.build_train_step(cfg, mesh)
+    params = laguna.init_params(cfg, jax.random.key(0))
+    opt_state = opt_init(params)
+    rs = np.random.RandomState(0)
+    ids = jax.numpy.asarray(rs.randint(0, cfg.vocab_size, (2, 64)))
+    labels = jax.numpy.asarray(rs.randint(0, cfg.vocab_size, (2, 64)))
+    return AnalysisTarget("laguna_train_step", step_fn,
+                          (params, opt_state, ids, labels))
+
+
 def _serving_engine(_force_flags=(), _cfg_kwargs=None, _disable_pallas=(),
                     **kwargs):
     import contextlib
@@ -447,6 +467,7 @@ def _t_serving_tp_step() -> AnalysisTarget:
 TARGETS = {
     "llama_train_step": _t_llama_train_step,
     "moe_llama_train_step": _t_moe_train_step,
+    "laguna_train_step": _t_laguna_train_step,
     "serving_decode_step": _t_serving_decode_step,
     "serving_flash_decode_step": _t_serving_flash_decode_step,
     "serving_quant_decode_step": _t_serving_quant_decode_step,
@@ -463,7 +484,7 @@ TARGETS = {
 # expensive future target (multi-device compile) can register without
 # slowing the tier-1 suite
 GATE_TARGETS = ("llama_train_step", "moe_llama_train_step",
-                "serving_decode_step", "serving_flash_decode_step",
+                "laguna_train_step", "serving_decode_step", "serving_flash_decode_step",
                 "serving_quant_decode_step", "serving_quant_scatter_step",
                 "serving_prefill_step", "serving_verify_step",
                 "serving_mixed_step", "serving_tier_restore",

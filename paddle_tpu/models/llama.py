@@ -661,29 +661,6 @@ def build_train_step(cfg: LlamaConfig, mesh: Mesh, lr=3e-4, weight_decay=0.1,
     # attention in its own sep shard_map
     attn_fn = sep_attention(mesh, "sep", sep_attn_impl) if sep > 1 and pp == 1 else None
     specs = param_specs(cfg, pp=pp > 1, mp=dict(mesh.shape).get("mp", 1))
-    data_spec = P(("dp", "sharding"), "sep")
-
-    def to_named(tree_specs):
-        return jax.tree_util.tree_map(
-            lambda s: NamedSharding(mesh, s), tree_specs,
-            is_leaf=lambda s: isinstance(s, P),
-        )
-
-    param_shardings = to_named(specs)
-
-    def opt_init(params):
-        return {
-            "step": jnp.zeros((), jnp.int32),
-            "m": jax.tree_util.tree_map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
-            "v": jax.tree_util.tree_map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
-            # master fp32 weights (multi_precision AdamW semantics)
-            "master": jax.tree_util.tree_map(lambda p: p.astype(jnp.float32), params),
-            # last step's pre-clip grad global-norm: free to export (it is
-            # already computed for clipping) and the multichip dryrun's
-            # numerics fingerprint — loss ≈ ln(vocab) at init cannot
-            # distinguish right from wrong backward compute
-            "gnorm": jnp.zeros((), jnp.float32),
-        }
 
     # the executed-1F1B runner binds 'pp' plus any nontrivial dp/sharding
     # axes manually (loss_and_grads_1f1b), and since round 5 also a 'sep'
@@ -728,7 +705,7 @@ def build_train_step(cfg: LlamaConfig, mesh: Mesh, lr=3e-4, weight_decay=0.1,
     vpp_chunks = ((num_chunks or 2)
                   if schedule in ("vpp", "interleave") else 1)
 
-    def train_step(params, opt_state, input_ids, labels):
+    def loss_and_grads(params, input_ids, labels):
         if pp == 1 and sep == 1:
             # plain GSPMD program: Mosaic kernels cannot be partitioned
             # automatically, they run per shard (ops.pallas.spmd_kernels)
@@ -742,20 +719,68 @@ def build_train_step(cfg: LlamaConfig, mesh: Mesh, lr=3e-4, weight_decay=0.1,
                 return loss_fn(cfg, dict(p, embed=embed), input_ids, labels)
 
             with pallas.spmd_kernels(mesh, ("dp", "sharding"), "mp"):
-                loss, grads = jax.value_and_grad(lfn)(params)
-        elif use_1f1b:
-            loss, grads = loss_and_grads_1f1b(cfg, params, input_ids, labels,
-                                              mesh, num_microbatches,
-                                              num_chunks=vpp_chunks,
-                                              zero_bubble=zb,
-                                              sep_attn_impl=sep_attn_impl)
+                return jax.value_and_grad(lfn)(params)
+        if use_1f1b:
+            return loss_and_grads_1f1b(cfg, params, input_ids, labels,
+                                       mesh, num_microbatches,
+                                       num_chunks=vpp_chunks,
+                                       zero_bubble=zb,
+                                       sep_attn_impl=sep_attn_impl)
+        if pp > 1:
+            lfn = lambda p: loss_fn_pp(cfg, p, input_ids, labels, mesh,
+                                       num_microbatches, sep_attn_impl)
         else:
-            if pp > 1:
-                lfn = lambda p: loss_fn_pp(cfg, p, input_ids, labels, mesh,
-                                           num_microbatches, sep_attn_impl)
-            else:
-                lfn = lambda p: loss_fn(cfg, p, input_ids, labels, attn_fn)
-            loss, grads = jax.value_and_grad(lfn)(params)
+            lfn = lambda p: loss_fn(cfg, p, input_ids, labels, attn_fn)
+        return jax.value_and_grad(lfn)(params)
+
+    return adamw_train_step(mesh, specs, loss_and_grads, lr=lr,
+                            weight_decay=weight_decay, beta1=beta1,
+                            beta2=beta2, grad_clip=grad_clip)
+
+
+def adamw_train_step(mesh: Mesh, specs, loss_and_grads, *, lr, weight_decay,
+                     beta1, beta2, grad_clip, counters=None):
+    """The one AdamW scaffold of the model zoo: the jitted, sharded,
+    donating train step round a model's ``loss_and_grads(params, input_ids,
+    labels) -> (loss, grads)``, its ``opt_init`` and the shardings, as
+    ``(step_fn, opt_init, param_shardings, data_sharding)``.
+
+    ``specs`` is the parameter tree of PartitionSpecs.  Global-norm clip,
+    bias-corrected AdamW with decoupled weight decay on float32 master
+    weights; the optimizer state carries the last step's pre-clip gradient
+    norm (``gnorm``).  ``counters`` ({name: int32 shape}) adds cumulative
+    counters to that state: ``loss_and_grads`` then returns ``(loss, grads,
+    {name: this step's counts})`` and the step adds them on
+    (docs/observability.md)."""
+    counters = counters or {}
+    data_spec = P(("dp", "sharding"), "sep")
+
+    def to_named(tree_specs):
+        return jax.tree_util.tree_map(
+            lambda s: NamedSharding(mesh, s), tree_specs,
+            is_leaf=lambda s: isinstance(s, P),
+        )
+
+    param_shardings = to_named(specs)
+
+    def opt_init(params):
+        return {
+            "step": jnp.zeros((), jnp.int32),
+            "m": jax.tree_util.tree_map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
+            "v": jax.tree_util.tree_map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
+            # master fp32 weights (multi_precision AdamW semantics)
+            "master": jax.tree_util.tree_map(lambda p: p.astype(jnp.float32), params),
+            # last step's pre-clip grad global-norm: free to export (it is
+            # already computed for clipping) and the multichip dryrun's
+            # numerics fingerprint — loss ≈ ln(vocab) at init cannot
+            # distinguish right from wrong backward compute
+            "gnorm": jnp.zeros((), jnp.float32),
+            **{name: jnp.zeros(shape, jnp.int32)
+               for name, shape in counters.items()},
+        }
+
+    def train_step(params, opt_state, input_ids, labels):
+        loss, grads, *counts = loss_and_grads(params, input_ids, labels)
         g32 = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), grads)
         # global-norm clip (HybridParallelClipGrad semantics; psum over all axes
         # is implicit — the sharded sum-of-squares reduces globally under GSPMD)
@@ -790,6 +815,8 @@ def build_train_step(cfg: LlamaConfig, mesh: Mesh, lr=3e-4, weight_decay=0.1,
         )
         new_opt = {"step": step, "m": unf(new_m), "v": unf(new_v),
                    "master": unf(new_w), "gnorm": gnorm}
+        for name in counters:
+            new_opt[name] = opt_state[name] + counts[0][name]
         return loss, new_params, new_opt
 
     opt_shardings = {
@@ -798,6 +825,7 @@ def build_train_step(cfg: LlamaConfig, mesh: Mesh, lr=3e-4, weight_decay=0.1,
         "v": param_shardings,
         "master": param_shardings,
         "gnorm": NamedSharding(mesh, P()),
+        **{name: NamedSharding(mesh, P()) for name in counters},
     }
     data_sharding = NamedSharding(mesh, data_spec)
     jitted = jax.jit(

@@ -17,6 +17,7 @@ routed experts with top-k token-choice gating, load-balance auxiliary loss
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -52,6 +53,16 @@ class MoEConfig:
     # (no capacity, no padding; opt-in — changes drop semantics),
     # "auto" = sort above _SORT_DISPATCH_MIN_EXPERTS
     dispatch: str = "auto"
+    # router scores: "softmax" over the experts, or "sigmoid" of each logit
+    # (DeepSeek-V3-class routers); the chosen K are normalised to sum to one
+    # and multiplied by routed_scaling_factor
+    router_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # (lo, hi): the experts THIS program holds (one member of an
+    # expert-parallel group).  Tokens are routed over all num_experts, the
+    # e_* weights are [hi - lo, ...] and only those experts' terms are
+    # summed; None = all.  Needs the ragged engine.
+    experts_held: Any = None
     max_position_embeddings: int = 4096
     rms_norm_eps: float = 1e-5
     rope_theta: float = 10000.0
@@ -242,13 +253,10 @@ def moe_ffn(cfg: MoEConfig, x, lp):
     g = b * s
     xf = x.reshape(g, h)
 
-    logits = (xf.astype(jnp.float32) @ lp["router"])           # [g, E]
-    probs = jax.nn.softmax(logits, axis=-1)
+    topk_p, topk_i, probs, logits = route_topk(
+        xf, lp["router"], K, cfg.router_scoring, cfg.routed_scaling_factor)
     # z-loss: keeps router logits small (numerics at scale)
     z_loss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
-
-    topk_p, topk_i = jax.lax.top_k(probs, K)                   # [g, K]
-    topk_p = topk_p / jnp.maximum(topk_p.sum(-1, keepdims=True), 1e-9)
 
     cap = int(np.ceil(cfg.capacity_factor * K * g / E))
     cap = max(cap, 1)
@@ -259,10 +267,50 @@ def moe_ffn(cfg: MoEConfig, x, lp):
     aux = E * jnp.sum(frac_tokens * frac_probs)
 
     mode = resolved_dispatch(cfg)
+    if cfg.experts_held is not None and mode != "ragged":
+        raise ValueError("experts_held needs dispatch='ragged': the "
+                         "capacity engines compute every expert")
     route = {"sort": _dispatch_sort, "ragged": _dispatch_ragged,
              "dense": _dispatch_dense}[mode]
     out = route(cfg, xf, lp, topk_p, topk_i, cap)
     return out.reshape(b, s, h), aux, z_loss
+
+
+def route_topk(xf, router, top_k, scoring="softmax", scale=1.0, groups=None):
+    """The routing front end: xf [g, h] -> (weights [g, K] float32, experts
+    [g, K], scores [g, E], logits [g, E]).  Scores are computed in float32
+    over ALL experts; the K largest are normalised to sum to one and
+    multiplied by ``scale``.
+
+    ``groups`` (the rows are that many equal runs: a batch's sequences)
+    chooses the K by each logit's standard score over its run (less the
+    expert's mean there, over its deviation): what the run's rows share in
+    their logits, and how far an expert's logits swing, move no choice, so
+    rows that have run together are still spread evenly over the experts.
+    The weights come from the scores as they are, and no gradient passes
+    through the choice."""
+    logits = xf.astype(jnp.float32) @ router                   # [g, E]
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"router scoring must be 'softmax'|'sigmoid', "
+                         f"got {scoring!r}")
+    if groups is None:
+        topk_p, topk_i = jax.lax.top_k(scores, top_k)          # [g, K]
+    else:
+        runs = jax.lax.stop_gradient(logits).reshape(groups, -1,
+                                                     logits.shape[-1])
+        centred = runs - runs.mean(axis=1, keepdims=True)
+        standard = centred * jax.lax.rsqrt(
+            jnp.mean(jnp.square(centred), axis=1, keepdims=True) + 1e-12)
+        _, topk_i = jax.lax.top_k(standard.reshape(logits.shape), top_k)
+        topk_p = jnp.take_along_axis(scores, topk_i, axis=1)
+    topk_p = topk_p / jnp.maximum(topk_p.sum(-1, keepdims=True), 1e-9)
+    if scale != 1.0:
+        topk_p = topk_p * scale
+    return topk_p, topk_i, scores, logits
 
 
 def resolved_dispatch(cfg: MoEConfig) -> str:
@@ -318,22 +366,180 @@ def _dispatch_ragged(cfg, xf, lp, topk_p, topk_i, cap):
     capacity drops occur (cap_factor >= E), and otherwise keep the tokens
     GShard would drop — a quality/perf point, not a parity point, so it is
     opt-in (cfg.dispatch='ragged'), never chosen by 'auto'."""
-    g, h = xf.shape
-    E, K = cfg.num_experts, cfg.top_k
-    N = g * K
+    out, _ = ragged_experts(xf, lp, topk_p, topk_i, cfg.num_experts,
+                            cfg.experts_held)
+    return out
 
-    flat_e = topk_i.reshape(N)
-    order = jnp.argsort(flat_e, stable=True)
-    tok = order // K
-    xs = xf[tok]                                   # [N, h] grouped by expert
-    counts = jnp.bincount(flat_e, length=E).astype(jnp.int32)
-    gate = jax.lax.ragged_dot(xs, lp["e_gate"], counts)
-    up = jax.lax.ragged_dot(xs, lp["e_up"], counts)
-    act = swiglu_mod.swiglu(gate, up)
-    out_s = jax.lax.ragged_dot(act, lp["e_down"], counts)   # [N, h]
-    w = topk_p.reshape(N)[order].astype(xf.dtype)
-    y = jnp.zeros((g, h), xf.dtype)
-    return y.at[tok].add(out_s * w[:, None])
+
+# rows of one chunk of the grouped products over a SHARE of the experts: this
+# much above the rows a uniform router sends to the held experts
+_HELD_ROWS_SLACK = 1.25
+
+
+def held_rows(g: int, top_k: int, num_experts: int, n_held: int) -> tuple:
+    """(rows, chunks) of the grouped products over ``n_held`` of
+    ``num_experts`` for ``g`` tokens: ``chunks * rows`` holds the worst case
+    (every token choosing ``min(top_k, n_held)`` held experts), so nothing
+    is ever dropped at static shapes; ``rows`` is the expected load plus a
+    quarter.  The first chunk always runs, at static shapes (the step's
+    time does not move with the router while the held experts' load is
+    within it); the chunks behind it run while they hold live rows."""
+    worst = g * min(top_k, n_held)
+    if n_held == num_experts:
+        return worst, 1
+    expected = g * top_k * n_held / num_experts
+    rows = -(-int(np.ceil(_HELD_ROWS_SLACK * expected)) // 256) * 256
+    rows = min(rows, worst)
+    return rows, -(-worst // rows)
+
+
+def ragged_experts(xf, lp, topk_p, topk_i, num_experts, held=None):
+    """The grouped expert FFN of the ragged engine on xf [g, h] for the
+    experts ``held = (lo, hi)`` (None: all): ``lp["e_*"]`` are
+    ``[hi - lo, ...]``.  Assignments to absent experts sort behind the held
+    ones and are never gathered.  The sorted assignments are taken
+    ``rows`` at a time (``held_rows``): the first chunk always, the rest by
+    a loop that runs as many chunks as hold work, forward and backward
+    (``_chunked_experts``), so the worst case costs memory for one chunk
+    and time for the chunks it fills.
+
+    Returns (out [g, h], stats): ``held`` [hi - lo] assignments to each held
+    expert, ``total`` all g * K assignments, ``rows`` the rows the grouped
+    products were given (padding included), ``dropped`` the held
+    assignments that no chunk covered (0 by construction; counted from the
+    group sizes actually handed to ``ragged_dot``)."""
+    g, h = xf.shape
+    K = topk_i.shape[1]
+    N = g * K
+    lo, hi = held or (0, num_experts)
+    n_held = hi - lo
+    rows, chunks = held_rows(g, K, num_experts, n_held)
+
+    with jax.named_scope("moe/dispatch"):
+        flat_e = topk_i.reshape(N)
+        local = jnp.where((flat_e >= lo) & (flat_e < hi), flat_e - lo, n_held)
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+        counts = jnp.bincount(local, length=n_held + 1)[:n_held].astype(
+            jnp.int32)
+        ends = jnp.cumsum(counts)
+        starts = ends - counts
+        n_live = ends[-1]
+        pad = chunks * rows - N
+        if pad > 0:
+            order = jnp.concatenate([order, jnp.zeros((pad,), order.dtype)])
+        flat_w = topk_p.reshape(N).astype(jnp.float32)
+    weights = (lp["e_gate"], lp["e_up"], lp["e_down"])
+    if chunks == 1:
+        at, tok, sizes, live = _chunk_rows(order, starts, ends, n_live, 0,
+                                           rows, K)
+        ys = _expert_rows(xf[tok], *weights, flat_w[at], live, sizes)
+        with jax.named_scope("moe/combine"):
+            y = jnp.zeros((g, h), jnp.float32).at[tok].add(ys)
+        covered, ran = sizes.sum(), jnp.int32(1)
+    else:
+        y, covered, ran = _chunked_experts(
+            xf, *weights, flat_w, order, starts, ends, n_live, rows, chunks,
+            K)
+    stats = {"held": counts, "total": jnp.int32(N),
+             "rows": (ran * rows).astype(jnp.int32),
+             "dropped": (n_live - covered).astype(jnp.int32)}
+    return y.astype(xf.dtype), stats
+
+
+def _chunk_rows(order, starts, ends, n_live, first, rows, K):
+    """Rows [first, first + rows) of the sorted assignments: (assignment of
+    each row, its token, the group sizes of this chunk, which rows are
+    live)."""
+    with jax.named_scope("moe/dispatch"):
+        at = jax.lax.dynamic_slice(order, (first,), (rows,))
+        sizes = jnp.clip(jnp.minimum(ends, first + rows)
+                         - jnp.maximum(starts, first), 0, rows)
+        live = first + jnp.arange(rows, dtype=jnp.int32) < n_live
+        return at, at // K, sizes, live
+
+
+def _expert_rows(xs, e_gate, e_up, e_down, w, live, sizes):
+    """xs [rows, h] grouped by expert -> each row's expert output times its
+    router weight, float32; rows past the live ones give zeros.  A grouped
+    product leaves the rows past its groups unwritten, in the backward pass
+    too: both ends are selected by ``live``, so that what lies there never
+    reaches a token."""
+    xs = jnp.where(live[:, None], xs, 0)
+    with jax.named_scope("moe/experts"):
+        gate = jax.lax.ragged_dot(xs, e_gate, sizes)
+        up = jax.lax.ragged_dot(xs, e_up, sizes)
+        act = swiglu_mod.swiglu(gate, up)
+        ys = jax.lax.ragged_dot(act, e_down, sizes)
+    with jax.named_scope("moe/combine"):
+        ys = jnp.where(live[:, None], ys.astype(jnp.float32), 0.0)
+        return ys * jnp.where(live, w, 0.0)[:, None]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
+def _chunked_experts(xf, e_gate, e_up, e_down, flat_w, order, starts, ends,
+                     n_live, rows, chunks, K):
+    """(y [g, h] float32, held assignments covered, chunks run): the held
+    experts' weighted outputs summed onto their tokens, ``rows`` sorted
+    assignments at a time: the first chunk always, then as many chunks as
+    hold live rows (a ``while_loop``: no further chunk is computed, or kept
+    for the backward pass, that holds none).  Its own VJP walks the same
+    chunks again and recomputes each, so neither pass holds more than one
+    chunk."""
+    stop = jnp.minimum(n_live, chunks * rows)
+
+    def body(carry):
+        first, y, covered = carry
+        at, tok, sizes, live = _chunk_rows(order, starts, ends, n_live,
+                                           first, rows, K)
+        ys = _expert_rows(xf[tok], e_gate, e_up, e_down, flat_w[at], live,
+                          sizes)
+        with jax.named_scope("moe/combine"):
+            return first + rows, y.at[tok].add(ys), covered + sizes.sum()
+
+    first, y, covered = jax.lax.while_loop(
+        lambda c: c[0] < stop, body,
+        body((jnp.int32(0), jnp.zeros(xf.shape, jnp.float32), jnp.int32(0))))
+    return y, covered, first // rows
+
+
+def _chunked_experts_fwd(xf, e_gate, e_up, e_down, flat_w, order, starts,
+                         ends, n_live, rows, chunks, K):
+    out = _chunked_experts(xf, e_gate, e_up, e_down, flat_w, order, starts,
+                           ends, n_live, rows, chunks, K)
+    return out, (xf, e_gate, e_up, e_down, flat_w, order, starts, ends,
+                 n_live)
+
+
+def _chunked_experts_bwd(rows, chunks, K, res, cts):
+    xf, e_gate, e_up, e_down, flat_w, order, starts, ends, n_live = res
+    dy = cts[0]
+    stop = jnp.minimum(n_live, chunks * rows)
+
+    def body(carry):
+        first, dxf, dws, dflat = carry
+        at, tok, sizes, live = _chunk_rows(order, starts, ends, n_live,
+                                           first, rows, K)
+        _, vjp = jax.vjp(
+            lambda xs, eg, eu, ed, w: _expert_rows(xs, eg, eu, ed, w, live,
+                                                   sizes),
+            xf[tok], e_gate, e_up, e_down, flat_w[at])
+        dxs, *dw, dwr = vjp(dy[tok])
+        with jax.named_scope("moe/dispatch"):
+            dxf = dxf.at[tok].add(dxs.astype(jnp.float32))
+            # dead rows repeat assignment 0: their cotangent is zero
+            dflat = dflat.at[at].add(dwr)
+        return (first + rows, dxf,
+                tuple(a + b for a, b in zip(dws, dw)), dflat)
+
+    _, dxf, dws, dflat = jax.lax.while_loop(
+        lambda c: c[0] < stop, body,
+        body((jnp.int32(0), jnp.zeros(xf.shape, jnp.float32),
+              tuple(jnp.zeros_like(w) for w in (e_gate, e_up, e_down)),
+              jnp.zeros_like(flat_w))))
+    return (dxf.astype(xf.dtype), *dws, dflat, None, None, None, None)
+
+
+_chunked_experts.defvjp(_chunked_experts_fwd, _chunked_experts_bwd)
 
 
 def _dispatch_sort(cfg, xf, lp, topk_p, topk_i, cap):
@@ -442,80 +648,18 @@ def make_mesh(dp=1, mp=1, sharding=1, sep=1, pp=1, devices=None):
 
 def build_train_step(cfg: MoEConfig, mesh: Mesh, lr=3e-4, weight_decay=0.1,
                      beta1=0.9, beta2=0.95, grad_clip=1.0):
-    """Same optimizer/sharding scaffold as models/llama.build_train_step, with
-    the MoE loss (ce + aux + z)."""
+    """models/llama's AdamW scaffold round the MoE loss (ce + aux + z)."""
+    from .llama import adamw_train_step
+
     specs = param_specs(cfg, mp=dict(mesh.shape).get("mp", 1))
-    data_spec = P(("dp", "sharding"), "sep")
 
-    def to_named(tree_specs):
-        return jax.tree_util.tree_map(
-            lambda sp: NamedSharding(mesh, sp), tree_specs,
-            is_leaf=lambda sp: isinstance(sp, P))
-
-    param_shardings = to_named(specs)
-
-    def opt_init(params):
-        z = lambda p: jnp.zeros(p.shape, jnp.float32)
-        return {
-            "step": jnp.zeros((), jnp.int32),
-            "m": jax.tree_util.tree_map(z, params),
-            "v": jax.tree_util.tree_map(z, params),
-            "master": jax.tree_util.tree_map(lambda p: p.astype(jnp.float32), params),
-            # pre-clip grad global-norm (multichip dryrun fingerprint;
-            # mirrors models/llama.build_train_step)
-            "gnorm": jnp.zeros((), jnp.float32),
-        }
-
-    def train_step(params, opt_state, input_ids, labels):
-        loss, grads = jax.value_and_grad(
+    def loss_and_grads(params, input_ids, labels):
+        return jax.value_and_grad(
             lambda p: loss_fn(cfg, p, input_ids, labels))(params)
-        g32 = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), grads)
-        leaves = jax.tree_util.tree_leaves(g32)
-        gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in leaves))
-        scale_f = jnp.minimum(1.0, grad_clip / jnp.maximum(gnorm, 1e-6))
-        step = opt_state["step"] + 1
-        b1c = 1 - beta1 ** step.astype(jnp.float32)
-        b2c = 1 - beta2 ** step.astype(jnp.float32)
 
-        def upd(g, m, v, master):
-            g = g * scale_f
-            m2 = beta1 * m + (1 - beta1) * g
-            v2 = beta2 * v + (1 - beta2) * g * g
-            update = (m2 / b1c) / (jnp.sqrt(v2 / b2c) + 1e-8)
-            master2 = master * (1 - lr * weight_decay) - lr * update
-            return m2, v2, master2
-
-        updated = jax.tree_util.tree_map(
-            upd, g32, opt_state["m"], opt_state["v"], opt_state["master"])
-        # tree_map over 4 trees returns a (m2, v2, w2) tuple per leaf; split
-        flat, treedef = jax.tree_util.tree_flatten(
-            updated, is_leaf=lambda x: isinstance(x, tuple))
-        new_m = jax.tree_util.tree_unflatten(treedef, [t[0] for t in flat])
-        new_v = jax.tree_util.tree_unflatten(treedef, [t[1] for t in flat])
-        new_w = jax.tree_util.tree_unflatten(treedef, [t[2] for t in flat])
-        new_params = jax.tree_util.tree_map(
-            lambda w, p: w.astype(p.dtype), new_w, params)
-        new_opt = {"step": step, "m": new_m, "v": new_v, "master": new_w,
-                   "gnorm": gnorm}
-        return loss, new_params, new_opt
-
-    opt_shardings = {
-        "step": NamedSharding(mesh, P()),
-        "m": param_shardings,
-        "v": param_shardings,
-        "master": param_shardings,
-        "gnorm": NamedSharding(mesh, P()),
-    }
-    data_sharding = NamedSharding(mesh, data_spec)
-    jitted = jax.jit(
-        train_step,
-        in_shardings=(param_shardings, opt_shardings, data_sharding, data_sharding),
-        out_shardings=(NamedSharding(mesh, P()), param_shardings, opt_shardings),
-        donate_argnums=(0, 1),
-    )
-    # fresh zeros in opt state don't inherit param shardings — pin them
-    opt_init = jax.jit(opt_init, out_shardings=opt_shardings)
-    return jitted, opt_init, param_shardings, data_sharding
+    return adamw_train_step(mesh, specs, loss_and_grads, lr=lr,
+                            weight_decay=weight_decay, beta1=beta1,
+                            beta2=beta2, grad_clip=grad_clip)
 
 
 def count_params(params) -> int:

@@ -8,7 +8,10 @@ tiles stream HBM→VMEM, logits never materialize in HBM, the MXU does the two
 matmuls per tile and the VPU the online rescale.
 
 Feature parity with the reference kernel family:
-- causal and full attention;
+- causal and full attention; a causal sliding ``window`` (a position sees
+  itself and the ``window - 1`` before it) whose calls walk only the band:
+  the grid's inner dimension is the few blocks a q (kv) block's band can
+  touch, so the cost is s·w and not s²/2;
 - GQA/MQA natively: K/V blocks are indexed per kv head group inside the grid
   (``bh // rep`` index maps) — grouped heads are never materialized in HBM;
 - arbitrary sequence lengths: inputs are padded to the block grid and the
@@ -143,8 +146,47 @@ def _causal_mask(s, q_idx, bq, kv_idx, bkv):
     return jnp.where(rows >= cols, s, NEG_INF)
 
 
+def _window_mask(s, q_idx, bq, kv_idx, bkv, window):
+    rows = q_idx * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = kv_idx * bkv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(rows - cols < window, s, NEG_INF)
+
+
+def _band_first_kv(q_idx, bq, bkv, window):
+    """First kv block that the band of q block ``q_idx`` touches."""
+    return jnp.maximum(q_idx * bq - (window - 1), 0) // bkv
+
+
+def _band_first_q(kv_idx, bq, bkv):
+    """First q block that sees kv block ``kv_idx`` (causal)."""
+    return kv_idx * bkv // bq
+
+
+def _band_widths(n_q, n_kv, bq, bkv, window):
+    """(kv blocks a q block's band touches at most, q blocks a kv block's
+    band touches at most): the inner grid dimensions of the banded calls."""
+    kv_per_q = max(((i + 1) * bq - 1) // bkv
+                   - max(i * bq - (window - 1), 0) // bkv + 1
+                   for i in range(n_q))
+    q_per_kv = max(min(n_q - 1, ((j + 1) * bkv - 1 + window - 1) // bq)
+                   - j * bkv // bq + 1 for j in range(n_kv))
+    return kv_per_q, q_per_kv
+
+
+def _run_block(q_idx, kv_idx, *, causal, bq, bkv, kv_len, window):
+    """Whole-block skips: padded KV blocks (fully out of range), causal
+    (block fully above the diagonal) and, under a window, blocks fully
+    behind the band."""
+    run = kv_idx * bkv < kv_len
+    if causal:
+        run &= (q_idx + 1) * bq - 1 >= kv_idx * bkv
+    if window is not None:
+        run &= q_idx * bq - ((kv_idx + 1) * bkv - 1) < window
+    return run
+
+
 def _masked_logits(q, k, refs, q_idx, kv_idx, *, scale, causal, bq, bkv,
-                   kv_len, skv_pad, has_mask, has_seg):
+                   kv_len, skv_pad, has_mask, has_seg, window=None):
     """Shared fwd/bwd logits tile: QK^T · scale with all masks applied.
     ``refs`` holds the optional (mask, q_seg, kv_seg) refs in order."""
     s = jax.lax.dot_general(
@@ -157,6 +199,8 @@ def _masked_logits(q, k, refs, q_idx, kv_idx, *, scale, causal, bq, bkv,
         s = _seg_mask(s, next(it), next(it))
     if causal:
         s = _causal_mask(s, q_idx, bq, kv_idx, bkv)
+    if window is not None:
+        s = _window_mask(s, q_idx, bq, kv_idx, bkv, window)
     if kv_len != skv_pad:
         s = _bounds_mask(s, kv_idx, bkv, kv_len)
     return s
@@ -169,25 +213,26 @@ def _safe_exp(s, shift):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bkv, kv_len,
-                skv_pad, has_mask, has_seg):
-    """Grid: (bh, num_q_blocks, num_kv_blocks); kv innermost (sequential)."""
+                skv_pad, has_mask, has_seg, window=None):
+    """Grid: (bh, num_q_blocks, num_kv_blocks); kv innermost (sequential).
+    Under ``window`` the inner dimension walks the band's kv blocks only,
+    from ``_band_first_kv`` on."""
     n_opt = int(has_mask) + 2 * int(has_seg)
     opt_refs = rest[:n_opt]
     o_ref, lse_ref, m_scr, l_scr, acc_scr = rest[n_opt:]
-    kv_idx = pl.program_id(2)
+    j = pl.program_id(2)
     q_idx = pl.program_id(1)
+    kv_idx = j if window is None else (
+        _band_first_kv(q_idx, bq, bkv, window) + j)
 
-    @pl.when(kv_idx == 0)
+    @pl.when(j == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # whole-block skips: causal (block fully above the diagonal) and padded
-    # KV blocks (fully out of range)
-    run = kv_idx * bkv < kv_len
-    if causal:
-        run &= (q_idx + 1) * bq - 1 >= kv_idx * bkv
+    run = _run_block(q_idx, kv_idx, causal=causal, bq=bq, bkv=bkv,
+                     kv_len=kv_len, window=window)
 
     @pl.when(run)
     def _compute():
@@ -196,7 +241,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bkv, kv_len,
         v = v_ref[0].astype(jnp.float32)  # [bkv, d]
         s = _masked_logits(q, k, opt_refs, q_idx, kv_idx, scale=scale,
                            causal=causal, bq=bq, bkv=bkv, kv_len=kv_len,
-                           skv_pad=skv_pad, has_mask=has_mask, has_seg=has_seg)
+                           skv_pad=skv_pad, has_mask=has_mask,
+                           has_seg=has_seg, window=window)
         m_prev = m_scr[:]  # [bq, 1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -209,7 +255,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bkv, kv_len,
         m_scr[:] = m_new
         l_scr[:] = l_new
 
-    @pl.when(kv_idx == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
         l = l_scr[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -241,7 +287,8 @@ def _opt_specs(bq, bkv, mask, mask_idx, segs, batch_of, q_blk, kv_blk,
 
 
 def _flash_fwd(q, k, v, scale, causal, *, rep=1, kv_len=None, mask=None,
-               mask_idx=None, segs=None, batch_of=None, blocks=None):
+               mask_idx=None, segs=None, batch_of=None, blocks=None,
+               window=None):
     """q: [bh, sq, d] (bh = b·hq); k,v: [bh // rep, skv, d].
     Returns (out [bh, sq, d], lse [bh, sq]).  All seq lengths already padded
     to the block grid; ``kv_len`` is the real KV length before padding;
@@ -256,18 +303,28 @@ def _flash_fwd(q, k, v, scale, causal, *, rep=1, kv_len=None, mask=None,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, bq=bq_sz, bkv=bkv_sz,
         kv_len=kv_len, skv_pad=skv, has_mask=mask is not None,
-        has_seg=segs is not None,
+        has_seg=segs is not None, window=window,
     )
+    if window is None:
+        n_inner, kv_of = n_kv, lambda i, j: j
+    else:
+        # the band's kv blocks only; past the diagonal the index stays on
+        # the last block (no new copy) and the kernel skips the step
+        n_inner = _band_widths(n_q, n_kv, bq_sz, bkv_sz, window)[0]
+        kv_of = lambda i, j: jnp.minimum(
+            _band_first_kv(i, bq_sz, bkv_sz, window) + j, n_kv - 1)
     opt_arrays, opt_specs = _opt_specs(
         bq_sz, bkv_sz, mask, mask_idx, segs, batch_of,
-        q_blk=lambda b, i, j: i, kv_blk=lambda b, i, j: j)
+        q_blk=lambda b, i, j: i, kv_blk=lambda b, i, j: kv_of(i, j))
     out, lse = pl.pallas_call(
         kernel,
-        grid=(bh, n_q, n_kv),
+        grid=(bh, n_q, n_inner),
         in_specs=[
             pl.BlockSpec((1, bq_sz, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bkv_sz, d), lambda b, i, j: (b // rep, j, 0)),
-            pl.BlockSpec((1, bkv_sz, d), lambda b, i, j: (b // rep, j, 0)),
+            pl.BlockSpec((1, bkv_sz, d),
+                         lambda b, i, j: (b // rep, kv_of(i, j), 0)),
+            pl.BlockSpec((1, bkv_sz, d),
+                         lambda b, i, j: (b // rep, kv_of(i, j), 0)),
             *opt_specs,
         ],
         out_specs=[
@@ -283,32 +340,37 @@ def _flash_fwd(q, k, v, scale, causal, *, rep=1, kv_len=None, mask=None,
             _VMEM((bq_sz, 1), jnp.float32),
             _VMEM((bq_sz, d), jnp.float32),
         ],
-        name="flash_attn_fwd",
+        name="flash_attn_fwd" if window is None else "flash_attn_win_fwd",
         interpret=interpret_mode(),
     )(q, k, v, *opt_arrays)
     return out, lse[..., 0]
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                scale, causal, bq, bkv, kv_len, skv_pad, n_q,
-                has_mask, has_seg):
-    """Grid: (bh_kv, num_kv_blocks, rep·num_q_blocks); the innermost dim walks
-    every q block of every q head in the kv head's group (sequential)."""
+                scale, causal, bq, bkv, kv_len, skv_pad, n_q, n_inner,
+                has_mask, has_seg, window=None):
+    """Grid: (bh_kv, num_kv_blocks, rep·n_inner); the innermost dim walks
+    the q blocks (all ``n_inner = n_q`` of them, or under ``window`` the
+    band's, from ``_band_first_q`` on) of every q head in the kv head's
+    group (sequential)."""
     n_opt = int(has_mask) + 2 * int(has_seg)
     opt_refs = rest[:n_opt]
     dk_ref, dv_ref, dk_scr, dv_scr = rest[n_opt:]
     t = pl.program_id(2)
     kv_idx = pl.program_id(1)
-    q_idx = t % n_q
+    q_idx = t % n_inner
+    if window is not None:
+        q_idx += _band_first_q(kv_idx, bq, bkv)
 
     @pl.when(t == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    run = kv_idx * bkv < kv_len
-    if causal:
-        run &= (q_idx + 1) * bq - 1 >= kv_idx * bkv
+    run = _run_block(q_idx, kv_idx, causal=causal, bq=bq, bkv=bkv,
+                     kv_len=kv_len, window=window)
+    if window is not None:
+        run &= q_idx < n_q
 
     @pl.when(run)
     def _compute():
@@ -320,7 +382,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         delta = delta_ref[0]                      # [bq, 1]
         s = _masked_logits(q, k, opt_refs, q_idx, kv_idx, scale=scale,
                            causal=causal, bq=bq, bkv=bkv, kv_len=kv_len,
-                           skv_pad=skv_pad, has_mask=has_mask, has_seg=has_seg)
+                           skv_pad=skv_pad, has_mask=has_mask,
+                           has_seg=has_seg, window=window)
         p = _safe_exp(s, lse)                      # exact probs
         # dv += p^T @ do
         dv_scr[:] += jax.lax.dot_general(
@@ -339,21 +402,24 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-               scale, causal, bq, bkv, kv_len, skv_pad, has_mask, has_seg):
-    """Grid: (bh, num_q_blocks, num_kv_blocks); kv innermost (sequential)."""
+               scale, causal, bq, bkv, kv_len, skv_pad, has_mask, has_seg,
+               window=None):
+    """Grid: (bh, num_q_blocks, num_kv_blocks); kv innermost (sequential);
+    under ``window`` the band's kv blocks only, as in the forward."""
     n_opt = int(has_mask) + 2 * int(has_seg)
     opt_refs = rest[:n_opt]
     dq_ref, dq_scr = rest[n_opt:]
-    kv_idx = pl.program_id(2)
+    j = pl.program_id(2)
     q_idx = pl.program_id(1)
+    kv_idx = j if window is None else (
+        _band_first_kv(q_idx, bq, bkv, window) + j)
 
-    @pl.when(kv_idx == 0)
+    @pl.when(j == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    run = kv_idx * bkv < kv_len
-    if causal:
-        run &= (q_idx + 1) * bq - 1 >= kv_idx * bkv
+    run = _run_block(q_idx, kv_idx, causal=causal, bq=bq, bkv=bkv,
+                     kv_len=kv_len, window=window)
 
     @pl.when(run)
     def _compute():
@@ -365,7 +431,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         delta = delta_ref[0]
         s = _masked_logits(q, k, opt_refs, q_idx, kv_idx, scale=scale,
                            causal=causal, bq=bq, bkv=bkv, kv_len=kv_len,
-                           skv_pad=skv_pad, has_mask=has_mask, has_seg=has_seg)
+                           skv_pad=skv_pad, has_mask=has_mask,
+                           has_seg=has_seg, window=window)
         p = _safe_exp(s, lse)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
@@ -373,13 +440,14 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dq_scr[:] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    @pl.when(kv_idx == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _flash_bwd(q, k, v, out, lse, do, scale, causal, *, rep=1, kv_len=None,
-               mask=None, mask_idx=None, segs=None, batch_of=None, blocks=None):
+               mask=None, mask_idx=None, segs=None, batch_of=None, blocks=None,
+               window=None):
     """Pallas FlashAttention-2 backward; q/out/do: [bh, sq, d], k/v:
     [bh // rep, skv, d].  Returns (dq [bh,...], dk, dv [bh//rep,...]) — the
     group sum for GQA happens inside the dkv kernel's accumulator."""
@@ -394,25 +462,41 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, *, rep=1, kv_len=None,
                     axis=-1, keepdims=True)          # [bh, sq, 1]
     lse3 = lse[..., None]                             # [bh, sq, 1]
 
-    hq_of = lambda bh_kv, t: bh_kv * rep + t // n_q   # dkv grid → q-row index
+    if window is None:
+        kv_inner, q_inner = n_kv, n_q
+        kv_of = lambda i, j: j
+        q_of = lambda kv, t: t % n_q
+    else:
+        # the band's blocks only; an index past the band's end stays on
+        # the last block (no new copy) and the kernel skips the step
+        kv_inner, q_inner = _band_widths(n_q, n_kv, bq_sz, bkv_sz, window)
+        kv_of = lambda i, j: jnp.minimum(
+            _band_first_kv(i, bq_sz, bkv_sz, window) + j, n_kv - 1)
+        q_of = lambda kv, t: jnp.minimum(
+            _band_first_q(kv, bq_sz, bkv_sz) + t % q_inner, n_q - 1)
+
+    # dkv grid → q-row index
+    hq_of = lambda bh_kv, t: bh_kv * rep + t // q_inner
 
     common = dict(scale=scale, causal=causal, bq=bq_sz, bkv=bkv_sz,
-                  kv_len=kv_len, skv_pad=skv,
+                  kv_len=kv_len, skv_pad=skv, window=window,
                   has_mask=mask is not None, has_seg=segs is not None)
 
     # ---- dk/dv: grid (bh_kv, n_kv, rep·n_q), q innermost over the group ----
     # the optional-input index maps resolve the group-dependent q head first
-    q_spec = pl.BlockSpec((1, bq_sz, d), lambda b, kv, t: (hq_of(b, t), t % n_q, 0))
-    row_spec = pl.BlockSpec((1, bq_sz, 1), lambda b, kv, t: (hq_of(b, t), t % n_q, 0))
+    q_spec = pl.BlockSpec((1, bq_sz, d),
+                          lambda b, kv, t: (hq_of(b, t), q_of(kv, t), 0))
+    row_spec = pl.BlockSpec((1, bq_sz, 1),
+                            lambda b, kv, t: (hq_of(b, t), q_of(kv, t), 0))
     kv_spec = pl.BlockSpec((1, bkv_sz, d), lambda b, kv, t: (b, kv, 0))
     opt_arrays, opt_specs = _opt_specs(
         bq_sz, bkv_sz, mask, mask_idx, segs, batch_of,
-        q_blk=lambda b, kv, t: t % n_q, kv_blk=lambda b, kv, t: kv,
+        q_blk=lambda b, kv, t: q_of(kv, t), kv_blk=lambda b, kv, t: kv,
         head_of=lambda b, kv, t: hq_of(b, t))
 
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, n_q=n_q, **common),
-        grid=(bhkv, n_kv, rep * n_q),
+        functools.partial(_dkv_kernel, n_q=n_q, n_inner=q_inner, **common),
+        grid=(bhkv, n_kv, rep * q_inner),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec,
                   *opt_specs],
         out_specs=[kv_spec, kv_spec],
@@ -420,49 +504,54 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, *, rep=1, kv_len=None,
                    jax.ShapeDtypeStruct((bhkv, skv, d), v.dtype)],
         scratch_shapes=[_VMEM((bkv_sz, d), jnp.float32),
                         _VMEM((bkv_sz, d), jnp.float32)],
-        name="flash_attn_bwd_dkv",
+        name=("flash_attn_bwd_dkv" if window is None
+              else "flash_attn_win_bwd_dkv"),
         interpret=interpret_mode(),
     )(q, k, v, do, lse3, delta, *opt_arrays)
 
     # ---- dq: grid (bh, n_q, n_kv), kv innermost ----
     q_spec_i = pl.BlockSpec((1, bq_sz, d), lambda b, i, j: (b, i, 0))
-    kv_spec_j = pl.BlockSpec((1, bkv_sz, d), lambda b, i, j: (b // rep, j, 0))
+    kv_spec_j = pl.BlockSpec((1, bkv_sz, d),
+                             lambda b, i, j: (b // rep, kv_of(i, j), 0))
     row_spec_i = pl.BlockSpec((1, bq_sz, 1), lambda b, i, j: (b, i, 0))
     opt_arrays_q, opt_specs_q = _opt_specs(
         bq_sz, bkv_sz, mask, mask_idx, segs, batch_of,
-        q_blk=lambda b, i, j: i, kv_blk=lambda b, i, j: j)
+        q_blk=lambda b, i, j: i, kv_blk=lambda b, i, j: kv_of(i, j))
 
     dq, = pl.pallas_call(
         functools.partial(_dq_kernel, **common),
-        grid=(bh, n_q, n_kv),
+        grid=(bh, n_q, kv_inner),
         in_specs=[q_spec_i, kv_spec_j, kv_spec_j, q_spec_i, row_spec_i,
                   row_spec_i, *opt_specs_q],
         out_specs=[q_spec_i],
         out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype)],
         scratch_shapes=[_VMEM((bq_sz, d), jnp.float32)],
-        name="flash_attn_bwd_dq",
+        name=("flash_attn_bwd_dq" if window is None
+              else "flash_attn_win_bwd_dq"),
         interpret=interpret_mode(),
     )(q, k, v, do, lse3, delta, *opt_arrays_q)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11, 12))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(6, 7, 8, 9, 10, 11, 12, 13))
 def _flash_attention_core(q, k, v, mask, q_seg, kv_seg,
                           scale, causal, rep, kv_len, mask_idx, batch_of,
-                          blocks):
+                          blocks, window=None):
     out, _ = _flash_fwd(
         q, k, v, scale, causal, rep=rep, kv_len=kv_len, mask=mask,
         mask_idx=mask_idx, segs=(q_seg, kv_seg) if q_seg is not None else None,
-        batch_of=batch_of, blocks=blocks)
+        batch_of=batch_of, blocks=blocks, window=window)
     return out
 
 
 def _flash_core_fwd(q, k, v, mask, q_seg, kv_seg,
-                    scale, causal, rep, kv_len, mask_idx, batch_of, blocks):
+                    scale, causal, rep, kv_len, mask_idx, batch_of, blocks,
+                    window=None):
     out, lse = _flash_fwd(
         q, k, v, scale, causal, rep=rep, kv_len=kv_len, mask=mask,
         mask_idx=mask_idx, segs=(q_seg, kv_seg) if q_seg is not None else None,
-        batch_of=batch_of, blocks=blocks)
+        batch_of=batch_of, blocks=blocks, window=window)
     return out, (q, k, v, mask, q_seg, kv_seg, out, lse)
 
 
@@ -507,13 +596,13 @@ def _xla_mask_grad(q, k, v, out, lse, do, mask, mask_idx, segs, scale, causal,
 
 
 def _flash_core_bwd(scale, causal, rep, kv_len, mask_idx, batch_of, blocks,
-                    res, do):
+                    window, res, do):
     q, k, v, mask, q_seg, kv_seg, out, lse = res
     segs = (q_seg, kv_seg) if q_seg is not None else None
     dq, dk, dv = _flash_bwd(
         q, k, v, out, lse, do, scale, causal, rep=rep, kv_len=kv_len,
         mask=mask, mask_idx=mask_idx, segs=segs,
-        batch_of=batch_of, blocks=blocks)
+        batch_of=batch_of, blocks=blocks, window=window)
     zero = lambda x: None if x is None else jnp.zeros_like(x)
     if mask is not None and jnp.issubdtype(mask.dtype, jnp.inexact):
         dmask = _xla_mask_grad(q, k, v, out, lse, do, mask, mask_idx, segs,
@@ -549,7 +638,7 @@ def _normalize_mask(attn_mask, b, hq, sq, skv):
 
 
 def flash_attention_bshd(q, k, v, attn_mask=None, causal=False, scale=None,
-                         segment_ids=None):
+                         segment_ids=None, window=None):
     """Public entry: q,k,v [batch, seq, heads, head_dim] (paddle layout).
 
     GQA/MQA: kv heads are indexed per group inside the kernel grid — grouped
@@ -557,9 +646,23 @@ def flash_attention_bshd(q, k, v, attn_mask=None, causal=False, scale=None,
     or additive) streams through the kernel tile-by-tile.  ``segment_ids``
     (a [b, s] int array, or a (q_ids, kv_ids) pair) implements packed/varlen
     attention (reference: flash_attn_varlen cu_seqlens).  Arbitrary sequence
-    lengths are padded to the block grid and masked in-kernel."""
+    lengths are padded to the block grid and masked in-kernel.  ``window``
+    (causal self-attention only, no mask or segments): a position sees
+    itself and the ``window - 1`` before it, and the kernels walk the band's
+    blocks only."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    if window is not None:
+        if not causal or attn_mask is not None or segment_ids is not None:
+            raise ValueError("window= is causal self-attention's band: it "
+                             "needs causal=True and takes no attn_mask or "
+                             "segment_ids")
+        if q.shape[1] != k.shape[1]:
+            raise ValueError(f"window= needs equal q and kv lengths, got "
+                             f"{q.shape[1]} and {k.shape[1]}")
+        window = int(window)
+        if window < 1:
+            raise ValueError(f"window must be >= 1, got {window}")
     if attn_mask is None and segment_ids is None:
         # on a mesh of several devices (ops.pallas.spmd_kernels) every
         # (batch, head) pair is independent: each device attends its own
@@ -567,14 +670,14 @@ def flash_attention_bshd(q, k, v, attn_mask=None, causal=False, scale=None,
         bshd = lambda batch, heads: P(batch, None, heads, None)
         return per_shard(
             functools.partial(_flash_attention_bshd, causal=causal,
-                              scale=scale),
+                              scale=scale, window=window),
             lambda b, h: ((bshd(b, h),) * 3, bshd(b, h)))(q, k, v)
     return _flash_attention_bshd(q, k, v, attn_mask, causal, scale,
                                  segment_ids)
 
 
 def _flash_attention_bshd(q, k, v, attn_mask=None, causal=False, scale=None,
-                          segment_ids=None):
+                          segment_ids=None, window=None):
     b, sq, hq, d = q.shape
     hkv = k.shape[2]
     skv = k.shape[1]
@@ -595,7 +698,7 @@ def _flash_attention_bshd(q, k, v, attn_mask=None, causal=False, scale=None,
                 attn_mask = jnp.logical_and(attn_mask, seg_ok)
             else:
                 attn_mask = attn_mask + jnp.where(seg_ok, 0.0, NEG_INF)
-        return _composed_attention(q, k, v, attn_mask, causal, scale)
+        return _composed_attention(q, k, v, attn_mask, causal, scale, window)
     KERNEL_CALLS += 1
     rep = hq // hkv
 
@@ -632,12 +735,12 @@ def _flash_attention_bshd(q, k, v, attn_mask=None, causal=False, scale=None,
 
     out = _flash_attention_core(qh, kh, vh, mask, q_seg, kv_seg,
                                 scale, causal, rep, skv, mask_idx, batch_of,
-                                (bq_sz, bkv_sz))
+                                (bq_sz, bkv_sz), window)
     out = out[:, :sq]
     return out.reshape(b, hq, sq, d).transpose(0, 2, 1, 3)
 
 
-def _composed_attention(q, k, v, attn_mask, causal, scale):
+def _composed_attention(q, k, v, attn_mask, causal, scale, window=None):
     qh, kh, vh = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
     if kh.shape[1] != qh.shape[1]:
         rep = qh.shape[1] // kh.shape[1]
@@ -650,6 +753,8 @@ def _composed_attention(q, k, v, attn_mask, causal, scale):
     logits = jnp.einsum("bhqd,bhkd->bhqk", qh.astype(jnp.float32), kh.astype(jnp.float32)) * scale
     if causal:
         m = jnp.tril(jnp.ones((logits.shape[-2], logits.shape[-1]), bool))
+        if window is not None:
+            m &= ~jnp.tril(m, -window)
         logits = jnp.where(m, logits, NEG_INF)
     if attn_mask is not None:
         if attn_mask.dtype == jnp.bool_:

@@ -10,19 +10,66 @@ rope+attention prologue later."""
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 
 
-def rope_cos_sin(seq_len, head_dim, base=10000.0, position_ids=None, dtype=jnp.float32):
-    inv_freq = 1.0 / (base ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_inv_freq(inv_freq, rotary_dim, base, factor,
+                  original_max_position_embeddings, beta_fast=32.0,
+                  beta_slow=1.0):
+    """YaRN's blended inverse frequencies: dimension ``i`` keeps its own
+    frequency below the correction dimension of ``beta_fast`` rotations,
+    takes ``1 / factor`` of it above that of ``beta_slow``, and is blended
+    linearly between (Peng et al. 2023, the Hugging Face ``yarn``
+    rope_type, ``truncate`` on)."""
+
+    def correction_dim(rotations):
+        return (rotary_dim * math.log(original_max_position_embeddings
+                                      / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), rotary_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(rotary_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return (inv_freq / factor) * ramp + inv_freq * (1.0 - ramp)
+
+
+def yarn_attention_factor(factor):
+    return 0.1 * math.log(factor) + 1.0
+
+
+def rope_cos_sin(seq_len, head_dim, base=10000.0, position_ids=None,
+                 dtype=jnp.float32, rotary_dim=None, yarn=None):
+    """cos/sin tables [b_or_1, s, rotary_dim].  ``rotary_dim`` (default: the
+    whole head) is how many leading dimensions of the head rotate;
+    ``yarn`` (``factor``, ``original_max_position_embeddings`` and
+    optionally ``beta_fast``, ``beta_slow``, ``attention_factor``) blends
+    the frequencies and scales cos and sin by the attention factor."""
+    r = head_dim if rotary_dim is None else int(rotary_dim)
+    inv_freq = 1.0 / (base ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r))
+    scale = None
+    if yarn is not None:
+        inv_freq = yarn_inv_freq(
+            inv_freq, r, base, yarn["factor"],
+            yarn["original_max_position_embeddings"],
+            yarn.get("beta_fast", 32.0), yarn.get("beta_slow", 1.0))
+        scale = (yarn.get("attention_factor")
+                 or yarn_attention_factor(yarn["factor"]))
     pos = (
         jnp.arange(seq_len, dtype=jnp.float32)[None, :]
         if position_ids is None
         else position_ids.astype(jnp.float32)
     )
-    freqs = jnp.einsum("bs,d->bsd", pos, inv_freq)  # [b, s, d/2]
+    freqs = jnp.einsum("bs,d->bsd", pos, inv_freq)  # [b, s, r/2]
     emb = jnp.concatenate([freqs, freqs], axis=-1)
-    return jnp.cos(emb).astype(dtype), jnp.sin(emb).astype(dtype)
+    if scale is None:
+        return jnp.cos(emb).astype(dtype), jnp.sin(emb).astype(dtype)
+    return ((jnp.cos(emb) * scale).astype(dtype),
+            (jnp.sin(emb) * scale).astype(dtype))
 
 
 def _rotate_half(x):
@@ -30,13 +77,23 @@ def _rotate_half(x):
     return jnp.concatenate([-x2, x1], axis=-1)
 
 
+def _rotate(t, c, s):
+    """Rotate the leading ``c.shape[-1]`` dimensions of the head, pass the
+    rest through (partial rotary)."""
+    r = c.shape[-1]
+    if r == t.shape[-1]:
+        return t * c + _rotate_half(t) * s
+    tr = t[..., :r]
+    tr = (tr * c + _rotate_half(tr) * s).astype(t.dtype)
+    return jnp.concatenate([tr, t[..., r:]], axis=-1)
+
+
 def apply_rotary_pos_emb(q, k, cos, sin):
-    """q,k: [b, s, h, d]; cos,sin: [b_or_1, s, d] → broadcast over heads."""
+    """q,k: [b, s, h, d]; cos,sin: [b_or_1, s, r] (r <= d: the leading r
+    dimensions rotate) → broadcast over heads."""
     c = cos[:, :, None, :]
     s = sin[:, :, None, :]
-    q2 = q * c + _rotate_half(q) * s
-    k2 = k * c + _rotate_half(k) * s
-    return q2.astype(q.dtype), k2.astype(k.dtype)
+    return _rotate(q, c, s).astype(q.dtype), _rotate(k, c, s).astype(k.dtype)
 
 
 def fused_rotary_position_embedding(

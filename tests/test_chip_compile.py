@@ -190,3 +190,25 @@ def test_flash_attention_seq2048(one_chip, compiled_kernels, grad):
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
     _compile(fn, one_chip, ((2, 2048, NH, HD), BF16),
              ((2, 2048, NKV, HD), BF16), ((2, 2048, NKV, HD), BF16))
+
+
+@pytest.mark.parametrize("heads,window", [(64, 512), (48, None)],
+                         ids=["window512x64", "full48"])
+def test_flash_attention_seq8192_laguna_widths(one_chip, compiled_kernels,
+                                               heads, window):
+    """Laguna-XS.2's two kinds of layer at 8,192 positions: the banded
+    kernels (64 query heads over 8 KV heads, window 512) and the full ones
+    (48 heads), forward and all three gradients."""
+    def loss(q, k, v):
+        return fa.flash_attention_bshd(q, k, v, causal=True,
+                                       window=window).astype(
+                                           jnp.float32).sum()
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                        ((1, 8192, heads, HD), BF16),
+                        ((1, 8192, NKV, HD), BF16),
+                        ((1, 8192, NKV, HD), BF16))
+    names = ("flash_attn_win_fwd", "flash_attn_win_bwd_dkv",
+             "flash_attn_win_bwd_dq")
+    assert all((n in compiled.as_text()) == (window is not None)
+               for n in names)

@@ -56,6 +56,67 @@ def test_flash_backward_parity(b, s, h, d, causal):
         assert err < 2e-3, f"d{name} rel err {err}"
 
 
+@pytest.mark.parametrize("s,window", [
+    (384, 64),      # smaller than the 128 block
+    (384, 128),     # one block
+    (384, 200),     # wider than a block, no multiple of it
+    (300, 100),     # a padded last block
+    (256, 1000),    # wider than the sequence: plain causal
+])
+def test_flash_window_forward_and_backward_parity(s, window):
+    """The banded calls (a grid over the band's blocks only) against
+    composed attention under the same causal window, GQA 4 over 2: the
+    forward and all three gradients."""
+    rs = np.random.RandomState(0)
+    q = _rand(rs, 2, s, 4, 16)
+    k, v = _rand(rs, 2, s, 2, 16), _rand(rs, 2, s, 2, 16)
+    do = _rand(rs, 2, s, 4, 16)
+    scale = 1.0 / np.sqrt(16)
+    flash = lambda q, k, v: fa.flash_attention_bshd(q, k, v, causal=True,
+                                                    window=window)
+    plain = lambda q, k, v: fa._composed_attention(q, k, v, None, True,
+                                                   scale, window)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(plain(q, k, v)),
+                               rtol=1e-5, atol=1e-5)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * do), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(plain(*a) * do), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_flash_window_walks_the_band_only_and_is_named():
+    """A windowed call's grids cover the band's blocks (s x w work, not
+    s^2 / 2) under names of their own; a call without a window keeps its
+    names and its full grid; a window sees exactly ``window`` positions."""
+    from paddle_tpu.analysis.kernel_contracts import _pallas_eqns
+
+    q = jnp.zeros((1, 1024, 2, 16), jnp.float32)
+    loss = lambda w: (lambda q: jnp.sum(fa.flash_attention_bshd(
+        q, q, q, causal=True, window=w)))
+    grids = lambda w: {e.params["name"]: tuple(e.params["grid_mapping"].grid)
+                       for e in _pallas_eqns(jax.make_jaxpr(
+                           jax.grad(loss(w)))(q))}
+    # 1024 positions in blocks of 512
+    assert grids(None) == {"flash_attn_fwd": (2, 2, 2),
+                           "flash_attn_bwd_dkv": (2, 2, 2),
+                           "flash_attn_bwd_dq": (2, 2, 2)}
+    full = grids(None)
+    q = jnp.zeros((1, 4096, 2, 16), jnp.float32)
+    assert grids(512) == {"flash_attn_win_fwd": (2, 8, 2),
+                          "flash_attn_win_bwd_dkv": (2, 8, 2),
+                          "flash_attn_win_bwd_dq": (2, 8, 2)}
+    assert grids(None)["flash_attn_fwd"] == (2, 8, 8) and full
+    # position i's output under window w depends on i-w+1 .. i only
+    x = jnp.asarray(np.random.RandomState(1).randn(1, 256, 1, 8), jnp.float32)
+    seen = jax.jacobian(lambda v: fa.flash_attention_bshd(
+        x, x, v, causal=True, window=3)[0, 200, 0, 0])(x)[0, :, 0, 0]
+    assert np.flatnonzero(np.asarray(seen)).tolist() == [198, 199, 200]
+    with pytest.raises(ValueError, match="causal"):
+        fa.flash_attention_bshd(x, x, x, causal=False, window=3)
+
+
 def test_flash_gqa_grouped_heads():
     rs = np.random.RandomState(2)
     q = _rand(rs, 2, 128, 8, 32)
