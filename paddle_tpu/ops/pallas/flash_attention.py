@@ -30,6 +30,44 @@ gridded over kv blocks with (group, q) innermost, and a dq kernel gridded over
 q blocks with kv innermost.  Per-tile probabilities are recomputed exactly
 from the saved log-sum-exp; delta = rowsum(dO·O) is precomputed in XLA
 (O(s·d)).  Block sizes are chosen per-call from a VMEM budget.
+
+Precision: the MXU products take their operands in the dtype the inputs
+arrive in and accumulate in float32.  ``Q·Kᵀ`` and ``dO·Vᵀ`` multiply the
+inputs themselves (a bf16 x bf16 product is exact in float32).  The four
+products whose left side the kernel makes (``P·V``, ``Pᵀ·dO``, ``dSᵀ·Q``,
+``dS·K``) round that side to the right side's dtype once, after all float32
+arithmetic on it (``exp``, ``p·(dp − delta)·scale``) is done; ``scale``
+multiplies the float32 logits, never a bf16 ``q``.  Logits, the running max
+and sum, ``lse``, ``delta`` and the accumulators are float32 throughout.
+With float32 inputs no cast is made and the products are float32 x float32
+at the default precision.  On the TPU that is one bf16 pass as well: Mosaic
+rounds a float32 operand to bf16 on its way into the MXU, so handing it bf16
+gives the same bits in the same time (measured, PERF.md §6 PR 29); what the
+explicit casts buy is that the jaxpr says what the chip does, on any backend.
+
+Tiles: a block that ``_run_block`` lets run is an *edge* block (the causal
+diagonal, the window's far edge or the KV length falls inside it; or the
+call streams a mask or segment ids, then every block is) or an *interior*
+one.  Each kernel holds a body for either: the edge body builds the masks
+and guards ``exp`` against fully masked rows, the interior body is the
+products and a plain ``exp(s − m)``.  ``tile_census`` counts both kinds from
+the shapes; the kernel path's last call leaves its census and operand dtype
+in ``LAST_CALL``.
+
+Layout, which is where the time was (read off the TPU compiler's bundle
+schedule, PERF.md §6 PR 29).  A per-row statistic kept as a column [bq, 1]
+sits on one lane of each vreg and has to be spread over the lanes (an XLU
+permute) every time it meets ``s`` or an accumulator, and a column block
+[bq, 1] of an HBM array fills whole 128-lane tiles: 256 KB copied for 2 KB
+of numbers.  So: the forward keeps its running max and sum [bq, 128] with
+all lanes alike, as they come off the lane reductions; the dk/dv kernel
+computes its tile by kv rows ([bkv, bq] = K·Qᵀ), where ``lse`` and ``delta``
+are rows that spread over sublanes for nothing, dv and dk contract over the
+tile's lanes, and neither ``P`` nor ``dS`` is transposed; the dq kernel takes
+the same rows and stands them up once a q block as [bq, 128] columns with
+all lanes alike; a grid step that a kernel skips keeps the block index of a
+step that runs (``_walks``), so nothing is copied for it.  None of this
+changes a number: the same sums in the same order.
 """
 
 from __future__ import annotations
@@ -39,6 +77,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
@@ -50,11 +89,16 @@ from . import interpret_mode, kernel_disabled, per_shard
 _VMEM = pltpu.VMEM
 
 NEG_INF = -1e30
+_LANES = 128        # a vreg's lanes: the width per-row statistics are kept at
 
 # trace-time counters: how often the public entry took the Pallas kernel path
 # vs the composed-XLA fallback (bench.py records both in its detail output)
 KERNEL_CALLS = 0
 FALLBACK_CALLS = 0
+# the kernel path's last call, written as it is traced: ``tiles``, the
+# (skipped, edge, interior) blocks of one (head, pass) (``tile_census``), and
+# ``operands``, the dtype its MXU products take (bfloat16: one pass each)
+LAST_CALL: dict | None = None
 
 # VMEM working-set budget for block-size selection (per-core VMEM is ~16 MiB;
 # leave headroom for the pipeline's double buffering and the compiler)
@@ -78,15 +122,16 @@ def _pick_block(seq: int, cap: int) -> int:
 
 
 def _pick_blocks(sq: int, skv: int, d: int, has_mask: bool) -> tuple[int, int]:
-    """(bq, bkv) under the VMEM budget.  Working set per grid step (fp32,
-    double-buffered inputs): q + 2·kv + optional mask tile + s/p intermediates
-    + accumulators."""
+    """(bq, bkv) under the VMEM budget.  Working set per grid step, reckoned
+    at 4 bytes an element whatever the inputs' dtype (bf16 tiles take half
+    of their part): double-buffered q + 2·kv + optional mask tile, the
+    float32 s/p intermediates and the float32 accumulators."""
     cap = 512
 
     def fits(bq, bkv):
         inputs = 2 * (bq * d + 2 * bkv * d) * 4          # double-buffered
         mask_b = 2 * bq * bkv * 4 if has_mask else 0
-        scratch = (bq * d + 2 * bq) * 4
+        scratch = (bq * d + 2 * bq * _LANES) * 4
         inter = 3 * bq * bkv * 4                          # s, p, selects
         return inputs + mask_b + scratch + inter <= _VMEM_BUDGET
 
@@ -126,30 +171,36 @@ def _tile_mask(s, mask_blk):
     return s + mask_blk.astype(jnp.float32)
 
 
-def _seg_mask(s, q_seg, kv_seg):
-    """Packed-sequence mask: attend only within equal segment ids.
-    Seg refs are [1, blk, 1] (trailing singleton keeps Mosaic's last-two-dims
-    block constraint satisfiable)."""
-    return jnp.where(q_seg[0, :, 0][:, None] == kv_seg[0, :, 0][None, :],
-                     s, NEG_INF)
+def _seg_mask(s, q_seg, kv_seg, by_kv):
+    """Packed-sequence mask: attend only within equal segment ids.  One
+    side's ids arrive as a column [1, blk, 1] (trailing singleton keeps
+    Mosaic's last-two-dims block constraint satisfiable), the side along the
+    tile's lanes (kv; q on a ``by_kv`` tile) as a row [1, 1, blk]."""
+    if by_kv:
+        same = kv_seg[0, :, 0][:, None] == q_seg[0, 0, :][None, :]
+    else:
+        same = q_seg[0, :, 0][:, None] == kv_seg[0, 0, :][None, :]
+    return jnp.where(same, s, NEG_INF)
 
 
-def _bounds_mask(s, kv_idx, bkv, kv_len):
-    """Mask padded KV columns (seq padded up to the block grid)."""
-    cols = kv_idx * bkv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(cols < kv_len, s, NEG_INF)
-
-
-def _causal_mask(s, q_idx, bq, kv_idx, bkv):
-    rows = q_idx * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    cols = kv_idx * bkv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(rows >= cols, s, NEG_INF)
-
-
-def _window_mask(s, q_idx, bq, kv_idx, bkv, window):
-    rows = q_idx * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    cols = kv_idx * bkv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(rows - cols < window, s, NEG_INF)
+def _position_masks(s, q_idx, kv_idx, by_kv, *, causal, bq, bkv, kv_len,
+                    skv_pad, window):
+    """The causal diagonal, the window's far edge and the padded KV columns
+    (seq padded up to the block grid) on one tile; q runs along axis 0, or
+    along axis 1 on a ``by_kv`` tile."""
+    if not causal and window is None and kv_len == skv_pad:
+        return s
+    q_ax = 1 if by_kv else 0
+    rows = q_idx * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_ax)
+    cols = kv_idx * bkv + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                   1 - q_ax)
+    if causal:
+        s = jnp.where(rows >= cols, s, NEG_INF)
+    if window is not None:
+        s = jnp.where(rows - cols < window, s, NEG_INF)
+    if kv_len != skv_pad:
+        s = jnp.where(cols < kv_len, s, NEG_INF)
+    return s
 
 
 def _band_first_kv(q_idx, bq, bkv, window):
@@ -173,6 +224,30 @@ def _band_widths(n_q, n_kv, bq, bkv, window):
     return kv_per_q, q_per_kv
 
 
+def _walks(n_q, n_kv, bq, bkv, causal, window):
+    """How the kernels' innermost grid dimension walks the blocks:
+    (kv steps a q block, (q block, step) -> kv block, q steps a kv block,
+    (kv block, step) -> q block).  Without a window a step is a block; under
+    one the steps are the band's blocks only.  A step that the kernel skips
+    (above the diagonal, past the band's end) is given the index of the
+    nearest block that runs, which is already there: no copy is made for
+    it."""
+    last_kv = lambda i: ((i + 1) * bq - 1) // bkv    # the diagonal's block
+    if window is None:
+        kv_of = ((lambda i, j: jnp.minimum(j, last_kv(i))) if causal
+                 else (lambda i, j: j))
+        q_of = ((lambda kv, t: jnp.clip(t % n_q, _band_first_q(kv, bq, bkv),
+                                        n_q - 1))
+                if causal else (lambda kv, t: t % n_q))
+        return n_kv, kv_of, n_q, q_of
+    kv_inner, q_inner = _band_widths(n_q, n_kv, bq, bkv, window)
+    kv_of = lambda i, j: jnp.minimum(
+        _band_first_kv(i, bq, bkv, window) + j, last_kv(i))
+    q_of = lambda kv, t: jnp.minimum(
+        _band_first_q(kv, bq, bkv) + t % q_inner, n_q - 1)
+    return kv_inner, kv_of, q_inner, q_of
+
+
 def _run_block(q_idx, kv_idx, *, causal, bq, bkv, kv_len, window):
     """Whole-block skips: padded KV blocks (fully out of range), causal
     (block fully above the diagonal) and, under a window, blocks fully
@@ -185,30 +260,96 @@ def _run_block(q_idx, kv_idx, *, causal, bq, bkv, kv_len, window):
     return run
 
 
-def _masked_logits(q, k, refs, q_idx, kv_idx, *, scale, causal, bq, bkv,
-                   kv_len, skv_pad, has_mask, has_seg, window=None):
-    """Shared fwd/bwd logits tile: QK^T · scale with all masks applied.
-    ``refs`` holds the optional (mask, q_seg, kv_seg) refs in order."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
+def _edge_block(q_idx, kv_idx, *, causal, bq, bkv, kv_len, skv_pad, window,
+                masked):
+    """Whether a mask edge crosses a block that ``_run_block`` lets run: the
+    causal diagonal, the window's far edge or ``kv_len`` falls inside it, or
+    the call streams a mask or segment ids (``masked``: every block then).
+    A block it clears is interior: every logit in it is kept.  A Python bool
+    where the call's arguments alone decide it."""
+    if masked:
+        return True
+    edge = False
+    if causal:
+        edge |= q_idx * bq < (kv_idx + 1) * bkv - 1
+    if window is not None:
+        edge |= (q_idx + 1) * bq - 1 - kv_idx * bkv >= window
+    if kv_len != skv_pad:
+        edge |= (kv_idx + 1) * bkv > kv_len
+    return edge
+
+
+def tile_census(sq, skv, bq, bkv, causal, window=None, kv_len=None,
+                masked=False):
+    """(skipped, edge, interior) blocks of one (head, pass) of a call over
+    ``sq`` x ``skv`` positions in blocks of ``bq`` x ``bkv``, by the
+    predicates the kernels branch on: skipped blocks do no work, edge blocks
+    run the masked body, interior blocks the plain one.  A fact of the
+    shapes, so it is counted here and not at run time."""
+    n_q, n_kv = pl.cdiv(sq, bq), pl.cdiv(skv, bkv)
+    i, j = np.indices((n_q, n_kv))
+    geom = dict(causal=causal, bq=bq, bkv=bkv, window=window,
+                kv_len=skv if kv_len is None else kv_len)
+    run = _run_block(i, j, **geom)
+    edge = run & _edge_block(i, j, skv_pad=n_kv * bkv, masked=masked, **geom)
+    return tuple(int(n) for n in (run.size - run.sum(), edge.sum(),
+                                  run.sum() - edge.sum()))
+
+
+def _when_tile(run, edge, body):
+    """``body(edge)`` on a block that runs: the masked body where an edge
+    crosses it, the plain one elsewhere; one body only where ``edge`` is
+    known when the kernel is traced."""
+    if isinstance(edge, bool):
+        pl.when(run)(functools.partial(body, edge))
+    else:
+        pl.when(run & edge)(functools.partial(body, True))
+        pl.when(run & ~edge)(functools.partial(body, False))
+
+
+def _dot(a, b, dims):
+    """MXU product accumulated in float32, the operands in the dtype they
+    come in (bf16 x bf16 is one pass, and exact in float32); where the two
+    differ the narrower is widened, which is exact too."""
+    dt = jnp.promote_types(a.dtype, b.dtype)
+    return jax.lax.dot_general(a.astype(dt), b.astype(dt), (dims, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _logits(q, k, refs, q_idx, kv_idx, *, edge, scale, causal, bq, bkv,
+            kv_len, skv_pad, has_mask, has_seg, window=None, by_kv=False):
+    """Shared fwd/bwd logits tile: QK^T · scale [bq, bkv] (``by_kv``: KQ^T,
+    the same tile transposed, which the dk/dv kernel accumulates from
+    without transposing anything), with all masks applied on an ``edge``
+    block and none on an interior one.  ``refs`` holds the optional (mask,
+    q_seg, kv_seg) refs in order, the mask tile in the tile's orientation."""
+    s = (_dot(k, q, ((1,), (1,))) if by_kv else _dot(q, k, ((1,), (1,)))) * scale
+    if not edge:
+        return s
     it = iter(refs)
     if has_mask:
         s = _tile_mask(s, next(it)[0])
     if has_seg:
-        s = _seg_mask(s, next(it), next(it))
-    if causal:
-        s = _causal_mask(s, q_idx, bq, kv_idx, bkv)
-    if window is not None:
-        s = _window_mask(s, q_idx, bq, kv_idx, bkv, window)
-    if kv_len != skv_pad:
-        s = _bounds_mask(s, kv_idx, bkv, kv_len)
-    return s
+        s = _seg_mask(s, next(it), next(it), by_kv)
+    return _position_masks(s, q_idx, kv_idx, by_kv, causal=causal, bq=bq,
+                           bkv=bkv, kv_len=kv_len, skv_pad=skv_pad,
+                           window=window)
 
 
-def _safe_exp(s, shift):
-    """exp(s - shift) that is exactly 0 for fully-masked entries even when the
-    running max / lse is itself NEG_INF (avoids exp(-inf + inf) = 1)."""
+def _lanes(x, n):
+    """[rows, _LANES] whose lanes are all alike -> [rows, n]."""
+    if n > _LANES:
+        x = jnp.tile(x, (1, pl.cdiv(n, _LANES)))
+    return x[:, :n]
+
+
+def _exp(s, shift, edge):
+    """exp(s - shift).  On an ``edge`` block it is exactly 0 for masked
+    entries even when the running max / lse is itself NEG_INF (avoids
+    exp(-inf + inf) = 1); on an interior block every logit is finite and
+    NEG_INF is too, so the plain difference is safe."""
+    if not edge:
+        return jnp.exp(s - shift)
     return jnp.where(s > 0.5 * NEG_INF, jnp.exp(s - shift), 0.0)
 
 
@@ -231,58 +372,71 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bkv, kv_len,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    run = _run_block(q_idx, kv_idx, causal=causal, bq=bq, bkv=bkv,
-                     kv_len=kv_len, window=window)
+    geom = dict(causal=causal, bq=bq, bkv=bkv, kv_len=kv_len, window=window)
+    run = _run_block(q_idx, kv_idx, **geom)
+    edge = _edge_block(q_idx, kv_idx, skv_pad=skv_pad,
+                       masked=has_mask or has_seg, **geom)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)  # [bq, d]
-        k = k_ref[0].astype(jnp.float32)  # [bkv, d]
-        v = v_ref[0].astype(jnp.float32)  # [bkv, d]
-        s = _masked_logits(q, k, opt_refs, q_idx, kv_idx, scale=scale,
-                           causal=causal, bq=bq, bkv=bkv, kv_len=kv_len,
-                           skv_pad=skv_pad, has_mask=has_mask,
-                           has_seg=has_seg, window=window)
-        m_prev = m_scr[:]  # [bq, 1]
+    def _compute(edge):
+        q = q_ref[0]  # [bq, d]
+        k = k_ref[0]  # [bkv, d]
+        v = v_ref[0]  # [bkv, d]
+        s = _logits(q, k, opt_refs, q_idx, kv_idx, edge=edge, scale=scale,
+                    skv_pad=skv_pad, has_mask=has_mask, has_seg=has_seg,
+                    **geom)
+        # m and l are kept [bq, 128] with all lanes alike: a row's max and
+        # sum come off the lane reduction that way, and nothing has to be
+        # spread back over the lanes to meet s or the accumulator
+        m_prev = m_scr[:]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        p = _safe_exp(s, m_new)  # [bq, bkv]
-        alpha = _safe_exp(m_prev, m_new)  # [bq, 1]
-        l_new = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        p = _exp(s, _lanes(m_new, s.shape[1]), edge)  # [bq, bkv]
+        alpha = _exp(m_prev, m_new, edge)
+        l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * _lanes(alpha, v.shape[1]) + _dot(
+            p.astype(v.dtype), v, ((1,), (0,)))
         m_scr[:] = m_new
-        l_scr[:] = l_new
+
+    _when_tile(run, edge, _compute)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
         l = l_scr[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[:] + jnp.log(l_safe)  # [bq, 1]
+        o_ref[0] = (acc_scr[:] / _lanes(l_safe, acc_scr.shape[1])).astype(
+            o_ref.dtype)
+        lse_ref[0] = (m_scr[:] + jnp.log(l_safe))[:, :1]  # [bq, 1]
 
 
 def _opt_specs(bq, bkv, mask, mask_idx, segs, batch_of, q_blk, kv_blk,
-               head_of=None):
+               head_of=None, by_kv=False):
     """(arrays, in_specs) for the optional streamed inputs, shared by the three
     kernels.  ``q_blk``/``kv_blk``: grid position → (q block, kv block);
     ``head_of``: grid position → q-head row (defaults to grid dim 0; the dkv
-    kernel resolves it from its (kv-head, group·q) walk)."""
+    kernel resolves it from its (kv-head, group·q) walk).  ``by_kv``: the
+    kernel's tiles are [bkv, bq] (the dkv kernel's): the mask is streamed
+    transposed, and q's segment ids lie along the lanes, not kv's."""
     head_of = head_of or (lambda *g: g[0])
     arrays, specs = [], []
     if mask is not None:
-        arrays.append(mask)
+        (b0, of0), (b1, of1) = (((bkv, kv_blk), (bq, q_blk)) if by_kv
+                                else ((bq, q_blk), (bkv, kv_blk)))
+        arrays.append(mask.swapaxes(1, 2) if by_kv else mask)
         specs.append(pl.BlockSpec(
-            (1, bq, bkv),
-            lambda *g: (mask_idx(head_of(*g)), q_blk(*g), kv_blk(*g))))
+            (1, b0, b1),
+            lambda *g: (mask_idx(head_of(*g)), of0(*g), of1(*g))))
     if segs is not None:
-        q_seg, kv_seg = segs
-        arrays += [q_seg, kv_seg]
-        specs.append(pl.BlockSpec(
-            (1, bq, 1), lambda *g: (batch_of(head_of(*g)), q_blk(*g), 0)))
-        specs.append(pl.BlockSpec(
-            (1, bkv, 1), lambda *g: (batch_of(head_of(*g)), kv_blk(*g), 0)))
+        q_seg, kv_seg = segs              # [b, s, 1] columns
+        col = lambda blk, of: pl.BlockSpec(
+            (1, blk, 1), lambda *g: (batch_of(head_of(*g)), of(*g), 0))
+        row = lambda blk, of: pl.BlockSpec(
+            (1, 1, blk), lambda *g: (batch_of(head_of(*g)), 0, of(*g)))
+        if by_kv:
+            arrays += [q_seg.swapaxes(1, 2), kv_seg]
+            specs += [row(bq, q_blk), col(bkv, kv_blk)]
+        else:
+            arrays += [q_seg, kv_seg.swapaxes(1, 2)]
+            specs += [col(bq, q_blk), row(bkv, kv_blk)]
     return arrays, specs
 
 
@@ -305,14 +459,7 @@ def _flash_fwd(q, k, v, scale, causal, *, rep=1, kv_len=None, mask=None,
         kv_len=kv_len, skv_pad=skv, has_mask=mask is not None,
         has_seg=segs is not None, window=window,
     )
-    if window is None:
-        n_inner, kv_of = n_kv, lambda i, j: j
-    else:
-        # the band's kv blocks only; past the diagonal the index stays on
-        # the last block (no new copy) and the kernel skips the step
-        n_inner = _band_widths(n_q, n_kv, bq_sz, bkv_sz, window)[0]
-        kv_of = lambda i, j: jnp.minimum(
-            _band_first_kv(i, bq_sz, bkv_sz, window) + j, n_kv - 1)
+    n_inner, kv_of, _, _ = _walks(n_q, n_kv, bq_sz, bkv_sz, causal, window)
     opt_arrays, opt_specs = _opt_specs(
         bq_sz, bkv_sz, mask, mask_idx, segs, batch_of,
         q_blk=lambda b, i, j: i, kv_blk=lambda b, i, j: kv_of(i, j))
@@ -336,8 +483,8 @@ def _flash_fwd(q, k, v, scale, causal, *, rep=1, kv_len=None, mask=None,
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            _VMEM((bq_sz, 1), jnp.float32),
-            _VMEM((bq_sz, 1), jnp.float32),
+            _VMEM((bq_sz, _LANES), jnp.float32),
+            _VMEM((bq_sz, _LANES), jnp.float32),
             _VMEM((bq_sz, d), jnp.float32),
         ],
         name="flash_attn_fwd" if window is None else "flash_attn_win_fwd",
@@ -352,7 +499,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     """Grid: (bh_kv, num_kv_blocks, rep·n_inner); the innermost dim walks
     the q blocks (all ``n_inner = n_q`` of them, or under ``window`` the
     band's, from ``_band_first_q`` on) of every q head in the kv head's
-    group (sequential)."""
+    group (sequential).  Its tiles are [bkv, bq]: ``lse`` and ``delta``
+    arrive as rows [1, 1, bq], the optional inputs as ``_opt_specs(by_kv=
+    True)`` lays them."""
     n_opt = int(has_mask) + 2 * int(has_seg)
     opt_refs = rest[:n_opt]
     dk_ref, dv_ref, dk_scr, dv_scr = rest[n_opt:]
@@ -367,33 +516,32 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    run = _run_block(q_idx, kv_idx, causal=causal, bq=bq, bkv=bkv,
-                     kv_len=kv_len, window=window)
+    geom = dict(causal=causal, bq=bq, bkv=bkv, kv_len=kv_len, window=window)
+    run = _run_block(q_idx, kv_idx, **geom)
     if window is not None:
         run &= q_idx < n_q
+    edge = _edge_block(q_idx, kv_idx, skv_pad=skv_pad,
+                       masked=has_mask or has_seg, **geom)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)          # [bq, d]
-        k = k_ref[0].astype(jnp.float32)          # [bkv, d]
-        v = v_ref[0].astype(jnp.float32)          # [bkv, d]
-        do = do_ref[0].astype(jnp.float32)        # [bq, d]
-        lse = lse_ref[0]                          # [bq, 1]
-        delta = delta_ref[0]                      # [bq, 1]
-        s = _masked_logits(q, k, opt_refs, q_idx, kv_idx, scale=scale,
-                           causal=causal, bq=bq, bkv=bkv, kv_len=kv_len,
-                           skv_pad=skv_pad, has_mask=has_mask,
-                           has_seg=has_seg, window=window)
-        p = _safe_exp(s, lse)                      # exact probs
-        # dv += p^T @ do
-        dv_scr[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale              # [bq, bkv]
-        # dk += ds^T @ q
-        dk_scr[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    def _compute(edge):
+        q = q_ref[0]                              # [bq, d]
+        k = k_ref[0]                              # [bkv, d]
+        v = v_ref[0]                              # [bkv, d]
+        do = do_ref[0]                            # [bq, d]
+        lse = lse_ref[0]                          # [1, bq]
+        delta = delta_ref[0]                      # [1, bq]
+        # the tile by kv rows, [bkv, bq]: dv and dk then contract over its
+        # lanes, and neither p nor ds is transposed on the way to the MXU
+        s = _logits(q, k, opt_refs, q_idx, kv_idx, edge=edge, scale=scale,
+                    skv_pad=skv_pad, has_mask=has_mask, has_seg=has_seg,
+                    by_kv=True, **geom)
+        p = _exp(s, lse, edge)                     # exact probs
+        dv_scr[:] += _dot(p.astype(do.dtype), do, ((1,), (0,)))
+        dp = _dot(v, do, ((1,), (1,)))
+        ds = p * (dp - delta) * scale              # [bkv, bq]
+        dk_scr[:] += _dot(ds.astype(q.dtype), q, ((1,), (0,)))
+
+    _when_tile(run, edge, _compute)
 
     @pl.when(t == pl.num_programs(2) - 1)
     def _finalize():
@@ -405,10 +553,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                scale, causal, bq, bkv, kv_len, skv_pad, has_mask, has_seg,
                window=None):
     """Grid: (bh, num_q_blocks, num_kv_blocks); kv innermost (sequential);
-    under ``window`` the band's kv blocks only, as in the forward."""
+    under ``window`` the band's kv blocks only, as in the forward.  ``lse``
+    and ``delta`` arrive as rows [1, 1, bq] and are stood up once a q block
+    as columns [bq, 128] with all lanes alike (``lse_scr``, ``delta_scr``)."""
     n_opt = int(has_mask) + 2 * int(has_seg)
     opt_refs = rest[:n_opt]
-    dq_ref, dq_scr = rest[n_opt:]
+    dq_ref, dq_scr, lse_scr, delta_scr = rest[n_opt:]
     j = pl.program_id(2)
     q_idx = pl.program_id(1)
     kv_idx = j if window is None else (
@@ -417,28 +567,27 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     @pl.when(j == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
+        # a column with all lanes alike is the transpose of the row spread
+        # over 128 sublanes
+        lse_scr[:] = jnp.broadcast_to(lse_ref[0], (_LANES, bq)).T
+        delta_scr[:] = jnp.broadcast_to(delta_ref[0], (_LANES, bq)).T
 
-    run = _run_block(q_idx, kv_idx, causal=causal, bq=bq, bkv=bkv,
-                     kv_len=kv_len, window=window)
+    geom = dict(causal=causal, bq=bq, bkv=bkv, kv_len=kv_len, window=window)
+    run = _run_block(q_idx, kv_idx, **geom)
+    edge = _edge_block(q_idx, kv_idx, skv_pad=skv_pad,
+                       masked=has_mask or has_seg, **geom)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]
-        delta = delta_ref[0]
-        s = _masked_logits(q, k, opt_refs, q_idx, kv_idx, scale=scale,
-                           causal=causal, bq=bq, bkv=bkv, kv_len=kv_len,
-                           skv_pad=skv_pad, has_mask=has_mask,
-                           has_seg=has_seg, window=window)
-        p = _safe_exp(s, lse)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dq_scr[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    def _compute(edge):
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        s = _logits(q, k, opt_refs, q_idx, kv_idx, edge=edge, scale=scale,
+                    skv_pad=skv_pad, has_mask=has_mask, has_seg=has_seg,
+                    **geom)
+        p = _exp(s, _lanes(lse_scr[:], s.shape[1]), edge)
+        dp = _dot(do, v, ((1,), (1,)))
+        ds = p * (dp - _lanes(delta_scr[:], s.shape[1])) * scale
+        dq_scr[:] += _dot(ds.astype(k.dtype), k, ((1,), (0,)))
+
+    _when_tile(run, edge, _compute)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
@@ -458,22 +607,15 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, *, rep=1, kv_len=None,
     n_q = pl.cdiv(sq, bq_sz)
     n_kv = pl.cdiv(skv, bkv_sz)
 
+    # lse and delta go in as rows [bh, 1, sq], 2 KB a q block: as columns
+    # [bh, sq, 1] they fill 128-lane tiles in HBM, 256 KB a block, copied
+    # with every step of the dk/dv kernel
     delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
-                    axis=-1, keepdims=True)          # [bh, sq, 1]
-    lse3 = lse[..., None]                             # [bh, sq, 1]
+                    axis=-1)[:, None, :]
+    lse = lse[:, None, :]
 
-    if window is None:
-        kv_inner, q_inner = n_kv, n_q
-        kv_of = lambda i, j: j
-        q_of = lambda kv, t: t % n_q
-    else:
-        # the band's blocks only; an index past the band's end stays on
-        # the last block (no new copy) and the kernel skips the step
-        kv_inner, q_inner = _band_widths(n_q, n_kv, bq_sz, bkv_sz, window)
-        kv_of = lambda i, j: jnp.minimum(
-            _band_first_kv(i, bq_sz, bkv_sz, window) + j, n_kv - 1)
-        q_of = lambda kv, t: jnp.minimum(
-            _band_first_q(kv, bq_sz, bkv_sz) + t % q_inner, n_q - 1)
+    kv_inner, kv_of, q_inner, q_of = _walks(n_q, n_kv, bq_sz, bkv_sz, causal,
+                                            window)
 
     # dkv grid → q-row index
     hq_of = lambda bh_kv, t: bh_kv * rep + t // q_inner
@@ -486,13 +628,13 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, *, rep=1, kv_len=None,
     # the optional-input index maps resolve the group-dependent q head first
     q_spec = pl.BlockSpec((1, bq_sz, d),
                           lambda b, kv, t: (hq_of(b, t), q_of(kv, t), 0))
-    row_spec = pl.BlockSpec((1, bq_sz, 1),
-                            lambda b, kv, t: (hq_of(b, t), q_of(kv, t), 0))
+    row_spec = pl.BlockSpec((1, 1, bq_sz),
+                            lambda b, kv, t: (hq_of(b, t), 0, q_of(kv, t)))
     kv_spec = pl.BlockSpec((1, bkv_sz, d), lambda b, kv, t: (b, kv, 0))
     opt_arrays, opt_specs = _opt_specs(
         bq_sz, bkv_sz, mask, mask_idx, segs, batch_of,
         q_blk=lambda b, kv, t: q_of(kv, t), kv_blk=lambda b, kv, t: kv,
-        head_of=lambda b, kv, t: hq_of(b, t))
+        head_of=lambda b, kv, t: hq_of(b, t), by_kv=True)
 
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, n_q=n_q, n_inner=q_inner, **common),
@@ -507,13 +649,13 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, *, rep=1, kv_len=None,
         name=("flash_attn_bwd_dkv" if window is None
               else "flash_attn_win_bwd_dkv"),
         interpret=interpret_mode(),
-    )(q, k, v, do, lse3, delta, *opt_arrays)
+    )(q, k, v, do, lse, delta, *opt_arrays)
 
     # ---- dq: grid (bh, n_q, n_kv), kv innermost ----
     q_spec_i = pl.BlockSpec((1, bq_sz, d), lambda b, i, j: (b, i, 0))
     kv_spec_j = pl.BlockSpec((1, bkv_sz, d),
                              lambda b, i, j: (b // rep, kv_of(i, j), 0))
-    row_spec_i = pl.BlockSpec((1, bq_sz, 1), lambda b, i, j: (b, i, 0))
+    row_spec_i = pl.BlockSpec((1, 1, bq_sz), lambda b, i, j: (b, 0, i))
     opt_arrays_q, opt_specs_q = _opt_specs(
         bq_sz, bkv_sz, mask, mask_idx, segs, batch_of,
         q_blk=lambda b, i, j: i, kv_blk=lambda b, i, j: kv_of(i, j))
@@ -525,11 +667,13 @@ def _flash_bwd(q, k, v, out, lse, do, scale, causal, *, rep=1, kv_len=None,
                   row_spec_i, *opt_specs_q],
         out_specs=[q_spec_i],
         out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype)],
-        scratch_shapes=[_VMEM((bq_sz, d), jnp.float32)],
+        scratch_shapes=[_VMEM((bq_sz, d), jnp.float32),
+                        _VMEM((bq_sz, _LANES), jnp.float32),
+                        _VMEM((bq_sz, _LANES), jnp.float32)],
         name=("flash_attn_bwd_dq" if window is None
               else "flash_attn_win_bwd_dq"),
         interpret=interpret_mode(),
-    )(q, k, v, do, lse3, delta, *opt_arrays_q)
+    )(q, k, v, do, lse, delta, *opt_arrays_q)
     return dq, dk, dv
 
 
@@ -681,7 +825,7 @@ def _flash_attention_bshd(q, k, v, attn_mask=None, causal=False, scale=None,
     b, sq, hq, d = q.shape
     hkv = k.shape[2]
     skv = k.shape[1]
-    global KERNEL_CALLS, FALLBACK_CALLS
+    global KERNEL_CALLS, FALLBACK_CALLS, LAST_CALL
     if d % 8 != 0 or hq % hkv != 0 or kernel_disabled("flash_attention"):
         FALLBACK_CALLS += 1
         if segment_ids is not None:
@@ -710,6 +854,11 @@ def _flash_attention_bshd(q, k, v, attn_mask=None, causal=False, scale=None,
     bq_sz, bkv_sz = _pick_blocks(sq, skv, d, attn_mask is not None)
     sq_pad = _round_up(sq, bq_sz)
     skv_pad = _round_up(skv, bkv_sz)
+    LAST_CALL = {
+        "tiles": tile_census(
+            sq_pad, skv_pad, bq_sz, bkv_sz, causal, window, kv_len=skv,
+            masked=attn_mask is not None or segment_ids is not None),
+        "operands": jnp.promote_types(q.dtype, k.dtype).name}
     qh = _pad_seq(qh, 1, sq_pad)
     kh = _pad_seq(kh, 1, skv_pad)
     vh = _pad_seq(vh, 1, skv_pad)

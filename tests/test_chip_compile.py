@@ -192,6 +192,33 @@ def test_flash_attention_seq2048(one_chip, compiled_kernels, grad):
              ((2, 2048, NKV, HD), BF16), ((2, 2048, NKV, HD), BF16))
 
 
+@pytest.mark.parametrize("case", ["segments", "bool_mask", "additive_mask",
+                                  "head_dim64", "float32"])
+def test_flash_attention_streamed_inputs_and_widths(one_chip,
+                                                    compiled_kernels, case):
+    """What the two training cells do not pass: segment ids and masks (the
+    dk/dv kernel takes them by kv rows), a head narrower than the 128 lanes
+    the softmax statistics are kept on, float32 inputs; forward and all three
+    gradients at 1,024 positions."""
+    hd = 64 if case == "head_dim64" else HD
+    dtype = jnp.float32 if case == "float32" else BF16
+
+    def loss(q, k, v):
+        kw = {"causal": True}
+        if case == "segments":
+            kw["segment_ids"] = jnp.zeros((2, 1024), jnp.int32)
+        if case == "bool_mask":
+            kw = {"attn_mask": jnp.ones((2, 1, 1024, 1024), bool)}
+        if case == "additive_mask":
+            kw = {"attn_mask": jnp.zeros((1, NH, 1024, 1024), jnp.float32)}
+        return fa.flash_attention_bshd(q, k, v, **kw).astype(
+            jnp.float32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+             ((2, 1024, NH, hd), dtype), ((2, 1024, NKV, hd), dtype),
+             ((2, 1024, NKV, hd), dtype))
+
+
 @pytest.mark.parametrize("heads,window", [(64, 512), (48, None)],
                          ids=["window512x64", "full48"])
 def test_flash_attention_seq8192_laguna_widths(one_chip, compiled_kernels,

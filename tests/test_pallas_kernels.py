@@ -117,6 +117,220 @@ def test_flash_window_walks_the_band_only_and_is_named():
         fa.flash_attention_bshd(x, x, x, causal=False, window=3)
 
 
+# bf16 operands, float32 accumulation (the training cells' path): the made
+# side of P.V, P^T.dO, dS^T.Q and dS.K is rounded to bf16 once, and so are the
+# results.  One rounding is at most half of bf16's step, 2^-9 of the value.
+_BF16_HALF_STEP = 2.0 ** -9
+
+
+@pytest.mark.parametrize("s,hq,hkv,causal,window", [
+    (1024, 2, 2, True, None),    # causal, 2 blocks of 512 each way
+    (2048, 2, 2, True, None),    # causal, 4 blocks: interior tiles too
+    (1024, 4, 1, True, None),    # GQA, 4 query heads a kv head
+    (2048, 2, 1, True, 512),     # the band: window 512 in blocks of 512
+    (1100, 2, 2, True, None),    # odd length: kv_len makes an edge tile
+    (1024, 2, 2, False, None),   # non-causal: every tile interior
+], ids=["causal2", "causal4", "gqa4", "window512", "odd1100", "full"])
+def test_flash_bf16_operands_parity(s, hq, hkv, causal, window):
+    """bf16 inputs through the kernels against composed attention on the same
+    values in float32, forward and all three gradients.  The limits are
+    worst cases reckoned from bf16's step, not fitted: an output row is a
+    convex mix of v's rows, so rounding p (2^-9 of each weight) and the
+    output (2^-9 of the value) moves an element by at most 2 x 2^-9 x max|v|;
+    a gradient takes one more rounded factor (dS or P) and a rounded result
+    on top of the rounded output that delta is made from, four roundings in
+    all, held against its norm."""
+    d = 32
+    rs = np.random.RandomState(4)
+    bf = lambda *shape: jnp.asarray(rs.randn(*shape), jnp.bfloat16)
+    q, k, v = bf(1, s, hq, d), bf(1, s, hkv, d), bf(1, s, hkv, d)
+    do = bf(1, s, hq, d).astype(jnp.float32)
+    up = lambda *xs: tuple(x.astype(jnp.float32) for x in xs)
+    flash = lambda q, k, v: fa.flash_attention_bshd(
+        q, k, v, causal=causal, window=window)
+    plain = lambda q, k, v: fa._composed_attention(
+        q, k, v, None, causal, 1.0 / np.sqrt(d), window)
+    out, want = flash(q, k, v), plain(*up(q, k, v))
+    assert out.dtype == jnp.bfloat16 and fa.LAST_CALL["operands"] == "bfloat16"
+    err = np.abs(np.asarray(out.astype(jnp.float32)) - np.asarray(want)).max()
+    assert err <= 2 * _BF16_HALF_STEP * float(jnp.max(jnp.abs(
+        v.astype(jnp.float32))))
+    loss = lambda f: lambda *a: jnp.sum(f(*a).astype(jnp.float32) * do)
+    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    ref = jax.grad(loss(plain), (0, 1, 2))(*up(q, k, v))
+    for g, r, name in zip(got, ref, "qkv"):
+        assert g.dtype == jnp.bfloat16
+        gap = float(jnp.linalg.norm(g.astype(jnp.float32) - r)
+                    / jnp.linalg.norm(r))
+        assert gap <= 4 * _BF16_HALF_STEP, f"d{name}: {gap}"
+
+
+@pytest.mark.parametrize("causal,window", [(True, None), (False, None),
+                                           (True, 512)],
+                         ids=["causal", "full", "band"])
+def test_flash_skipped_steps_copy_nothing(causal, window):
+    """A grid step that the kernels skip is handed the block index of a step
+    that runs beside it (the pipeline copies a block only when its index
+    changes), and a step that runs is handed its own block."""
+    n, blk = 8, 512
+    kv_steps, kv_of, q_steps, q_of = fa._walks(n, n, blk, blk, causal, window)
+    geom = dict(causal=causal, bq=blk, bkv=blk, kv_len=n * blk, window=window)
+    for i in range(n):
+        first = fa._band_first_kv(i, blk, blk, window) if window else 0
+        walked = [(first + j, int(kv_of(i, j))) for j in range(kv_steps)]
+        for kv, got in walked:
+            ran = kv < n and fa._run_block(i, kv, **geom)
+            assert got == kv if ran else fa._run_block(i, got, **geom)
+    for kv in range(n):
+        first = fa._band_first_q(kv, blk, blk) if window else 0
+        for t in range(q_steps):
+            q, got = first + t, int(q_of(kv, t))
+            ran = q < n and fa._run_block(q, kv, **geom)
+            assert got == q if ran else fa._run_block(got, kv, **geom)
+    assert (kv_steps, q_steps) == ((2, 2) if window else (n, n))
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("inputs", ["segment_pair", "bool_mask",
+                                    "additive_mask", "bool_mask+segments"])
+def test_flash_streamed_inputs_backward_unlike_sides(inputs, causal):
+    """The dk/dv kernel takes its tiles by kv rows, so a mask arrives
+    transposed and q's segment ids along the lanes: with 300 q positions
+    over 520 kv positions, unlike ids either side and a random mask, no
+    symmetry can hide a swapped axis.  All three gradients against composed
+    attention, float32."""
+    rs = np.random.RandomState(0)
+    b, sq, skv, h, hkv, d = 2, 300, 520, 4, 2, 16
+    q, do = _rand(rs, b, sq, h, d), _rand(rs, b, sq, h, d)
+    k, v = _rand(rs, b, skv, hkv, d), _rand(rs, b, skv, hkv, d)
+    q_ids = jnp.asarray(rs.randint(0, 3, (b, sq)), jnp.int32)
+    kv_ids = jnp.asarray(rs.randint(0, 3, (b, skv)), jnp.int32)
+    same = q_ids[:, None, :, None] == kv_ids[:, None, None, :]
+    kept = jnp.asarray(rs.rand(b, 1, sq, skv) > 0.3)
+    added = _rand(rs, 1, h, sq, skv)
+    kw, ref_mask = {
+        "segment_pair": (dict(segment_ids=(q_ids, kv_ids)), same),
+        "bool_mask": (dict(attn_mask=kept), kept),
+        "additive_mask": (dict(attn_mask=added), added),
+        "bool_mask+segments": (dict(attn_mask=kept,
+                                    segment_ids=(q_ids, kv_ids)), kept & same),
+    }[inputs]
+    got = jax.grad(lambda *a: jnp.sum(fa.flash_attention_bshd(
+        *a, causal=causal, **kw) * do), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(fa._composed_attention(
+        *a, ref_mask, causal, 1.0 / np.sqrt(d)) * do), (0, 1, 2))(q, k, v)
+    assert fa.LAST_CALL["tiles"][2] == 0      # every block that runs is edge
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def _eqns_under(jaxpr):
+    """Every eqn of a jaxpr and of the jaxprs its eqns hold (cond, ...)."""
+    from paddle_tpu.analysis.rules import _sub_jaxprs
+
+    for e in jaxpr.eqns:
+        yield e
+        for sub in _sub_jaxprs(e):
+            yield from _eqns_under(sub)
+
+
+def _flash_kernel_bodies(dtype, s=1024, **kw):
+    """{kernel name: its body's jaxpr} of a forward + backward flash call."""
+    from paddle_tpu.analysis.kernel_contracts import _pallas_eqns
+
+    x = jnp.zeros((1, s, 2, 16), dtype)
+    loss = lambda q, k, v: jnp.sum(fa.flash_attention_bshd(
+        q, k, v, **kw).astype(jnp.float32))
+    closed = jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(x, x, x)
+    return {e.params["name"]: e.params["jaxpr"] for e in _pallas_eqns(closed)}
+
+
+@pytest.mark.parametrize("window", [None, 512], ids=["full", "band"])
+def test_flash_products_take_the_inputs_dtype(window):
+    """The three tile bodies: with bf16 inputs every product has bf16
+    operands and accumulates in float32; with float32 inputs nothing is
+    rounded to bf16 anywhere (the kernels compute what they always did)."""
+    bodies = _flash_kernel_bodies(jnp.bfloat16, causal=True, window=window)
+    assert len(bodies) == 3
+    for name, body in bodies.items():
+        dots = [e for e in _eqns_under(body)
+                if e.primitive.name == "dot_general"]
+        # an edge and an interior body of 2 (forward), 4 (dk/dv), 3 (dq)
+        assert len(dots) == 2 * {"fwd": 2, "dkv": 4, "_dq": 3}[name[-3:]], name
+        for e in dots:
+            assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16] * 2, name
+            assert e.params["preferred_element_type"] == jnp.float32, name
+            assert e.outvars[0].aval.dtype == jnp.float32
+    for name, body in _flash_kernel_bodies(
+            jnp.float32, causal=True, window=window).items():
+        for e in _eqns_under(body):
+            if e.primitive.name == "convert_element_type":
+                assert e.params["new_dtype"] != jnp.bfloat16, name
+            if e.primitive.name == "dot_general":
+                assert [v.aval.dtype for v in e.invars] == [jnp.float32] * 2
+
+
+def _brute_force_census(sq, skv, bq, bkv, causal, window, kv_len):
+    """(skipped, edge, interior) counted over the mask itself: a block with
+    no kept logit, with some, with all (padded q rows are not masked)."""
+    n_q, n_kv = -(-sq // bq), -(-skv // bkv)
+    rows = np.arange(n_q * bq)[:, None]
+    cols = np.arange(n_kv * bkv)[None, :]
+    keep = np.broadcast_to(cols < kv_len, (n_q * bq, n_kv * bkv)).copy()
+    if causal:
+        keep &= rows >= cols
+    if window is not None:
+        keep &= rows - cols < window
+    kept = keep.reshape(n_q, bq, n_kv, bkv).sum(axis=(1, 3))
+    return (int((kept == 0).sum()), int(((kept > 0) & (kept < bq * bkv)).sum()),
+            int((kept == bq * bkv).sum()))
+
+
+@pytest.mark.parametrize("sq,skv,bq,bkv,causal,window,kv_len,cell", [
+    (2048, 2048, 512, 512, True, None, 2048, (6, 4, 6)),       # yicoder-train
+    (8192, 8192, 512, 512, True, None, 8192, (120, 16, 120)),  # laguna, full
+    (8192, 8192, 512, 512, True, 512, 8192, (225, 31, 0)),     # laguna, band
+    (2176, 2176, 128, 128, True, 300, 2049, None),   # band over unlike edges
+    (1536, 1536, 512, 512, False, None, 1100, None),  # non-causal, padded kv
+    (1024, 2048, 256, 512, False, None, 2048, None),  # cross, all interior
+], ids=["yicoder", "laguna-full", "laguna-band", "band-odd", "padded", "cross"])
+def test_tile_census_against_the_mask(sq, skv, bq, bkv, causal, window,
+                                      kv_len, cell):
+    got = fa.tile_census(sq, skv, bq, bkv, causal, window, kv_len)
+    assert got == _brute_force_census(sq, skv, bq, bkv, causal, window, kv_len)
+    assert cell is None or got == cell
+    # a streamed mask or segment ids: every block that runs is an edge block
+    skipped, edge, interior = got
+    assert fa.tile_census(sq, skv, bq, bkv, causal, window, kv_len,
+                          masked=True) == (skipped, edge + interior, 0)
+
+
+@pytest.mark.parametrize("kw,bodies", [
+    (dict(causal=True), [False, True]),            # interior and edge
+    (dict(causal=True, window=512), [False, True]),
+    (dict(causal=False), [False]),                 # no edge anywhere
+    (dict(causal=False, segment_ids=jnp.zeros((1, 1024), jnp.int32)),
+     [True]),                                      # masked: edge only
+], ids=["causal", "band", "full", "segments"])
+def test_flash_interior_tiles_build_no_mask(kw, bodies):
+    """Each kernel holds one tile body a kind of block it can meet; the
+    interior one has no iota, compare or select in it, the edge one does."""
+    for name, body in _flash_kernel_bodies(jnp.bfloat16, **kw).items():
+        prims = [{x.primitive.name for x in _eqns_under(e.params["branches"][1].jaxpr)}
+                 for e in body.eqns if e.primitive.name == "cond"]
+        tiles = [p for p in prims if "dot_general" in p]
+        assert sorted("select_n" in p for p in tiles) == bodies, name
+        for p in tiles:
+            assert ("iota" in p) == ("select_n" in p and "causal" in kw
+                                     and kw["causal"]), (name, p)
+            if "select_n" not in p:
+                assert not p & {"iota", "gt", "ge", "lt", "eq"}, (name, p)
+    assert fa.LAST_CALL["tiles"] == fa.tile_census(
+        1024, 1024, 512, 512, kw["causal"], kw.get("window"),
+        masked="segment_ids" in kw)
+
+
 def test_flash_gqa_grouped_heads():
     rs = np.random.RandomState(2)
     q = _rand(rs, 2, 128, 8, 32)
