@@ -38,8 +38,7 @@ import traceback
 # in bf16 (9.1 GB of weights) beside the KV pool and the step's activations.
 # Widths are never cut.
 SERVE_LAYERS = 16
-# The largest training config the repo carries for a 16 GB chip (bench.py's
-# cfg_460m).  The Llama-3-8B widths do not fit one chip with AdamW state:
+# The largest training config the repo carries for a 16 GB chip (cfg_460m).  The Llama-3-8B widths do not fit one chip with AdamW state:
 # the two embedding tables alone are 1.05 B parameters = 14.7 GB of
 # bf16 + f32 m/v/master.
 TRAIN_CFG = dict(vocab_size=32000, hidden_size=1536, intermediate_size=4096,
@@ -414,7 +413,7 @@ def main() -> int:
            f"bf16; depth cut {full.num_hidden_layers} -> {SERVE_LAYERS} "
            f"layers: what one 16 GB chip holds (9.1 GB of weights)")
     train_cfg = llama.LlamaConfig(**TRAIN_CFG)
-    train_note = ("cfg_460m (bench.py), batch 8 x seq 2048: the llama3_8b "
+    train_note = ("cfg_460m, batch 8 x seq 2048: the llama3_8b "
                   "widths do not fit one chip with AdamW state (the "
                   "embedding tables alone are 1.05 B parameters)")
 

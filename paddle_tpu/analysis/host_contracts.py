@@ -46,7 +46,7 @@ fault-tolerance PR.  This pass verifies both statically, on the module AST
 
 Findings flow through the ordinary severity/allowlist machinery
 (``analyze(host=True)``, run by every serving gate target), land as a
-``host_contracts`` section on program cards and in bench rung detail, and
+``host_contracts`` section on program cards, and
 ``python -m paddle_tpu.analysis --host`` gates them standalone in CI.
 """
 
@@ -280,20 +280,29 @@ def _check_overlap(mod: _Module, overlap: str, depth: int, raw: list,
                 ov_blocking.append((fname, label, lineno))
         ov_blocking.sort(key=lambda b: (b[2], b[0]))
 
-        # one analysis unit per (method containing >= 1 window); both
-        # graceful/serial window sites of a step method share one prefix
-        # approximation, so findings dedupe on (method, field)
+        # one analysis unit per method containing a window.  A step
+        # method has one window and, before it, one launch: the call of
+        # the compiled program it picked into a local name
         sites: dict[str, list[int]] = {}
+        launches: dict[str, list[int]] = {}
         for mname in sorted(methods):
             if mname == overlap:
                 continue
-            for node in ast.walk(methods[mname]):
-                if (isinstance(node, ast.Call)
-                        and isinstance(node.func, ast.Attribute)
-                        and node.func.attr == overlap
-                        and isinstance(node.func.value, ast.Name)
-                        and node.func.value.id == "self"):
-                    sites.setdefault(mname, []).append(node.lineno)
+            nodes = list(ast.walk(methods[mname]))
+            calls = [n for n in nodes if isinstance(n, ast.Call)]
+            windows = [n.lineno for n in calls
+                       if isinstance(n.func, ast.Attribute)
+                       and n.func.attr == overlap
+                       and isinstance(n.func.value, ast.Name)
+                       and n.func.value.id == "self"]
+            if not windows:
+                continue
+            sites[mname] = windows
+            local = {t.id for n in nodes if isinstance(n, ast.Assign)
+                     for t in n.targets if isinstance(t, ast.Name)}
+            launches[mname] = sorted(
+                n.lineno for n in calls
+                if isinstance(n.func, ast.Name) and n.func.id in local)
 
         blocked_reported: set[tuple[str, int]] = set()
         for mname in sorted(sites):
@@ -342,6 +351,7 @@ def _check_overlap(mod: _Module, overlap: str, depth: int, raw: list,
                 "method": f"{cls_name}.{mname}",
                 "where": _where(mod, lines[0]),
                 "windows": lines,
+                "launches": launches[mname],
                 "launch_reads": len(pre_reads),
                 "overlap_writes": sorted(ov_writes),
                 "races": [{"field": f,
@@ -972,7 +982,7 @@ def check_host_contracts(target: str = "", *, modules=None, machines=None,
     """Run the host-contract pass.  Returns ``(findings, sections)`` —
     the same shape as :func:`check_kernel_contracts`: typed findings for
     the severity/allowlist machinery plus per-unit section dicts for
-    program cards / bench detail / ``--json``.
+    program cards / ``--json``.
 
     ``modules`` (``[(name, source, filename), ...]``) and ``machines``
     (:class:`MachineSpec` s) default to the shipped engine + fleet and
@@ -1002,8 +1012,8 @@ def check_host_contracts(target: str = "", *, modules=None, machines=None,
 
 
 def host_contracts_summary(sections) -> dict:
-    """Aggregate host-contract verdicts for card summaries / bench
-    detail.  ``violations`` counts RAW findings (pre-allowlist) — the
+    """Aggregate host-contract verdicts for card summaries.
+    ``violations`` counts RAW findings (pre-allowlist) — the
     figure ``budgets.toml`` ceilings as ``host_contract_violations``."""
     out = {"windows": 0, "methods": 0, "machines": 0, "sites": 0,
            "races": 0, "blocking": 0, "undeclared_transitions": 0,

@@ -9,7 +9,7 @@ in seconds and with zero device time.
 ``build(name)`` returns an :class:`AnalysisTarget`; ``run(name)`` builds and
 analyzes it.  ``tools/lint_gate.py`` iterates :data:`GATE_TARGETS` (and the
 tier-1 suite runs the gate), so a change that knocks a train step or the
-serving decode path off the fast path fails CI, not a later bench round.
+serving decode path off the fast path fails CI, not a later chip run.
 """
 
 from __future__ import annotations
@@ -130,18 +130,8 @@ def _serving_engine(_force_flags=(), _cfg_kwargs=None, _disable_pallas=(),
     # operator's kill switch (e.g. PADDLE_TPU_CHUNKED_PREFILL=0) has it off
     # at runtime — without the override the ctor would skip building the
     # program and the target builder would crash the whole gate.
-    # PADDLE_TPU_GRACEFUL is forced for EVERY serving target: the graceful
-    # programs carry the in-graph NaN/inf logit guard, and the host_sync
-    # rule must see exactly what production traces (the guard's flags ride
-    # back with the step's tokens — a callback sneaking in would be the
-    # regression the gate exists to catch).  PADDLE_TPU_METRICS is forced
-    # for the same reason (ISSUE 11): observability's recording contract
-    # is host-side post-step — the gate analyzes the metrics-ON engine so
-    # a metric recorded via callback from INSIDE a compiled step would
-    # fail host_sync here, not in production.
     with contextlib.ExitStack() as stack:
-        for flag in (*_force_flags, "PADDLE_TPU_GRACEFUL",
-                     "PADDLE_TPU_METRICS"):
+        for flag in _force_flags:
             prev = os.environ.get(flag)
             os.environ[flag] = "1"
             stack.callback(lambda f=flag, p=prev: (
@@ -194,8 +184,7 @@ def _serving_engine(_force_flags=(), _cfg_kwargs=None, _disable_pallas=(),
         # program to the gather oracle and fail the budget gate
         # spuriously.
         eng._lint_env = {
-            **{flag: "1" for flag in (*_force_flags, "PADDLE_TPU_GRACEFUL",
-                                      "PADDLE_TPU_METRICS")},
+            **{flag: "1" for flag in _force_flags},
             "PADDLE_TPU_DISABLE_PALLAS": (",".join(sorted(_disable_pallas))
                                           if _disable_pallas else None),
             "PADDLE_TPU_TP": None,
@@ -245,34 +234,6 @@ def _t_serving_flash_decode_step() -> AnalysisTarget:
     table = jnp.asarray(eng._table)
     return AnalysisTarget(
         "serving_flash_decode_step", eng._decode_greedy,
-        (eng.params, eng.cache_k, eng.cache_v, tokens, pos, active,
-         temp, topp, seeds, table), env=eng._lint_env)
-
-
-def _t_serving_async_step() -> AnalysisTarget:
-    import jax.numpy as jnp
-
-    # the production decode program as the ASYNC host runtime launches it
-    # (ISSUE 16, docs/async_runtime.md): PADDLE_TPU_ASYNC_HOST=1 pinned at
-    # construction AND trace time.  The async runtime is host-side only —
-    # journal upkeep and late token fetches never touch the jaxpr — so
-    # this target's compiled program must stay IDENTICAL to
-    # serving_flash_decode_step's (its budget mirrors that entry), and the
-    # host_sync rule polices exactly that: a device-blocking callback or
-    # sync sneaking into the overlapped step is the regression that would
-    # silently serialize the pipeline again.
-    eng = _serving_engine(_force_flags=("PADDLE_TPU_ASYNC_HOST",))
-    assert eng._async_host, "async target must build the async-host engine"
-    B = eng.max_batch
-    tokens = jnp.zeros((B,), jnp.int32)
-    pos = jnp.asarray([5, 0], jnp.int32)
-    active = jnp.asarray([True, False])
-    temp = jnp.zeros((B,), jnp.float32)
-    topp = jnp.ones((B,), jnp.float32)
-    seeds = jnp.zeros((B,), jnp.int32)
-    table = jnp.asarray(eng._table)
-    return AnalysisTarget(
-        "serving_async_step", eng._decode_greedy,
         (eng.params, eng.cache_k, eng.cache_v, tokens, pos, active,
          temp, topp, seeds, table), env=eng._lint_env)
 
@@ -477,7 +438,6 @@ TARGETS = {
     "serving_mixed_step": _t_serving_mixed_step,
     "serving_tier_restore": _t_serving_tier_restore,
     "serving_tp_step": _t_serving_tp_step,
-    "serving_async_step": _t_serving_async_step,
 }
 
 # the CI gate runs every registered target; kept as an explicit list so an
@@ -488,7 +448,7 @@ GATE_TARGETS = ("llama_train_step", "moe_llama_train_step",
                 "serving_quant_decode_step", "serving_quant_scatter_step",
                 "serving_prefill_step", "serving_verify_step",
                 "serving_mixed_step", "serving_tier_restore",
-                "serving_tp_step", "serving_async_step")
+                "serving_tp_step")
 
 # targets that serve from the async host runtime: these additionally run
 # the module-scoped host-contract pass (host_contracts.py) — overlap-window

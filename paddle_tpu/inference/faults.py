@@ -12,13 +12,11 @@ engine polls at its three seams:
   (evict -> preempt -> fail-one) without needing a genuinely tiny pool;
 * **kernel dispatch** (``_launch``) — ``kernel_error`` raises where the
   compiled step would be dispatched, BEFORE the call, so host and device
-  state are untouched and the graceful engine can retry the step;
+  state are untouched and the engine can retry the step;
 * **sampler** — ``nan_logits`` sets a per-slot poison bit that the compiled
   step turns into a genuinely non-finite logits row IN-GRAPH, so the NaN/inf
   guard proves itself against the real failure shape, not a host-side
-  simulation (requires ``PADDLE_TPU_GRACEFUL=1``: the graceful-off program
-  is byte-identical to the pre-fault-tolerance engine and has no poison
-  operand, so this kind is inert there);
+  simulation;
 
 plus two host-side seams that exercise per-request isolation:
 
@@ -26,7 +24,7 @@ plus two host-side seams that exercise per-request isolation:
   consume loop), proving a host-side per-request fault cannot take down the
   batch;
 * ``cache_error`` — raises inside prefix-cache block registration; the
-  graceful engine degrades (the block stays private, a future request
+  engine degrades (the block stays private, a future request
   misses where it could have hit) without failing any request;
 * ``tier_drop`` — a host-KV-tier entry vanishes between the admission's
   tier match and the ship_in restore (docs/kv_tier.md): the poll fires at

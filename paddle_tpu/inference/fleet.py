@@ -48,8 +48,8 @@ faults:
 * a DEAD replica (``replica_crash`` injection, or an engine fault that
   escapes ``step()`` — only a persistent kernel failure can) triggers
   **failover**: the router replays the replica's journal — accepted
-  prompts, emitted tokens, prefill cursors, maintained incrementally via
-  ``snapshot()`` after every step — onto survivors through
+  prompts, emitted tokens, prefill cursors, maintained incrementally by
+  each engine and pulled at the death boundary — onto survivors through
   ``engine.adopt()``'s teacher-forced recompute.  Every replayed request's
   completed output stream is token-identical (greedy and seeded) to an
   uninterrupted fleet's, because each stream depends only on its own
@@ -102,8 +102,7 @@ from ..profiler import RecordEvent
 from .faults import KNOWN_KEYS, KNOWN_KINDS, REPLICA_KINDS, FaultPlan
 from .observability import (FLEET_STAT_SCHEMA, FlightRecorder,
                             MetricsRegistry, RequestTracer, SLOTracker,
-                            StatsView, flight_recorder_enabled,
-                            metrics_enabled)
+                            StatsView)
 from .serving import (TERMINAL_STATUSES, ContinuousBatchingEngine, Request,
                       journal_entry)
 
@@ -146,12 +145,7 @@ class FleetRouter:
     crash-equivalent and the replica DEAD (so un-hedgeable work fails
     with a diagnosis instead of hanging the serve loop).  ``slow_after``
     / ``heal_after``: consecutive slow / clean heartbeats before
-    DEGRADED / back to HEALTHY.
-
-    Requires graceful mode (``PADDLE_TPU_GRACEFUL=1``, the default): the
-    failover and hedge paths are built on the status lifecycle,
-    ``cancel()``, and per-request isolation that the graceful-off engine
-    predates."""
+    DEGRADED / back to HEALTHY."""
 
     def __init__(self, cfg, params, n_replicas: int = 2, *,
                  stall_steps: int = 3, stall_dead_steps: int = 12,
@@ -173,12 +167,9 @@ class FleetRouter:
         # families with a {"replica": k} label set, so metrics.expose()
         # is the whole fleet's Prometheus snapshot; the fleet's own
         # stats/SLO/flight tiers layer on top with fleet-prefixed names.
-        self._metrics_on = metrics_enabled()
         self.metrics = engine_kw.pop("metrics", None)
-        if self.metrics is None and self._metrics_on:
+        if self.metrics is None:
             self.metrics = MetricsRegistry()
-        # metrics-off: self.metrics stays None (absent evidence must read
-        # as absent — bench embeds null, never an empty exposition).
         # The router owns the replica label — a caller-provided label set
         # would collapse N replicas onto one labelled series.
         engine_kw.pop("metrics_labels", None)
@@ -199,9 +190,7 @@ class FleetRouter:
             # the kill switch neutralizes the fleet tier TOTALLY — even an
             # explicitly-passed tier object is dropped (and left
             # unmutated), so `router.host_tier is None` is a truthful
-            # "tier off" signal and the bench detail never presents a
-            # live-but-idle store in a kill-switched run (the engines
-            # would each disable it anyway)
+            # "tier off" signal (the engines would each disable it anyway)
             self.host_tier = None
         elif self.host_tier is not None:
             self.host_tier.shared = True
@@ -225,11 +214,6 @@ class FleetRouter:
         finally:
             if spec is not None:
                 os.environ["PADDLE_TPU_FAULT_INJECT"] = spec
-        if not self.replicas[0]._graceful:
-            raise RuntimeError(
-                "FleetRouter requires PADDLE_TPU_GRACEFUL=1: failover, "
-                "hedging and draining are built on the graceful engine's "
-                "status lifecycle and cancel()")
         self.health: list[str] = ["HEALTHY"] * self.n_replicas
         # fleet-level request registry: rid -> caller's Request, LIVE only
         # (terminal requests are pruned, mirroring the engine's journal)
@@ -242,56 +226,35 @@ class FleetRouter:
         # rid -> hedge replica (first-writer-wins pending); ownership stays
         # with the primary until a copy extends the stream
         self._hedge: dict[int, int] = {}
-        # per-replica journal: the last snapshot(), refreshed after every
-        # completed step AND every dispatch — on death this is at most zero
-        # completed steps stale, so replay loses nothing the fleet had
-        # mirrored
+        # per-replica journal (docs/async_runtime.md): the replicas
+        # maintain their journals incrementally (O(changed rids), flushed
+        # inside each engine's host-overlap window) and the router pulls
+        # them ONLY at the boundaries that consume them — replica death
+        # and stall hedging (_journal_pull)
         self._journal: list[dict | None] = [None] * self.n_replicas
-        # async host runtime (docs/async_runtime.md): with the flag on the
-        # replicas maintain their journals incrementally (O(changed rids),
-        # flushed inside each engine's host-overlap window) and the router
-        # pulls them ONLY at the boundaries that consume them — replica
-        # death and stall hedging (_journal_pull) — instead of paying a
-        # full snapshot() rebuild per step and per dispatch.  Off, the
-        # historical per-step/per-dispatch snapshot() refreshes run
-        # byte-identically.
-        self._async_host = _env_bool("PADDLE_TPU_ASYNC_HOST", True)
         self._last_progress = [0] * self.n_replicas
         self._slow_streak = [0] * self.n_replicas
         self._ok_streak = [0] * self.n_replicas
         self._step_no = 0          # fleet step counter (replica-clause key)
         # fleet stats on the shared registry behind the same dict view the
-        # engines use (keys + help: observability.FLEET_STAT_SCHEMA);
-        # PADDLE_TPU_METRICS=0 restores the plain pre-observability dict.
-        # The fleet SLO tracker is the authority the chaos bench's
-        # goodput-at-SLO headline now reads from (fed in _mirror with the
-        # SAME timestamps that set each request's ttft_s).
-        if self._metrics_on:
-            self.stats = StatsView(self.metrics, FLEET_STAT_SCHEMA,
-                                   prefix="paddle_tpu_fleet")
-            self.slo = SLOTracker(self.metrics, prefix="paddle_tpu_fleet")
-            self._h_jupdate = self.metrics.histogram(
-                "paddle_tpu_fleet_journal_update_seconds",
-                "Host seconds per router journal refresh: async-on, one "
-                "incremental pull per consumption boundary (failover/"
-                "hedge); async-off, one full snapshot() rebuild per step "
-                "and per dispatch — the critical-path journal tax "
-                "(docs/async_runtime.md)"
-            ).labels()
-        else:
-            self.stats = {k: 0 for k in FLEET_STAT_SCHEMA}
-            self.slo = None
-            self._h_jupdate = None
+        # engines use (keys + help: observability.FLEET_STAT_SCHEMA).
+        # The fleet SLO tracker is fed in _mirror with the SAME timestamps
+        # that set each request's ttft_s.
+        self.stats = StatsView(self.metrics, FLEET_STAT_SCHEMA,
+                               prefix="paddle_tpu_fleet")
+        self.slo = SLOTracker(self.metrics, prefix="paddle_tpu_fleet")
+        self._h_jupdate = self.metrics.histogram(
+            "paddle_tpu_fleet_journal_update_seconds",
+            "Host seconds per router journal refresh: one incremental "
+            "pull per consumption boundary (failover/hedge; "
+            "docs/async_runtime.md)").labels()
         # one flow-link tracer per replica lane (the engines' own tracers
         # already own the span traffic on those pids; the router only adds
         # the cross-replica failover/hedge arrows and health markers)
-        self._tracers = [RequestTracer(enabled=self._metrics_on, pid=r)
+        self._tracers = [RequestTracer(pid=r)
                          for r in range(self.n_replicas)]
         self._flow_seq = 0
-        self._flight = (FlightRecorder(registry=(self.metrics
-                                                 if self._metrics_on
-                                                 else None), name="fleet")
-                        if flight_recorder_enabled() else None)
+        self._flight = FlightRecorder(registry=self.metrics, name="fleet")
         self._faults = FaultPlan()
         self._arm_faults_from_env()
         from ..analysis.engine_audit import audit_enabled
@@ -378,11 +341,9 @@ class FleetRouter:
             req.finished = True
             req.error = msg
             self.stats["fleet_rejected"] += 1
-            if self.slo is not None:
-                self.slo.finish(req.rid, "REJECTED", time.perf_counter())
-            if self._flight is not None:
-                self._flight.record("terminal", rid=req.rid,
-                                    status="REJECTED", error=msg)
+            self.slo.finish(req.rid, "REJECTED", time.perf_counter())
+            self._flight.record("terminal", rid=req.rid,
+                                status="REJECTED", error=msg)
 
     @staticmethod
     def _copy_req(req: Request) -> Request:
@@ -408,8 +369,7 @@ class FleetRouter:
         req._submit_s = time.perf_counter()
         if req.trace_id is None:
             req.trace_id = f"req-{req.rid:x}"
-        if self.slo is not None:
-            self.slo.begin(req.rid, req._submit_s)
+        self.slo.begin(req.rid, req._submit_s)
         probe = next((e for e in self.replicas if e is not None), None)
         if probe is None:
             self._reject(req, "every replica is DEAD (fleet lost)")
@@ -417,7 +377,7 @@ class FleetRouter:
         try:
             probe._validate(req)
         except ValueError as e:
-            # the graceful-serve contract, fleet edition: one bad request
+            # the engine's serve() contract, fleet edition: one bad request
             # must not raise out of the router
             self._reject(req, str(e))
             return
@@ -441,9 +401,8 @@ class FleetRouter:
             self._reject(req, msg)
             return
         self.stats["routed_affinity" if m > 0 else "routed_spill"] += 1
-        if self._flight is not None:
-            self._flight.record("route", rid=req.rid, replica=target,
-                                match_blocks=int(m))
+        self._flight.record("route", rid=req.rid, replica=target,
+                            match_blocks=int(m))
         copy = self._copy_req(req)
         self.replicas[target].add_request(copy)
         if copy.status == "REJECTED":       # defensive: _route pre-filtered
@@ -452,18 +411,10 @@ class FleetRouter:
         self._reqs[req.rid] = req
         self._owner[req.rid] = target
         self._copies[req.rid] = {target: copy}
-        # keep the journal current through dispatch, not just steps: a
-        # crash before the replica's next step must still replay this.
-        # Async host runtime: the replica's incremental journal already
-        # tracks the dispatch (add_request _jmarks the rid) and the
-        # router pulls it at the death/stall boundary instead — no full
-        # rebuild on the dispatch path.
-        if not self._async_host:
-            t0 = time.perf_counter()
-            self.stats["journal_full_rebuilds"] += 1
-            self._journal[target] = self.replicas[target].snapshot()
-            if self._h_jupdate is not None:
-                self._h_jupdate.observe(time.perf_counter() - t0)
+        # a crash before the replica's next step still replays this: the
+        # replica's incremental journal already tracks the dispatch
+        # (add_request _jmarks the rid) and the router pulls it at the
+        # death/stall boundary
 
     def cancel(self, rid: int) -> bool:
         """Fleet-level cancel: every replica copy (owner and any pending
@@ -483,8 +434,7 @@ class FleetRouter:
         f.status = "CANCELLED"
         f.finished = True
         f.error = "cancelled by caller"
-        if self.slo is not None:
-            self.slo.finish(rid, "CANCELLED", time.perf_counter())
+        self.slo.finish(rid, "CANCELLED", time.perf_counter())
         return True
 
     # ---------------- health + failover (pillar 2) ----------------
@@ -510,9 +460,8 @@ class FleetRouter:
             return
         self.health[r] = state
         now = time.perf_counter()
-        if self._flight is not None:
-            self._flight.record("health", replica=r, frm=prev, to=state,
-                                why=why)
+        self._flight.record("health", replica=r, frm=prev, to=state,
+                            why=why)
         self._tracers[r].instant(0, f"health:{state}", now,
                                  args={"replica": r, "from": prev,
                                        "why": why})
@@ -539,11 +488,9 @@ class FleetRouter:
                                 f"heartbeats")
 
     def _journal_pull(self, r: int) -> None:
-        """Async host runtime: pull replica ``r``'s incrementally-
-        maintained journal — the O(changed rids) replacement for the
-        per-step/per-dispatch ``snapshot()`` rebuilds, taken only at the
-        boundaries that actually consume it (replica death, stall
-        hedging; docs/async_runtime.md)."""
+        """Pull replica ``r``'s incrementally-maintained journal, at the
+        boundaries that consume it (replica death, stall hedging;
+        docs/async_runtime.md)."""
         eng = self.replicas[r]
         if eng is None:
             return
@@ -551,10 +498,8 @@ class FleetRouter:
         self._journal[r] = (eng.journal() if eng._reqs
                             else {"running": [], "queued": []})
         self.stats["journal_incremental_updates"] += 1
-        if self._h_jupdate is not None:
-            self._h_jupdate.observe(time.perf_counter() - t0)
-        if self._flight is not None:
-            self._flight.record("journal_pull", replica=r)
+        self._h_jupdate.observe(time.perf_counter() - t0)
+        self._flight.record("journal_pull", replica=r)
 
     def _audit_journal_equiv(self, r: int) -> None:
         """Under PADDLE_TPU_ENGINE_AUDIT=1: assert replica ``r``'s
@@ -580,8 +525,7 @@ class FleetRouter:
         if j != s:
             from ..analysis.engine_audit import EngineAuditError
 
-            if self._flight is not None:
-                self._flight.dump(f"journal_divergence replica={r}")
+            self._flight.dump(f"journal_divergence replica={r}")
             raise EngineAuditError(
                 f"incremental journal diverged from snapshot() on "
                 f"replica {r} (async host runtime): "
@@ -592,9 +536,7 @@ class FleetRouter:
         incrementally-maintained snapshot's, falling back to synthesizing
         one from the fleet-mirrored request via the SAME
         ``serving.journal_entry`` schema the snapshot uses (equivalent
-        content minus the prefill-cursor provenance — the journal
-        refreshes after every step and dispatch, and the mirror runs
-        first)."""
+        content minus the prefill-cursor provenance)."""
         j = self._journal[r] or {}
         for e in j.get("running", []) + j.get("queued", []):
             if e["rid"] == rid:
@@ -625,9 +567,8 @@ class FleetRouter:
             fid = f"{link}-{rid}-{self._flow_seq}"
             self._tracers[source].flow_out(rid, link, now, fid)
             self._tracers[target].flow_in(rid, link, now + 1e-6, fid)
-        if self._flight is not None:
-            self._flight.record(link, rid=rid, frm=source, to=target,
-                                replayed_tokens=len(entry["output_ids"]))
+        self._flight.record(link, rid=rid, frm=source, to=target,
+                            replayed_tokens=len(entry["output_ids"]))
         return target
 
     def _kill(self, r: int, reason: str) -> None:
@@ -640,11 +581,10 @@ class FleetRouter:
         replica)."""
         with RecordEvent("fleet/failover"):
             dead_eng = self.replicas[r]   # for the flight-recorder dump
-            if self._async_host:
-                # the death boundary IS the async runtime's journal
-                # consumption point: pull the incremental journal while
-                # the engine object is still here, then replay from it
-                self._journal_pull(r)
+            # the death boundary IS the journal's consumption point:
+            # pull the incremental journal while the engine object is
+            # still here, then replay from it
+            self._journal_pull(r)
             self._health_to(r, "DEAD", reason)
             self.replicas[r] = None
             self.stats["failovers"] += 1
@@ -671,24 +611,20 @@ class FleetRouter:
                     f.finished = True
                     f.error = (f"replica {r} died ({reason}) with no "
                                f"surviving replica to replay onto")
-                    if self.slo is not None:
-                        self.slo.finish(rid, "FAILED",
-                                        time.perf_counter())
+                    self.slo.finish(rid, "FAILED",
+                                    time.perf_counter())
                     continue
                 self._owner[rid] = target
             # replica death is a flight-recorder dump trigger: the
             # router's recent events + the DEAD replica's own ring + a
             # fleet metrics snapshot, so chaos triage reads what the
             # engine was doing when it died without a rerun
-            if self._flight is not None:
-                self._flight.dump(
-                    f"replica {r} DEAD: {reason}",
-                    extra={"replica": r,
-                           "engine_events": (
-                               dead_eng._flight.events()
-                               if dead_eng is not None
-                               and dead_eng._flight is not None
-                               else None)})
+            self._flight.dump(
+                f"replica {r} DEAD: {reason}",
+                extra={"replica": r,
+                       "engine_events": (
+                           dead_eng._flight.events()
+                           if dead_eng is not None else None)})
             # every live entry is replayed: holding the dead replica's
             # final snapshot past this point would retain its requests'
             # full token lists for the router's lifetime (the retention
@@ -720,12 +656,11 @@ class FleetRouter:
             if self.health[r] == "HEALTHY":
                 self._health_to(r, "DEGRADED",
                                 f"no progress for {gap} fleet steps")
-            if self._async_host:
-                # hedge boundary: refresh the stalled replica's journal
-                # from its incremental entries before replaying them
-                # (the stalled engine's host side is still reachable —
-                # it is the device step that is not completing)
-                self._journal_pull(r)
+            # hedge boundary: refresh the stalled replica's journal
+            # from its incremental entries before replaying them
+            # (the stalled engine's host side is still reachable —
+            # it is the device step that is not completing)
+            self._journal_pull(r)
             for rid in [rid for rid, o in self._owner.items() if o == r]:
                 if rid in self._hedge:
                     continue               # already hedge-pending
@@ -777,8 +712,7 @@ class FleetRouter:
         f.status = copy.status
         f.finished = True
         f.error = copy.error
-        if self.slo is not None:
-            self.slo.finish(rid, copy.status, time.perf_counter())
+        self.slo.finish(rid, copy.status, time.perf_counter())
 
     def _mirror(self, r: int) -> None:
         """After replica ``r`` steps: bank its copies' new tokens onto the
@@ -802,10 +736,9 @@ class FleetRouter:
                     # (on failover) replay recompute — the number an SLO
                     # is written against
                     f.ttft_s = now - f._submit_s
-                if self.slo is not None:
-                    # the SAME `now` that stamps ttft_s: the SLO tracker's
-                    # records are exactly the figures the caller observes
-                    self.slo.tokens(rid, delta, now)
+                # the SAME `now` that stamps ttft_s: the SLO tracker's
+                # records are exactly the figures the caller observes
+                self.slo.tokens(rid, delta, now)
             if self._owner.get(rid) != r:
                 # hedge twin that has not won: a self-inflicted terminal
                 # (failed/expired on the hedge target) just drops the hedge
@@ -824,8 +757,8 @@ class FleetRouter:
     def step(self) -> bool:
         """One fleet round: poll replica-scoped chaos, step every live
         replica once (replica-index order — the deterministic clock every
-        clause keys on), mirror outputs, refresh journals, advance health,
-        and hedge stalled work.  Returns False when the whole fleet is
+        clause keys on), mirror outputs, advance health, and hedge
+        stalled work.  Returns False when the whole fleet is
         idle."""
         self._step_no += 1
         busy = False
@@ -858,7 +791,7 @@ class FleetRouter:
             try:
                 stepped = eng.step()
             except Exception as e:
-                # a fault that escapes the graceful engine's step() is a
+                # a fault that escapes the engine's step() is a
                 # replica-fatal condition (persistent kernel failure):
                 # surface it as death, not a router crash
                 self._kill(r, f"engine fault escaped step(): {e}")
@@ -867,34 +800,14 @@ class FleetRouter:
             self._last_progress[r] = self._step_no
             self._note_heartbeat(r, ok=not slow)
             self._mirror(r)
-            if self._async_host:
-                # async host runtime: the replica flushed its dirty rids
-                # inside its own host-overlap window; the router defers
-                # consumption to the death/stall boundaries
-                # (_journal_pull) — zero per-step rebuild cost here
-                stepped_any = True
-                if self._audit_every_step:
-                    self._audit_journal_equiv(r)
-            else:
-                # journal refresh: O(live tokens) host work per replica
-                # per step — bounded by max_batch x max_seq ints, small
-                # next to a device step, and the price of a journal that
-                # is never a completed step stale when its replica dies.
-                # Idle replicas skip it (their journal is empty).
-                # Timed into journal_update_seconds either way: with the
-                # flag off this histogram IS the critical-path journal
-                # tax per step the async runtime exists to remove (the
-                # asynchost A/B reads its sum).
-                if eng._reqs:
-                    t0 = time.perf_counter()
-                    self.stats["journal_full_rebuilds"] += 1
-                    self._journal[r] = eng.snapshot()
-                    if self._h_jupdate is not None:
-                        self._h_jupdate.observe(time.perf_counter() - t0)
-                else:
-                    self._journal[r] = {"running": [], "queued": []}
+            # the replica flushed its dirty rids inside its own
+            # host-overlap window; the router defers consumption to the
+            # death/stall boundaries (_journal_pull) — no per-step cost
+            stepped_any = True
+            if self._audit_every_step:
+                self._audit_journal_equiv(r)
             busy = busy or stepped or self._has_live(r)
-        if self._async_host and stepped_any:
+        if stepped_any:
             self.stats["host_overlap_steps"] += 1
         self._detect_stalls()
         if self._audit_every_step:
@@ -904,8 +817,7 @@ class FleetRouter:
             try:
                 audit_fleet(self)
             except EngineAuditError:
-                if self._flight is not None:
-                    self._flight.dump("fleet_audit_error")
+                self._flight.dump("fleet_audit_error")
                 raise
         return busy or bool(self._reqs)
 

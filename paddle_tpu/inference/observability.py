@@ -23,8 +23,7 @@ autoscaling) steer by:
   ONE timeline (pid = replica, tid = request id).
 * :class:`SLOTracker` — streaming per-request TTFT / TBT / queue-wait
   accounting derived from the same host events that emit the spans, plus
-  :meth:`SLOTracker.goodput_at` — the goodput-at-SLO figure the fleet bench
-  used to hand-roll, now a first-class engine product.
+  :meth:`SLOTracker.goodput_at`, the goodput-at-SLO figure.
 * :class:`FlightRecorder` — a bounded ring buffer of recent engine events
   (admits, degradation-ladder rungs, health transitions, faults, evictions,
   step-packing summaries) dumped alongside a metrics snapshot whenever a
@@ -35,17 +34,10 @@ The recording contract
 ----------------------
 ALL recording is host-side and post-step: a metric/span/flight event is
 written only from the control plane, after (or before) a compiled launch,
-never from inside one — zero device syncs, and token streams are
-byte-identical with observability on or off (asserted by the test suite
-with prefix cache + speculation + chunked prefill + graceful + TP all on;
-the ``host_sync`` lint rule keeps any in-graph callback out of the gated
-serving programs).  Per-step cost is O(1) appends — small enough to stay
-off the hot path the host-gap histogram itself measures.
-
-Kill switches (``utils/envflags.BOOL_FLAGS``): ``PADDLE_TPU_METRICS=0``
-restores the plain pre-PR stats dicts (no registry, no spans, no SLO
-tracking) byte-identically; ``PADDLE_TPU_FLIGHT_RECORDER=0`` disables the
-ring buffer and its dumps.
+never from inside one — zero device syncs, so token streams cannot depend
+on it (the ``host_sync`` lint rule keeps any in-graph callback out of the
+gated serving programs).  Per-step cost is O(1) appends — small enough to
+stay off the hot path the host-gap histogram itself measures.
 """
 
 from __future__ import annotations
@@ -59,24 +51,7 @@ from collections.abc import MutableMapping
 __all__ = [
     "MetricsRegistry", "StatsView", "SLOTracker", "FlightRecorder",
     "RequestTracer", "ENGINE_STAT_SCHEMA", "FLEET_STAT_SCHEMA",
-    "metrics_enabled", "flight_recorder_enabled",
 ]
-
-
-def metrics_enabled() -> bool:
-    """``PADDLE_TPU_METRICS`` (default on): the registry + tracing + SLO
-    tier.  ``=0`` restores the plain pre-observability stats dicts."""
-    from ..utils.envflags import env_bool
-
-    return env_bool("PADDLE_TPU_METRICS", True)
-
-
-def flight_recorder_enabled() -> bool:
-    """``PADDLE_TPU_FLIGHT_RECORDER`` (default on): the bounded event ring
-    buffer and its failure-triggered dumps."""
-    from ..utils.envflags import env_bool
-
-    return env_bool("PADDLE_TPU_FLIGHT_RECORDER", True)
 
 
 # ---------------------------------------------------------------- metrics
@@ -240,12 +215,12 @@ class MetricsRegistry:
 
     def describe(self) -> dict[str, str]:
         """{metric name: help} — the introspection surface the stat-schema
-        test audits (every counter a test or bench reads must be here)."""
+        test audits (every counter a test reads must be here)."""
         return {n: f.help for n, f in sorted(self._families.items())}
 
     def expose(self) -> str:
         """Prometheus text exposition of every family, name-sorted — the
-        snapshot bench rungs embed and flight-recorder dumps attach."""
+        snapshot flight-recorder dumps attach."""
         lines: list[str] = []
         for name in sorted(self._families):
             self._families[name].expose_into(lines)
@@ -255,7 +230,7 @@ class MetricsRegistry:
 # ------------------------------------------------- stats-dict migration
 
 #: engine ``stats`` keys -> (metric kind, help).  THE schema — every
-#: counter key read anywhere in tests/ or bench.py must appear here with a
+#: counter key read anywhere in tests/ must appear here with a
 #: real help string (tests/test_observability.py scans the sources and
 #: enforces it), so the dict view and the exposition can never drift.
 ENGINE_STAT_SCHEMA = {
@@ -396,11 +371,6 @@ FLEET_STAT_SCHEMA = {
                                     "Incremental journal() pulls consumed "
                                     "from replicas (failover/hedge "
                                     "boundaries, docs/async_runtime.md)"),
-    "journal_full_rebuilds": ("counter",
-                              "Full replica snapshot() rebuilds taken by "
-                              "the router (per step/dispatch with "
-                              "PADDLE_TPU_ASYNC_HOST=0; zero steady-state "
-                              "async)"),
     "host_overlap_steps": ("counter",
                            "Fleet steps driven with snapshot refreshes "
                            "deferred to failover boundaries (async host "
@@ -410,7 +380,7 @@ FLEET_STAT_SCHEMA = {
 
 class StatsView(MutableMapping):
     """Dict-compatible facade over registry counters/gauges: every read and
-    write an existing test or bench makes against ``engine.stats`` /
+    write a caller makes against ``engine.stats`` /
     ``fleet.stats`` keeps working (``stats[k] += 1``, ``stats[k] = 0``,
     ``stats.update(...)``, ``dict(stats)``), while the same numbers appear
     labelled in ``registry.expose()``.  Keys outside the schema register on
@@ -475,14 +445,12 @@ class RequestTracer:
     Every emit is one bounded host-buffer append (the profiler cap drops
     and counts overflow) — O(1), post-step, zero device sync."""
 
-    def __init__(self, enabled: bool = True, pid: int = 0,
-                 process_name: str | None = None):
-        self.enabled = bool(enabled)
+    def __init__(self, pid: int = 0, process_name: str | None = None):
         self.pid = int(pid)
         self.counts: dict[str, int] = {}
         self._process_name = process_name
         self._meta_gen = None       # buffer generation the metadata is in
-        if self.enabled and process_name:
+        if process_name:
             self._emit_process_name()
 
     def _emit_process_name(self):
@@ -510,8 +478,6 @@ class RequestTracer:
              args: dict | None = None):
         """Complete span [t0_s, t1_s] (perf_counter seconds) on this
         tracer's replica lane, thread lane ``tid`` (the request id)."""
-        if not self.enabled:
-            return
         self._emit({"name": name, "ph": "X", "cat": "request",
                     "ts": t0_s * 1e6,
                     "dur": max(t1_s - t0_s, 0.0) * 1e6,
@@ -520,8 +486,6 @@ class RequestTracer:
 
     def instant(self, tid: int, name: str, t_s: float,
                 args: dict | None = None):
-        if not self.enabled:
-            return
         self._emit({"name": name, "ph": "i", "s": "t", "cat": "request",
                     "ts": t_s * 1e6, "pid": self.pid, "tid": int(tid),
                     **({"args": args} if args else {})}, name)
@@ -530,15 +494,11 @@ class RequestTracer:
         """Link origin (e.g. the dead replica's last journal state): pairs
         with a :meth:`flow_in` of the same ``flow_id`` on another replica's
         tracer — chrome draws the arrow across process lanes."""
-        if not self.enabled:
-            return
         self._emit({"name": name, "ph": "s", "cat": "link", "id": flow_id,
                     "ts": t_s * 1e6, "pid": self.pid, "tid": int(tid)},
                    name)
 
     def flow_in(self, tid: int, name: str, t_s: float, flow_id: str):
-        if not self.enabled:
-            return
         self._emit({"name": name, "ph": "f", "bp": "e", "cat": "link",
                     "id": flow_id, "ts": t_s * 1e6, "pid": self.pid,
                     "tid": int(tid)}, name)
@@ -567,10 +527,9 @@ class SLOTracker:
 
     TBT semantics match what a caller observes: a *banking event* (one
     host fetch delivering >= 1 tokens to a request) is one arrival, and
-    gaps are measured between consecutive arrivals — exactly how the fleet
-    bench's hand-rolled poll loop measured them before this tracker made
-    the figure first-class.  :meth:`goodput_at` is the headline:
-    tokens of FINISHED requests that met BOTH latency bounds."""
+    gaps are measured between consecutive arrivals.  :meth:`goodput_at`
+    is the headline: tokens of FINISHED requests that met BOTH latency
+    bounds."""
 
     def __init__(self, registry: MetricsRegistry | None = None,
                  labels: dict | None = None,
@@ -645,7 +604,7 @@ class SLOTracker:
         FINISHED, produced a first token within ``ttft_slo_s`` of submit,
         and never gapped longer than ``tbt_slo_s`` between arrivals.
         Returns ``{"requests", "tokens", "rids"}`` — divide tokens by the
-        serve's wall clock for the bench headline."""
+        serve's wall clock for a goodput rate."""
         rids, toks = [], 0
         for rec in self.records:
             if rec["status"] != "FINISHED" or rec["ttft_s"] is None:

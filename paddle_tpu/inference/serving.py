@@ -121,9 +121,8 @@ streams, the spec path resumes once prefill drains).  Opt-out:
 ``PADDLE_TPU_CHUNKED_PREFILL=0``; chunked-off the engine is byte-identical
 to the bucketed-prefill engine.
 
-Fault tolerance (docs/fault_tolerance.md; default on, kill switch
-``PADDLE_TPU_GRACEFUL=0`` restores the brittle pre-fault-tolerance engine
-byte-identically): every request ends in a terminal ``status``
+Fault tolerance (docs/fault_tolerance.md): every request ends in a terminal
+``status``
 (``FINISHED | FAILED | REJECTED | CANCELLED | EXPIRED``) and no per-request
 fault escapes ``step()`` — the offending request is failed, its pages and
 cache refs released, and every surviving request's token stream is
@@ -416,9 +415,7 @@ class ContinuousBatchingEngine:
         optional shared :class:`~paddle_tpu.inference.observability.
         MetricsRegistry` plus constant label set (e.g. ``{"replica": k}``
         — how the FleetRouter aggregates N replicas into one exposition);
-        by default the engine creates its own registry.  Ignored with
-        ``PADDLE_TPU_METRICS=0``, which restores the plain pre-
-        observability ``stats`` dict."""
+        by default the engine creates its own registry."""
         from ..models import llama as _llama  # noqa: F401  (cfg type lives there)
 
         self.cfg = cfg
@@ -639,8 +636,9 @@ class ContinuousBatchingEngine:
         # instead of raising, honoring "forces it off regardless".
         # env_bool validates the value: a typo ('off') warns instead of
         # silently leaving the cache enabled (utils/envflags.py)
-        from ..utils.envflags import env_bool
+        from ..utils.envflags import env_bool, warn_retired_flags
 
+        warn_retired_flags()
         if enable_prefix_caching and env_bool("PADDLE_TPU_PREFIX_CACHE",
                                               True):
             if not paged:
@@ -736,12 +734,7 @@ class ContinuousBatchingEngine:
         self._topp = np.ones(max_batch, np.float32)
         self._seed = np.zeros(max_batch, np.int32)
         self._queue: list[Request] = []
-        # fault tolerance (docs/fault_tolerance.md).  ``_graceful`` is a
-        # TRACE-TIME static: with PADDLE_TPU_GRACEFUL=0 every compiled
-        # program below traces the pre-fault-tolerance jaxpr byte-for-byte
-        # (no poison operand, no guard flags) and faults raise out of
-        # step() exactly as they always did.
-        self._graceful = env_bool("PADDLE_TPU_GRACEFUL", True)
+        # fault tolerance (docs/fault_tolerance.md)
         from .faults import FaultPlan
 
         self._faults = FaultPlan.from_env()
@@ -751,8 +744,8 @@ class ContinuousBatchingEngine:
         # snapshot()'s journal source, and the auditor's I8 witness set
         self._reqs: dict[int, Request] = {}
         # per-slot sampler-seam poison bits (nan_logits injection): DATA to
-        # the graceful compiled steps, where they become a genuinely
-        # non-finite logits row the in-graph guard must catch
+        # the compiled steps, where they become a genuinely non-finite
+        # logits row the in-graph guard must catch
         self._poison = np.zeros(max_batch, bool)
         self._kernel_err_streak = 0
         # consecutive failed launches tolerated before giving up: a raise at
@@ -767,13 +760,9 @@ class ContinuousBatchingEngine:
         # two decode variants behind a STATIC sampling flag: the full-vocab
         # sort/softmax/categorical of the sampler must not run (XLA cannot
         # DCE work behind a data-dependent where) when every resident slot
-        # is greedy — the bench headline's configuration
-        self._decode_greedy = self._jit_step(
-            impl, n_rep=2 if self._graceful else 1, sampling=False,
-            graceful=self._graceful)
-        self._decode_sampling = self._jit_step(
-            impl, n_rep=2 if self._graceful else 1, sampling=True,
-            graceful=self._graceful)
+        # is greedy.  n_rep: tokens + guard flags ride back replicated.
+        self._decode_greedy = self._jit_step(impl, n_rep=2, sampling=False)
+        self._decode_sampling = self._jit_step(impl, n_rep=2, sampling=True)
         # prefill writes its lane directly into the donated pool arrays —
         # no slice-out/scatter-back copies of the full pool per admission
         pimpl = self._prefill_impl_paged if paged else self._prefill_impl
@@ -805,11 +794,9 @@ class ContinuousBatchingEngine:
             # per sampling mode for the whole serve, no shape-family churn
             self._spec_qmax = int(num_draft_tokens) + 1
             self._verify_greedy = self._jit_step(
-                self._verify_impl_paged, n_rep=3 if self._graceful else 2,
-                sampling=False, graceful=self._graceful)
+                self._verify_impl_paged, n_rep=3, sampling=False)
             self._verify_sampling = self._jit_step(
-                self._verify_impl_paged, n_rep=3 if self._graceful else 2,
-                sampling=True, graceful=self._graceful)
+                self._verify_impl_paged, n_rep=3, sampling=True)
         # chunked prefill + unified mixed prefill/decode step (stall-free
         # continuous batching; docs/chunked_prefill.md).  Like the prefix
         # cache and speculation, EVERY chunked behavior hangs off
@@ -849,69 +836,53 @@ class ContinuousBatchingEngine:
             # serve: chunk packing / per-slot progress are q_lens/pos DATA,
             # so prefill goes from log2(max_seq) bucketed variants to O(1)
             self._mixed_greedy = self._jit_step(
-                self._mixed_impl_paged, n_rep=2 if self._graceful else 1,
-                sampling=False, graceful=self._graceful)
+                self._mixed_impl_paged, n_rep=2, sampling=False)
             self._mixed_sampling = self._jit_step(
-                self._mixed_impl_paged, n_rep=2 if self._graceful else 1,
-                sampling=True, graceful=self._graceful)
+                self._mixed_impl_paged, n_rep=2, sampling=True)
         # ---- observability (ISSUE 11, docs/observability.md) ----
         # stats live on a typed MetricsRegistry behind a dict-compatible
         # view (keys + help strings: observability.ENGINE_STAT_SCHEMA), so
-        # every existing ``eng.stats[...]`` read keeps working while the
-        # same counters show up labelled in ``metrics.expose()``; the SLO
-        # tracker and request tracer feed off the same host events.  ALL
-        # recording is host-side post-step — the compiled programs above
-        # are untouched either way, so token streams are byte-identical
-        # with PADDLE_TPU_METRICS=0 (which restores the plain dict) or 1.
+        # ``eng.stats[...]`` reads like a dict while the same counters show
+        # up labelled in ``metrics.expose()``; the SLO tracker and request
+        # tracer feed off the same host events.  ALL recording is
+        # host-side post-step — the compiled programs above never see it.
         from .observability import (ENGINE_STAT_SCHEMA, FlightRecorder,
                                     MetricsRegistry, RequestTracer,
-                                    SLOTracker, StatsView,
-                                    flight_recorder_enabled, metrics_enabled)
+                                    SLOTracker, StatsView)
 
         self._obs_labels = dict(metrics_labels or {})
         replica = self._obs_labels.get("replica")
         obs_name = (f"replica-{replica}" if replica is not None
                     else "engine")
-        if metrics_enabled():
-            self.metrics = (metrics if metrics is not None
-                            else MetricsRegistry())
-            self.stats = StatsView(self.metrics, ENGINE_STAT_SCHEMA,
-                                   self._obs_labels)
-            self.slo = SLOTracker(self.metrics, self._obs_labels)
-            self._h_hostgap = self.metrics.histogram(
-                "paddle_tpu_serving_host_gap_seconds",
-                "Host-side gap between the end of one compiled serving "
-                "step and the next launch (scheduler/drafter/router time "
-                "the device sits idle — ROADMAP item 5's target)"
-            ).labels(**self._obs_labels)
-            self._h_step = self.metrics.histogram(
-                "paddle_tpu_serving_step_seconds",
-                "Wall seconds per compiled serving step (launch to host "
-                "fetch)").labels(**self._obs_labels)
-            self._h_h2d = (self.metrics.histogram(
-                "paddle_tpu_serving_h2d_restore_seconds",
-                "Host->device dispatch seconds per tier page restore "
-                "(kv_tier ship_in: two donated pool writes, overlapped "
-                "with the next compiled step by async dispatch)")
-                .labels(**self._obs_labels) if self._tier is not None
-                else None)
-            self._h_jupdate = self.metrics.histogram(
-                "paddle_tpu_serving_journal_update_seconds",
-                "Host seconds per incremental journal flush (dirty-rid "
-                "entry rebuilds overlapped with the in-flight device "
-                "step, docs/async_runtime.md)").labels(**self._obs_labels)
-            self._tracer = RequestTracer(
-                enabled=True,
-                pid=int(replica) if replica is not None else 0,
-                process_name=obs_name)
-        else:
-            self.metrics = None
-            self.slo = None
-            self._h_hostgap = self._h_step = self._h_h2d = None
-            self._h_jupdate = None
-            self._tracer = RequestTracer(enabled=False)
-            self.stats = {k: (0.0 if kind == "gauge" else 0)
-                          for k, (kind, _) in ENGINE_STAT_SCHEMA.items()}
+        self.metrics = metrics if metrics is not None else MetricsRegistry()
+        self.stats = StatsView(self.metrics, ENGINE_STAT_SCHEMA,
+                               self._obs_labels)
+        self.slo = SLOTracker(self.metrics, self._obs_labels)
+        self._h_hostgap = self.metrics.histogram(
+            "paddle_tpu_serving_host_gap_seconds",
+            "Host-side gap between the end of one compiled serving "
+            "step and the next launch (scheduler/drafter/router time "
+            "the device sits idle — ROADMAP item 5's target)"
+        ).labels(**self._obs_labels)
+        self._h_step = self.metrics.histogram(
+            "paddle_tpu_serving_step_seconds",
+            "Wall seconds per compiled serving step (launch to host "
+            "fetch)").labels(**self._obs_labels)
+        self._h_h2d = (self.metrics.histogram(
+            "paddle_tpu_serving_h2d_restore_seconds",
+            "Host->device dispatch seconds per tier page restore "
+            "(kv_tier ship_in: two donated pool writes, overlapped "
+            "with the next compiled step by async dispatch)")
+            .labels(**self._obs_labels) if self._tier is not None
+            else None)
+        self._h_jupdate = self.metrics.histogram(
+            "paddle_tpu_serving_journal_update_seconds",
+            "Host seconds per incremental journal flush (dirty-rid "
+            "entry rebuilds overlapped with the in-flight device "
+            "step, docs/async_runtime.md)").labels(**self._obs_labels)
+        self._tracer = RequestTracer(
+            pid=int(replica) if replica is not None else 0,
+            process_name=obs_name)
         self._last_step_end = None     # host-gap histogram anchor
         # the step's open phase span (serving/admit ... serving/bank), when
         # it began, and the seconds this step has spent waiting on the
@@ -921,9 +892,8 @@ class ContinuousBatchingEngine:
         self._device_wait_s = 0.0
         # flight recorder: bounded ring of recent engine events, dumped
         # (with a metrics snapshot) on request failure / audit error —
-        # chaos triage without a rerun.  Independent kill switch.
-        self._flight = (FlightRecorder(registry=self.metrics, name=obs_name)
-                        if flight_recorder_enabled() else None)
+        # chaos triage without a rerun
+        self._flight = FlightRecorder(registry=self.metrics, name=obs_name)
         # opt-in runtime invariant auditor (PADDLE_TPU_ENGINE_AUDIT=1):
         # cross-checks allocator / block-table / prefix-cache bookkeeping
         # after admission and after every decode chunk, raising
@@ -938,11 +908,7 @@ class ContinuousBatchingEngine:
         # terminal marks the rid dirty and _jflush rebuilds just those
         # entries.  The flush runs inside _host_overlap(), i.e. while
         # the device executes the already-launched step, so steady-state
-        # journal upkeep costs the host-gap nothing.  The dirty marks
-        # themselves are unconditional (a set.add); the flag only gates
-        # the overlap window and the fleet's consumption, so
-        # PADDLE_TPU_ASYNC_HOST=0 leaves the serial loop byte-identical.
-        self._async_host = env_bool("PADDLE_TPU_ASYNC_HOST", True)
+        # journal upkeep costs the host-gap nothing.
         self._jentries: dict[int, dict] = {}
         self._jdirty: set[int] = set()
 
@@ -1269,11 +1235,11 @@ class ContinuousBatchingEngine:
         return jnp.where(temp > 0.0, sampled.astype(jnp.int32), greedy)
 
     def _guard_logits(self, logits, active, poison):
-        """In-graph NaN/inf logit guard (graceful mode only): flag every
-        ACTIVE slot whose logits row is non-finite — numerically poisoned by
-        the model, or by the ``nan_logits`` fault-injection poison bit —
-        and replace the row with zeros so the sampler stays finite (the
-        host discards a flagged slot's token and quarantines the request).
+        """In-graph NaN/inf logit guard: flag every ACTIVE slot whose logits
+        row is non-finite — numerically poisoned by the model, or by the
+        ``nan_logits`` fault-injection poison bit — and replace the row with
+        zeros so the sampler stays finite (the host discards a flagged
+        slot's token and quarantines the request).
         Pure element-wise ops: no callback, no host sync — the flags ride
         back with the step's tokens in the same device fetch.  Inactive
         lanes are excluded: their garbage logits may be legitimately
@@ -1288,16 +1254,15 @@ class ContinuousBatchingEngine:
 
     def _chunk_scan(self, params, cache_k, cache_v, tokens, pos, active,
                     temp, topp, seeds, table=None, poison=None,
-                    sampling=False, graceful=False):
+                    sampling=False):
         """``chunk`` decode steps in one compiled program; the chosen token
         feeds back on-device (no host round-trip inside the chunk).
         ``sampling`` is STATIC: the greedy variant compiles without the
-        sampler's full-vocab sort.  ``graceful`` is STATIC too: off, the
-        program is byte-identical to the pre-fault-tolerance engine; on, a
-        ``poison`` operand feeds the in-graph NaN/inf guard and per-step
-        guard flags [chunk, B] come back with the tokens.  Returns
-        (tokens [chunk, B][, bad [chunk, B]], caches)."""
-        if graceful and poison is None:
+        sampler's full-vocab sort.  The ``poison`` operand feeds the
+        in-graph NaN/inf guard, and the per-step guard flags [chunk, B]
+        come back with the tokens.  Returns (tokens [chunk, B],
+        bad [chunk, B], caches)."""
+        if poison is None:
             # direct callers (lint targets, tests) may omit the injection
             # operand; a zeros vector traces the same guarded program
             poison = jnp.zeros_like(active)
@@ -1306,28 +1271,22 @@ class ContinuousBatchingEngine:
             ck, cv, tok, p = carry
             logits, ck, cv = self._decode_one(params, ck, cv, tok, p, active,
                                               table)
-            if graceful:
-                logits, bad = self._guard_logits(logits, active, poison)
+            logits, bad = self._guard_logits(logits, active, poison)
             if sampling:
                 nxt = self._sample_tokens(logits, p, temp, topp, seeds)
             else:
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return ((ck, cv, nxt, p + 1),
-                    (nxt, bad) if graceful else nxt)
+            return (ck, cv, nxt, p + 1), (nxt, bad)
 
-        (ck, cv, _, _), out = jax.lax.scan(
+        (ck, cv, _, _), (toks, bad) = jax.lax.scan(
             one, (cache_k, cache_v, tokens, pos), None, length=self.chunk)
-        if graceful:
-            toks, bad = out
-            return toks, bad, ck, cv
-        return out, ck, cv
+        return toks, bad, ck, cv
 
     def _decode_impl(self, params, cache_k, cache_v, tokens, pos, active,
-                     temp, topp, seeds, poison=None, sampling=False,
-                     graceful=False):
+                     temp, topp, seeds, poison=None, sampling=False):
         return self._chunk_scan(params, cache_k, cache_v, tokens, pos, active,
                                 temp, topp, seeds, poison=poison,
-                                sampling=sampling, graceful=graceful)
+                                sampling=sampling)
 
     def _prefill_body(self, params, ids, cache_k, cache_v, length, bucket,
                       write, start=None):
@@ -1393,10 +1352,10 @@ class ContinuousBatchingEngine:
 
     def _decode_impl_paged(self, params, cache_k, cache_v, tokens, pos, active,
                            temp, topp, seeds, table, poison=None,
-                           sampling=False, graceful=False):
+                           sampling=False):
         return self._chunk_scan(params, cache_k, cache_v, tokens, pos, active,
                                 temp, topp, seeds, table, poison=poison,
-                                sampling=sampling, graceful=graceful)
+                                sampling=sampling)
 
     def _prefill_impl_paged(self, params, ids, cache_k, cache_v, table_row,
                             length, bucket):
@@ -1555,7 +1514,7 @@ class ContinuousBatchingEngine:
 
     def _verify_impl_paged(self, params, cache_k, cache_v, tokens, pos,
                            active, q_lens, temp, topp, seeds, table,
-                           poison=None, sampling=False, graceful=False):
+                           poison=None, sampling=False):
         """Verify + accept in ONE compiled program.  Row t's logits condition
         on draft tokens <= t; the emitted token for position pos+t+1 is drawn
         with the SAME (seed, pos+t)-derived key ``_sample_tokens`` would use
@@ -1565,27 +1524,27 @@ class ContinuousBatchingEngine:
         token-identical to the non-speculative engine (greedy AND seeded
         sampled), not merely distribution-preserving.  Returns
         (out [B, Q] chosen tokens per row, n_emitted [B] in 1..q_lens,
-        caches); host code consumes out[:, :n_emitted]."""
+        bad [B] guard flags, caches); host code consumes
+        out[:, :n_emitted]."""
         logits, ck, cv = self._verify_one(params, cache_k, cache_v, tokens,
                                           pos, active, q_lens, table)
         Q = tokens.shape[1]
-        if graceful:
-            # per-slot guard over the LIVE rows only (rows past q_lens are
-            # computed from garbage positions and may be legitimately
-            # non-finite); a flagged slot's whole verify output is discarded
-            # by the host, so one [B] flag per slot suffices
-            if poison is None:
-                poison = jnp.zeros_like(active)
-            # poison bit FIRST, as a genuinely NaN row (same contract as
-            # _guard_logits): injection exercises the finiteness check a
-            # real numerical blowup hits — never a parallel flag-only path
-            row = jnp.where(poison, jnp.float32(jnp.nan), jnp.float32(0.0))
-            logits = logits + row[:, None, None].astype(logits.dtype)
-            live = jnp.arange(Q)[None, :] < q_lens[:, None]
-            rowbad = (~jnp.isfinite(logits).all(axis=-1)) & live
-            bad = active & rowbad.any(axis=-1)
-            logits = jnp.where(bad[:, None, None], jnp.zeros_like(logits),
-                               logits)
+        # per-slot guard over the LIVE rows only (rows past q_lens are
+        # computed from garbage positions and may be legitimately
+        # non-finite); a flagged slot's whole verify output is discarded
+        # by the host, so one [B] flag per slot suffices
+        if poison is None:
+            poison = jnp.zeros_like(active)
+        # poison bit FIRST, as a genuinely NaN row (same contract as
+        # _guard_logits): injection exercises the finiteness check a
+        # real numerical blowup hits — never a parallel flag-only path
+        row = jnp.where(poison, jnp.float32(jnp.nan), jnp.float32(0.0))
+        logits = logits + row[:, None, None].astype(logits.dtype)
+        live = jnp.arange(Q)[None, :] < q_lens[:, None]
+        rowbad = (~jnp.isfinite(logits).all(axis=-1)) & live
+        bad = active & rowbad.any(axis=-1)
+        logits = jnp.where(bad[:, None, None], jnp.zeros_like(logits),
+                           logits)
         if sampling:
             pos_t = pos[:, None] + jnp.arange(Q)[None, :]
             out = jax.vmap(
@@ -1600,11 +1559,9 @@ class ContinuousBatchingEngine:
         ok = ((tokens[:, 1:] == out[:, :-1])
               & (jnp.arange(1, Q)[None, :] < q_lens[:, None]))
         n_emitted = 1 + jnp.cumprod(ok.astype(jnp.int32), axis=1).sum(axis=1)
-        if graceful:
-            # the guard flags ride back with the step's tokens — no extra
-            # device fetch; the host quarantines flagged slots
-            return out, n_emitted.astype(jnp.int32), bad, ck, cv
-        return out, n_emitted.astype(jnp.int32), ck, cv
+        # the guard flags ride back with the step's tokens — no extra
+        # device fetch; the host quarantines flagged slots
+        return out, n_emitted.astype(jnp.int32), bad, ck, cv
 
     # -------- unified mixed prefill/decode step (compiled program) --------
 
@@ -1691,7 +1648,7 @@ class ContinuousBatchingEngine:
 
     def _mixed_impl_paged(self, params, cache_k, cache_v, tokens, pos,
                           active, q_lens, temp, topp, seeds, table,
-                          poison=None, sampling=False, graceful=False):
+                          poison=None, sampling=False):
         """Mixed step + emit in ONE compiled program.  The emitted token for
         slot b is drawn from its emit row's logits with the SAME
         (seed, pos + q_lens - 1)-derived key ``_sample_tokens`` uses in the
@@ -1700,26 +1657,24 @@ class ContinuousBatchingEngine:
         token (emit row at the last prompt token's position, the exact key
         the unchunked engine's first decode step derives) are
         token-identical to the bucketed-prefill engine, greedy AND seeded
-        sampled.  Returns (next token [B], caches); the host consumes a
-        lane's token only when it decoded or finished its prompt."""
+        sampled.  Returns (next token [B], bad [B] guard flags, caches);
+        the host consumes a lane's token only when it decoded or finished
+        its prompt."""
         logits, ck, cv = self._mixed_one(params, cache_k, cache_v, tokens,
                                          pos, active, q_lens, table)
-        if graceful:
-            # the emit row is each slot's ONLY row through the lm_head: a
-            # non-finite emit (numerical blowup or the nan_logits poison
-            # bit) flags the slot; the host quarantines the request instead
-            # of banking garbage.  One [B] flag, fetched with the tokens.
-            if poison is None:
-                poison = jnp.zeros_like(active)
-            logits, bad = self._guard_logits(logits, active, poison)
+        # the emit row is each slot's ONLY row through the lm_head: a
+        # non-finite emit (numerical blowup or the nan_logits poison
+        # bit) flags the slot; the host quarantines the request instead
+        # of banking garbage.  One [B] flag, fetched with the tokens.
+        if poison is None:
+            poison = jnp.zeros_like(active)
+        logits, bad = self._guard_logits(logits, active, poison)
         if sampling:
             nxt = self._sample_tokens(logits, pos + q_lens - 1, temp, topp,
                                       seeds)
         else:
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        if graceful:
-            return nxt, bad, ck, cv
-        return nxt, ck, cv
+        return nxt, bad, ck, cv
 
     # ---------------- block allocator (host control plane) ----------------
 
@@ -1740,9 +1695,8 @@ class ContinuousBatchingEngine:
             # pages may be free — drives the overload ladder adversarially
             # without needing a genuinely tiny pool.  Polled only when a
             # real grab would happen, so no-op calls never consume firings.
-            if self._flight is not None:
-                self._flight.record("fault", fault="alloc_fail", slot=slot,
-                                    step=self._step_no)
+            self._flight.record("fault", fault="alloc_fail", slot=slot,
+                                step=self._step_no)
             return False
         while base + len(owned) < n_blocks:
             if not self._free:
@@ -1777,8 +1731,7 @@ class ContinuousBatchingEngine:
                 self._demote(pairs)
             self._free.extend(page for _, page in pairs)
             self.stats["prefix_evictions"] += len(pairs)
-            if self._flight is not None:
-                self._flight.record("evict", pages=len(pairs))
+            self._flight.record("evict", pages=len(pairs))
         return len(pairs)
 
     # -------- hierarchical KV: demote / re-admit (docs/kv_tier.md) --------
@@ -1817,9 +1770,8 @@ class ContinuousBatchingEngine:
                         self.stats["tier_demotions"] += 1
         self.stats["tier_bytes"] = self._tier.used_bytes
         self.stats["tier_evictions"] = self._tier.evictions
-        if self._flight is not None:
-            self._flight.record("tier_demote", pages=len(pairs),
-                                tier_bytes=int(self._tier.used_bytes))
+        self._flight.record("tier_demote", pages=len(pairs),
+                            tier_bytes=int(self._tier.used_bytes))
 
     def _restore_tier_block(self, slot: int, req, ids, b: int, h: str,
                             parent: str | None) -> bool:
@@ -1840,9 +1792,8 @@ class ContinuousBatchingEngine:
             # and ship_in — the engine must fall back to normal prefill,
             # never hang or corrupt
             self._tier.discard(h)
-            if self._flight is not None:
-                self._flight.record("fault", fault="tier_drop", slot=slot,
-                                    step=self._step_no)
+            self._flight.record("fault", fault="tier_drop", slot=slot,
+                                step=self._step_no)
         if h in self._pcache._by_hash:
             # another slot restored or computed the same chain block since
             # this plan was made: map the HBM-resident copy instead (a
@@ -1903,11 +1854,9 @@ class ContinuousBatchingEngine:
         self._slot_shared[slot].append(h)
         self.stats["tier_readmits"] += 1
         self.stats["tier_bytes"] = self._tier.used_bytes
-        if self._h_h2d is not None:
-            self._h_h2d.observe(time.perf_counter() - t0)
-        if self._flight is not None:
-            self._flight.record("tier_readmit", rid=req.rid, slot=slot,
-                                block=b, page=dst)
+        self._h_h2d.observe(time.perf_counter() - t0)
+        self._flight.record("tier_readmit", rid=req.rid, slot=slot,
+                            block=b, page=dst)
         return True
 
     def _tier_restore_step(self, s: int, ids,
@@ -2015,11 +1964,7 @@ class ContinuousBatchingEngine:
                                               step=self._step_no, slot=slot):
             # prefix-cache seam (faults.py): a registration fault degrades
             # — the blocks stay private (a future request misses where it
-            # could have hit) and NO request fails; graceful-off restores
-            # the raise-out-of-step behavior
-            if not self._graceful:
-                raise FaultInjected(f"injected cache_error (step "
-                                    f"{self._step_no}, slot {slot})")
+            # could have hit) and NO request fails
             return
         # continue the chain from the mapped shared prefix — each new block
         # is hashed exactly once (inside register), nothing is re-hashed
@@ -2116,13 +2061,11 @@ class ContinuousBatchingEngine:
         self._queue.insert(0, req)
         self._jmark(req.rid)
         self.stats["preemptions"] += 1
-        if self._flight is not None:
-            self._flight.record("degrade", rung=4, what="preempt",
-                                rid=req.rid, slot=slot)
-        if self._graceful:
-            # every preemption is pool-pressure-driven, so in graceful mode
-            # it IS ladder rung 4 (rungs 1-3 already ran and left a deficit)
-            self.stats["degrade_preempt"] += 1
+        self._flight.record("degrade", rung=4, what="preempt",
+                            rid=req.rid, slot=slot)
+        # every preemption is pool-pressure-driven, so it IS ladder rung 4
+        # (rungs 1-3 already ran and left a deficit)
+        self.stats["degrade_preempt"] += 1
 
     def _ensure_growth(self, k):
         """Before a decode chunk: every active slot needs pages covering
@@ -2157,14 +2100,12 @@ class ContinuousBatchingEngine:
                            f"{self._evictable()} evictable cached, {pinned} "
                            f"pinned cached, {self.num_blocks} total); "
                            f"increase num_blocks")
-                    if self._graceful:
-                        # ladder rung 5 (docs/fault_tolerance.md): eviction,
-                        # degradation and preemption are all exhausted —
-                        # fail ONLY the unsatisfiable request.  Its pages
-                        # free immediately; survivors never see the fault.
-                        self._fail_slot(slot, "FAILED", msg, donate=True)
-                        break
-                    raise RuntimeError(msg)
+                    # ladder rung 5 (docs/fault_tolerance.md): eviction,
+                    # degradation and preemption are all exhausted — fail
+                    # ONLY the unsatisfiable request.  Its pages free
+                    # immediately; survivors never see the fault.
+                    self._fail_slot(slot, "FAILED", msg, donate=True)
+                    break
                 self._preempt(max(victims, key=lambda s: self._slot_age[s]))
 
     # ---------------- scheduler ----------------
@@ -2201,11 +2142,10 @@ class ContinuousBatchingEngine:
         # window, and a device-array prompt would turn that into a blocking
         # transfer mid-pipeline (host_blocking, analysis/host_contracts.py)
         req.prompt_ids = np.asarray(req.prompt_ids, np.int32).ravel()
-        req._submit_s = time.perf_counter()  # TTFT epoch (bench rung detail)
+        req._submit_s = time.perf_counter()  # TTFT epoch
         if req.trace_id is None:
             req.trace_id = f"req-{req.rid:x}"
-        if self.slo is not None:
-            self.slo.begin(req.rid, req._submit_s)
+        self.slo.begin(req.rid, req._submit_s)
         self._reqs[req.rid] = req
         if (self.max_queue is not None
                 and len(self._queue) >= self.max_queue):
@@ -2214,8 +2154,6 @@ class ContinuousBatchingEngine:
             # bypass add_request — accepted work is never rejected)
             msg = (f"queue full ({len(self._queue)} waiting, "
                    f"max_queue={self.max_queue})")
-            if not self._graceful:
-                raise RuntimeError(f"request {req.rid}: {msg}")
             with RecordEvent("serving/rejected"):
                 self._terminal(req, "REJECTED", msg)
             return
@@ -2309,22 +2247,17 @@ class ContinuousBatchingEngine:
                             # entry mid-plan (the chunked cursor spans
                             # steps between match and restore)
                             self._tier.pin(h)
-                        if self._flight is not None:
-                            self._flight.record("tier_match", rid=req.rid,
-                                                blocks=len(tier_plan))
+                        self._flight.record("tier_match", rid=req.rid,
+                                            blocks=len(tier_plan))
                 n_restored = 0
-                if tier_plan and not (self._chunked and self._graceful):
-                    # bucketed engines — and chunked GRACEFUL-OFF ones,
-                    # whose admission allocates the whole prompt's
-                    # private pages upfront, leaving no block boundary
-                    # the cursor-driven restore could append shared
-                    # pages at — restore at admission: each block takes
-                    # a free page and registers into the prefix cache
-                    # exactly like a freshly-prefilled block, then
-                    # prefill (bucketed, or the cursor from ``start``)
-                    # begins past the restored coverage.  A mid-walk
-                    # failure (pool dry, tier_drop) falls back to
-                    # prefill for the remainder — never a hang.
+                if tier_plan and not self._chunked:
+                    # bucketed engines restore at admission: each block
+                    # takes a free page and registers into the prefix
+                    # cache exactly like a freshly-prefilled block, then
+                    # the bucketed prefill begins past the restored
+                    # coverage.  A mid-walk failure (pool dry, tier_drop)
+                    # falls back to prefill for the remainder — never a
+                    # hang.
                     for b, h, parent in tier_plan:
                         if not self._restore_tier_block(slot, req, ids, b,
                                                         h, parent):
@@ -2333,7 +2266,7 @@ class ContinuousBatchingEngine:
                     for _b, h, _p in tier_plan:
                         self._tier.unpin(h)
                     tier_plan = []
-                if self._chunked and self._graceful:
+                if self._chunked:
                     # chunk-granular allocation (docs/fault_tolerance.md):
                     # a streaming prompt owns pages only as its cursor
                     # advances — _mixed_step's _ensure_growth allocates
@@ -2341,10 +2274,7 @@ class ContinuousBatchingEngine:
                     # pool pressure by shrinking the chunk instead of
                     # preempting.  Only the COW duplicate must exist at
                     # admission (its content is copied here).  Admission
-                    # still gates on full-prompt fit (avail check below),
-                    # so the common case admits at the same step it
-                    # always did; graceful-off keeps the pre-PR upfront
-                    # allocation byte-identically.
+                    # still gates on full-prompt fit (avail check below).
                     need = m if cow else n_map
                 avail = len(self._free) + self._evictable()
                 if (avail < gate - (n_map + n_restored) + headroom
@@ -2463,16 +2393,14 @@ class ContinuousBatchingEngine:
             # (docs/observability.md — the decode span opens here too)
             now = time.perf_counter()
             req._admit_s = now
-            if self.slo is not None:
-                self.slo.admitted(req.rid, now)
+            self.slo.admitted(req.rid, now)
             self._tracer.span(req.rid, "queued",
                               getattr(req, "_submit_s", now), now,
                               args={"rid": req.rid, "slot": slot,
                                     "cached_tokens": int(start)})
-            if self._flight is not None:
-                self._flight.record("admit", rid=req.rid, slot=slot,
-                                    prompt=int(s0),
-                                    cached_tokens=int(start))
+            self._flight.record("admit", rid=req.rid, slot=slot,
+                                prompt=int(s0),
+                                cached_tokens=int(start))
             if self._chunked:
                 # the prefill cursor IS the position state: pos/_written
                 # advance with each chunk, so preemption's trusted-content
@@ -2535,24 +2463,20 @@ class ContinuousBatchingEngine:
         # FAILED request — dump the flight recorder so triage reads the
         # engine's last seconds instead of rerunning the chaos
         now = time.perf_counter()
-        if self.slo is not None:
-            self.slo.finish(req.rid, status, now)
-        if self._tracer.enabled:
-            t_admit = getattr(req, "_admit_s", None)
-            if t_admit is not None:
-                self._tracer.span(req.rid, "decode", t_admit, now,
-                                  args={"tokens": len(req.output_ids),
-                                        "status": status})
-            self._tracer.instant(
-                req.rid, f"terminal:{status}", now,
-                args={"rid": req.rid,
-                      **({"error": error} if error else {})})
-        if self._flight is not None:
-            self._flight.record("terminal", rid=req.rid, status=status,
-                                tokens=len(req.output_ids),
-                                **({"error": error} if error else {}))
-            if status == "FAILED":
-                self._flight.dump(f"request_failed rid={req.rid}")
+        self.slo.finish(req.rid, status, now)
+        t_admit = getattr(req, "_admit_s", None)
+        if t_admit is not None:
+            self._tracer.span(req.rid, "decode", t_admit, now,
+                              args={"tokens": len(req.output_ids),
+                                    "status": status})
+        self._tracer.instant(
+            req.rid, f"terminal:{status}", now,
+            args={"rid": req.rid, **({"error": error} if error else {})})
+        self._flight.record("terminal", rid=req.rid, status=status,
+                            tokens=len(req.output_ids),
+                            **({"error": error} if error else {}))
+        if status == "FAILED":
+            self._flight.dump(f"request_failed rid={req.rid}")
 
     def _fail_slot(self, slot: int, status: str, error: str,
                    donate: bool = False):
@@ -2588,13 +2512,12 @@ class ContinuousBatchingEngine:
                                               slot=slot, rid=rid):
             where = "".join((f", slot {slot}" if slot is not None else "",
                              f", rid {rid}" if rid is not None else ""))
-            if self._flight is not None:
-                self._flight.record("fault", fault=kind,
-                                    step=self._step_no,
-                                    **({"slot": slot}
-                                       if slot is not None else {}),
-                                    **({"rid": rid}
-                                       if rid is not None else {}))
+            self._flight.record("fault", fault=kind,
+                                step=self._step_no,
+                                **({"slot": slot}
+                                   if slot is not None else {}),
+                                **({"rid": rid}
+                                   if rid is not None else {}))
             raise FaultInjected(
                 f"injected {kind} (step {self._step_no}{where})")
 
@@ -2602,10 +2525,8 @@ class ContinuousBatchingEngine:
         """Sampler seam: set per-slot poison bits for ``nan_logits`` clauses
         firing this step.  The bits are DATA to the compiled step, where
         they turn the slot's logits row genuinely non-finite IN-GRAPH — the
-        guard proves itself against the real failure shape.  Graceful-off
-        the compiled program has no poison operand (byte-identical to the
-        pre-fault-tolerance engine), so the kind is inert there."""
-        if not (self._graceful and self._faults):
+        guard proves itself against the real failure shape."""
+        if not self._faults:
             return
         for s in range(self.max_batch):
             req = self._slot_req[s]
@@ -2614,19 +2535,16 @@ class ContinuousBatchingEngine:
                 self._poison[s] = True
 
     def _retry_launch(self, err: FaultInjected) -> bool:
-        """Graceful handling of a kernel-dispatch fault: the raise happened
+        """Handling of a kernel-dispatch fault: the raise happened
         BEFORE the compiled call, so host and device state (including the
         donated cache buffers) are untouched and the step can simply run
         again.  A persistent failure (streak past the limit) means the
         program itself cannot run — re-raise rather than spin."""
-        if not self._graceful:
-            raise err
         self._kernel_err_streak += 1
         self.stats["kernel_error_retries"] += 1
-        if self._flight is not None:
-            self._flight.record("fault", fault="kernel_error",
-                                streak=self._kernel_err_streak,
-                                step=self._step_no)
+        self._flight.record("fault", fault="kernel_error",
+                            streak=self._kernel_err_streak,
+                            step=self._step_no)
         if self._kernel_err_streak > self._kernel_err_limit:
             raise err
         with RecordEvent("serving/kernel_error_retry"):
@@ -2662,13 +2580,12 @@ class ContinuousBatchingEngine:
             with RecordEvent("serving/degrade_evict"):
                 if self._reclaim(short) > 0:
                     self.stats["degrade_evict"] += 1
-                    if self._flight is not None:
-                        self._flight.record("degrade", rung=1, what="evict",
-                                            short=int(short))
+                    self._flight.record("degrade", rung=1, what="evict",
+                                        short=int(short))
         return need - len(self._free)
 
     def _expire_overdue(self):
-        """Deadline enforcement (graceful mode): a request past its
+        """Deadline enforcement: a request past its
         ``deadline_s`` wall-clock budget (from submission) terminates
         EXPIRED with whatever partial output it has, freeing its pages for
         requests that can still meet their SLO.  Queued and running
@@ -2705,11 +2622,7 @@ class ContinuousBatchingEngine:
         cursor's pages release like any preemption, and full blocks donate
         to the prefix cache so a re-submission resumes cheaply).  Partial
         output stays on the request.  Returns True when the request was
-        still live (False: unknown rid or already terminal).  Requires
-        graceful mode — the PADDLE_TPU_GRACEFUL=0 engine predates the
-        status lifecycle."""
-        if not self._graceful:
-            raise RuntimeError("cancel() requires PADDLE_TPU_GRACEFUL=1")
+        still live (False: unknown rid or already terminal)."""
         req = self._reqs.get(rid)
         if req is None or req.status in TERMINAL_STATUSES:
             return False
@@ -2803,8 +2716,7 @@ class ContinuousBatchingEngine:
     def _jmark(self, rid: int):
         """Mark one rid's journal entry stale (admission, token bank,
         chunk-cursor advance, adopt, preempt).  O(1) — the entry rebuild
-        happens in :meth:`_jflush`, inside the host-overlap window when
-        the async runtime is on."""
+        happens in :meth:`_jflush`, inside the host-overlap window."""
         self._jdirty.add(rid)
 
     def _jdrop(self, rid: int):
@@ -2843,18 +2755,15 @@ class ContinuousBatchingEngine:
         self._jdirty.clear()
         if n:
             self.stats["journal_incremental_updates"] += n
-            if self._h_jupdate is not None:
-                self._h_jupdate.observe(time.perf_counter() - t0)
-            if self._flight is not None:
-                self._flight.record("journal_flush", entries=n)
+            self._h_jupdate.observe(time.perf_counter() - t0)
+            self._flight.record("journal_flush", entries=n)
 
     def journal(self) -> dict:
         """:meth:`snapshot`-equivalent view assembled from the incremental
-        journal — the async host runtime's replacement for the router's
-        per-step/per-dispatch full rebuilds (docs/async_runtime.md).  The
-        fleet pulls this only at failover/hedge boundaries; equivalence
-        with :meth:`snapshot` is asserted every fleet step under
-        PADDLE_TPU_ENGINE_AUDIT=1 (fleet._audit_journal_equiv)."""
+        journal (docs/async_runtime.md).  The fleet pulls this only at
+        failover/hedge boundaries; equivalence with :meth:`snapshot` is
+        asserted every fleet step under PADDLE_TPU_ENGINE_AUDIT=1
+        (fleet._audit_journal_equiv)."""
         now = time.perf_counter()
         self._jflush(now)
 
@@ -2889,12 +2798,7 @@ class ContinuousBatchingEngine:
         """The token-independent half of a step's host work, run between
         the compiled launch and the first token fetch — while the device
         executes the step (JAX async dispatch), so steady-state journal
-        upkeep costs the host gap nothing.  A no-op with
-        PADDLE_TPU_ASYNC_HOST=0: the serial loop defers all journal work
-        to explicit snapshot() calls, byte-identically to the pre-async
-        engine."""
-        if not self._async_host:
-            return
+        upkeep costs the host gap nothing."""
         self.stats["host_overlap_steps"] += 1
         self._jflush()
 
@@ -2933,14 +2837,12 @@ class ContinuousBatchingEngine:
         req._submit_s = time.perf_counter()
         if req.trace_id is None:
             req.trace_id = f"req-{req.rid:x}"
-        if self.slo is not None:
-            self.slo.begin(req.rid, req._submit_s)
+        self.slo.begin(req.rid, req._submit_s)
         self._tracer.instant(req.rid, "adopt", req._submit_s,
                              args={"rid": req.rid,
                                    "replayed_tokens": len(req.output_ids)})
-        if self._flight is not None:
-            self._flight.record("adopt", rid=req.rid,
-                                replayed_tokens=len(req.output_ids))
+        self._flight.record("adopt", rid=req.rid,
+                            replayed_tokens=len(req.output_ids))
         self._reqs[req.rid] = req
         self._queue.append(req)
         self._jmark(req.rid)
@@ -3000,8 +2902,7 @@ class ContinuousBatchingEngine:
             except EngineAuditError:
                 # triage-without-a-rerun: the flight recorder's last
                 # N events + a metrics snapshot accompany the raise
-                if self._flight is not None:
-                    self._flight.dump("engine_audit_error")
+                self._flight.dump("engine_audit_error")
                 raise
 
     # ------------- per-step latency accounting (docs/observability.md) ----
@@ -3011,13 +2912,12 @@ class ContinuousBatchingEngine:
         the previous step's host fetch is pure host-side work (packing,
         drafting, journal upkeep) the device spent idle — the host-gap
         histogram ROADMAP item 5 will optimize against."""
-        if self._h_hostgap is not None and self._last_step_end is not None:
+        if self._last_step_end is not None:
             self._h_hostgap.observe(t0 - self._last_step_end)
 
     def _note_step_done(self, t0: float):
         end = time.perf_counter()
-        if self._h_step is not None:
-            self._h_step.observe(end - t0)
+        self._h_step.observe(end - t0)
         self._last_step_end = end
 
     def _count_launch(self, rows_computed: int, rows_live: int,
@@ -3066,7 +2966,7 @@ class ContinuousBatchingEngine:
         streaming, a single unified mixed prefill/decode step).  Returns
         False when idle.
 
-        Graceful mode: no per-request fault escapes this method — the
+        No per-request fault escapes this method — the
         offending request terminates (pages and cache refs released) and
         every survivor's token stream is identical to a run that never
         contained it (each slot's stream depends only on its own
@@ -3095,10 +2995,9 @@ class ContinuousBatchingEngine:
         """``step()``'s body, in phases: admit, pack, then one launch path
         (dispatch, host_overlap, fetch, bank)."""
         self._phase("serving/admit")
-        if self._graceful:
-            self._expire_overdue()
+        self._expire_overdue()
         self._admit()
-        if (self._graceful and self.paged and self._queue
+        if (self.paged and self._queue
                 and all(r is None for r in self._slot_req)):
             # admission made no progress with NOTHING resident: no future
             # step can free pages (zero-ref cache leaves were already fair
@@ -3134,7 +3033,7 @@ class ContinuousBatchingEngine:
             return self._mixed_step()
         if self._spec is not None:
             drafts = self._draft_proposals()
-            if drafts is not None and self._graceful and self.paged:
+            if drafts is not None:
                 qlens = np.ones(self.max_batch, np.int64)
                 for s, d in drafts.items():
                     qlens[s] = 1 + d.size
@@ -3147,10 +3046,9 @@ class ContinuousBatchingEngine:
                     # each round-trip banks, never which ones.
                     with RecordEvent("serving/degrade_spec_off"):
                         self.stats["degrade_spec_off"] += 1
-                        if self._flight is not None:
-                            self._flight.record("degrade", rung=2,
-                                                what="spec_off",
-                                                step=self._step_no)
+                        self._flight.record("degrade", rung=2,
+                                            what="spec_off",
+                                            step=self._step_no)
                     drafts = None
             if drafts is not None:
                 return self._spec_step(drafts)
@@ -3158,8 +3056,7 @@ class ContinuousBatchingEngine:
             # a drafter miss must cost nothing (same step shape as spec-off)
         k = self.chunk
         if self.paged:
-            if self._graceful:
-                self._degrade_reclaim(k)    # ladder rung 1 before rung 4
+            self._degrade_reclaim(k)    # ladder rung 1 before rung 4
             self._ensure_growth(k)  # may preempt the youngest slot
         active_np = np.asarray([r is not None for r in self._slot_req])
         if not active_np.any():
@@ -3177,30 +3074,20 @@ class ContinuousBatchingEngine:
         self._arm_poison()
         try:
             self._host_fault("kernel_error")   # dispatch seam: pre-launch
-            if self._graceful:
-                toks, bad, self.cache_k, self.cache_v = decode(
-                    self.params, self.cache_k, self.cache_v,
-                    jnp.asarray(self._last_tok), jnp.asarray(self._pos),
-                    jnp.asarray(active_np), jnp.asarray(self._temp),
-                    jnp.asarray(self._topp), jnp.asarray(self._seed),
-                    *extra, poison=jnp.asarray(self._poison))
-                # async host runtime: the token-independent host half
-                # (journal upkeep) runs while the device executes the
-                # launch above — the guard/token fetches below block as
-                # late as possible (docs/async_runtime.md)
-                self._phase("serving/host_overlap")
-                self._host_overlap()
-                self._phase("serving/fetch")
-                bad_np = np.asarray(bad)    # [k, B] guard flags
-            else:
-                toks, self.cache_k, self.cache_v = decode(
-                    self.params, self.cache_k, self.cache_v,
-                    jnp.asarray(self._last_tok), jnp.asarray(self._pos),
-                    jnp.asarray(active_np), jnp.asarray(self._temp),
-                    jnp.asarray(self._topp), jnp.asarray(self._seed), *extra)
-                self._phase("serving/host_overlap")
-                self._host_overlap()
-                self._phase("serving/fetch")
+            toks, bad, self.cache_k, self.cache_v = decode(
+                self.params, self.cache_k, self.cache_v,
+                jnp.asarray(self._last_tok), jnp.asarray(self._pos),
+                jnp.asarray(active_np), jnp.asarray(self._temp),
+                jnp.asarray(self._topp), jnp.asarray(self._seed),
+                *extra, poison=jnp.asarray(self._poison))
+            # async host runtime: the token-independent host half
+            # (journal upkeep) runs while the device executes the
+            # launch above — the guard/token fetches below block as
+            # late as possible (docs/async_runtime.md)
+            self._phase("serving/host_overlap")
+            self._host_overlap()
+            self._phase("serving/fetch")
+            bad_np = np.asarray(bad)    # [k, B] guard flags
         except FaultInjected as e:
             return self._retry_launch(e)
         self._kernel_err_streak = 0
@@ -3225,12 +3112,10 @@ class ContinuousBatchingEngine:
             try:
                 self._host_fault("slot_error", slot=slot, rid=req.rid)
             except FaultInjected as e:
-                if not self._graceful:
-                    raise
                 fail_err = str(e)
             if fail_err is None:
                 for j in range(valid):
-                    if self._graceful and bad_np[j, slot]:
+                    if bad_np[j, slot]:
                         # quarantine: tokens from the poisoned scan step on
                         # are sampled from a zeroed row — never banked
                         self.stats["nan_guard_trips"] += 1
@@ -3249,7 +3134,7 @@ class ContinuousBatchingEngine:
                     # count only tokens a caller actually receives: chunk
                     # steps past EOS / the token budget / max_seq are
                     # trimmed here, so they must not inflate
-                    # decode_tokens_per_s (the headline)
+                    # decode_tokens_per_s
                     self.stats["decode_tokens"] += 1
                     if (len(req.output_ids) >= req.max_new_tokens
                             or (req.eos_token_id is not None
@@ -3261,7 +3146,7 @@ class ContinuousBatchingEngine:
                 # the other lanes' tokens (already fetched) bank normally
                 self._fail_slot(slot, "FAILED", fail_err, donate=False)
                 continue
-            if self.slo is not None and banked:
+            if banked:
                 # one banking event: the whole chunk arrives in one fetch
                 self.slo.tokens(req.rid, banked, now)
             self._pos[slot] = old_pos + k  # device advanced k regardless
@@ -3345,7 +3230,7 @@ class ContinuousBatchingEngine:
             active[s] = True
             growth[s] = n
             chunk_rows[s] = n
-        if self._graceful and self._degrade_reclaim(growth) > 0:
+        if self._degrade_reclaim(growth) > 0:
             # ladder rungs 1 + 3: the step's FULL growth (decode lanes'
             # one-token appends + every packed prefill chunk) must fit —
             # _degrade_reclaim already evicted cache leaves (rung 1); if
@@ -3358,10 +3243,9 @@ class ContinuousBatchingEngine:
             if shrinkable:
                 with RecordEvent("serving/degrade_budget_shrink"):
                     self.stats["degrade_budget_shrink"] += 1
-                    if self._flight is not None:
-                        self._flight.record("degrade", rung=3,
-                                            what="budget_shrink",
-                                            slots=len(shrinkable))
+                    self._flight.record("degrade", rung=3,
+                                        what="budget_shrink",
+                                        slots=len(shrinkable))
                 for s in shrinkable:
                     tokens[s, 1:] = 0
                     q_lens[s] = 1
@@ -3379,9 +3263,7 @@ class ContinuousBatchingEngine:
                 # restore-only step: no compiled launch follows, but the
                 # H2D restore dispatches above ARE this step's device work
                 # — observe the host gap + step time here so the
-                # tier-restore family shows up in the histogram the async
-                # runtime's A/B measures (a silent family would make the
-                # overlap look better than it is)
+                # tier-restore family shows up in the histograms too
                 self._note_launch(t_r0)
                 self._note_step_done(t_r0)
             # tier restores are progress even when every lane's ROWS were
@@ -3396,40 +3278,28 @@ class ContinuousBatchingEngine:
                     rows_computed=B * T)
         t0 = time.perf_counter()
         self._note_launch(t0)
-        if self._flight is not None:
-            # step-packing summary: O(1) per step, the flight recorder's
-            # picture of what the scheduler chose when things went wrong
-            self._flight.record("pack", step=self._step_no,
-                                decode=len(decode_slots),
-                                prefill=len(chunk_rows),
-                                prefill_rows=prefill_rows)
+        # step-packing summary: O(1) per step, the flight recorder's
+        # picture of what the scheduler chose when things went wrong
+        self._flight.record("pack", step=self._step_no,
+                            decode=len(decode_slots),
+                            prefill=len(chunk_rows),
+                            prefill_rows=prefill_rows)
         any_sampled = bool((self._temp * active).max() > 0)
         mixed = self._mixed_sampling if any_sampled else self._mixed_greedy
         self._arm_poison()
         try:
             self._host_fault("kernel_error")   # dispatch seam: pre-launch
-            if self._graceful:
-                nxt, bad, self.cache_k, self.cache_v = mixed(
-                    self.params, self.cache_k, self.cache_v,
-                    jnp.asarray(tokens), jnp.asarray(pos),
-                    jnp.asarray(active), jnp.asarray(q_lens),
-                    jnp.asarray(self._temp), jnp.asarray(self._topp),
-                    jnp.asarray(self._seed), jnp.asarray(self._table),
-                    poison=jnp.asarray(self._poison))
-                self._phase("serving/host_overlap")
-                self._host_overlap()   # journal upkeep rides the launch
-                self._phase("serving/fetch")
-                bad_np = np.asarray(bad)    # [B] emit-row guard flags
-            else:
-                nxt, self.cache_k, self.cache_v = mixed(
-                    self.params, self.cache_k, self.cache_v,
-                    jnp.asarray(tokens), jnp.asarray(pos),
-                    jnp.asarray(active), jnp.asarray(q_lens),
-                    jnp.asarray(self._temp), jnp.asarray(self._topp),
-                    jnp.asarray(self._seed), jnp.asarray(self._table))
-                self._phase("serving/host_overlap")
-                self._host_overlap()
-                self._phase("serving/fetch")
+            nxt, bad, self.cache_k, self.cache_v = mixed(
+                self.params, self.cache_k, self.cache_v,
+                jnp.asarray(tokens), jnp.asarray(pos),
+                jnp.asarray(active), jnp.asarray(q_lens),
+                jnp.asarray(self._temp), jnp.asarray(self._topp),
+                jnp.asarray(self._seed), jnp.asarray(self._table),
+                poison=jnp.asarray(self._poison))
+            self._phase("serving/host_overlap")
+            self._host_overlap()   # journal upkeep rides the launch
+            self._phase("serving/fetch")
+            bad_np = np.asarray(bad)    # [B] emit-row guard flags
         except FaultInjected as e:
             return self._retry_launch(e)
         self._kernel_err_streak = 0
@@ -3445,7 +3315,7 @@ class ContinuousBatchingEngine:
             req = self._slot_req[s]
             if req is None:
                 continue            # preempted by _ensure_growth
-            if self._graceful and bad_np[s]:
+            if bad_np[s]:
                 self.stats["nan_guard_trips"] += 1
                 self._fail_slot(s, "FAILED",
                                 f"non-finite logits at position "
@@ -3455,8 +3325,6 @@ class ContinuousBatchingEngine:
             try:
                 self._host_fault("slot_error", slot=s, rid=req.rid)
             except FaultInjected as e:
-                if not self._graceful:
-                    raise
                 self._fail_slot(s, "FAILED", str(e), donate=False)
                 continue
             old_pos = int(self._pos[s])
@@ -3471,7 +3339,7 @@ class ContinuousBatchingEngine:
             req = self._slot_req[s]
             if req is None:
                 continue            # preempted after packing
-            if self._graceful and bad_np[s]:
+            if bad_np[s]:
                 # a poisoned prefill lane: the forward pass that computed
                 # this chunk's K/V is not trusted — quarantine the request
                 # before any of its progress (or blocks) is banked
@@ -3520,10 +3388,7 @@ class ContinuousBatchingEngine:
         req.output_ids.append(tok)
         if req.ttft_s is None:
             req.ttft_s = time.perf_counter() - getattr(req, "_submit_s", t0)
-        if self.slo is not None:
-            self.slo.tokens(req.rid, 1, self._last_step_end
-                            if self._last_step_end is not None
-                            else time.perf_counter())
+        self.slo.tokens(req.rid, 1, self._last_step_end)
         self.stats["decode_tokens"] += 1
         self._last_tok[slot] = tok
         self._jmark(req.rid)   # token bank advanced the journal entry
@@ -3604,28 +3469,17 @@ class ContinuousBatchingEngine:
         self._arm_poison()
         try:
             self._host_fault("kernel_error")   # dispatch seam: pre-launch
-            if self._graceful:
-                out, n_acc, bad, self.cache_k, self.cache_v = verify(
-                    self.params, self.cache_k, self.cache_v,
-                    jnp.asarray(tokens), jnp.asarray(self._pos),
-                    jnp.asarray(active_np), jnp.asarray(q_lens),
-                    jnp.asarray(self._temp), jnp.asarray(self._topp),
-                    jnp.asarray(self._seed), jnp.asarray(self._table),
-                    poison=jnp.asarray(self._poison))
-                self._phase("serving/host_overlap")
-                self._host_overlap()   # journal upkeep rides the launch
-                self._phase("serving/fetch")
-                bad_np = np.asarray(bad)    # [B] per-slot guard flags
-            else:
-                out, n_acc, self.cache_k, self.cache_v = verify(
-                    self.params, self.cache_k, self.cache_v,
-                    jnp.asarray(tokens), jnp.asarray(self._pos),
-                    jnp.asarray(active_np), jnp.asarray(q_lens),
-                    jnp.asarray(self._temp), jnp.asarray(self._topp),
-                    jnp.asarray(self._seed), jnp.asarray(self._table))
-                self._phase("serving/host_overlap")
-                self._host_overlap()
-                self._phase("serving/fetch")
+            out, n_acc, bad, self.cache_k, self.cache_v = verify(
+                self.params, self.cache_k, self.cache_v,
+                jnp.asarray(tokens), jnp.asarray(self._pos),
+                jnp.asarray(active_np), jnp.asarray(q_lens),
+                jnp.asarray(self._temp), jnp.asarray(self._topp),
+                jnp.asarray(self._seed), jnp.asarray(self._table),
+                poison=jnp.asarray(self._poison))
+            self._phase("serving/host_overlap")
+            self._host_overlap()   # journal upkeep rides the launch
+            self._phase("serving/fetch")
+            bad_np = np.asarray(bad)    # [B] per-slot guard flags
         except FaultInjected as e:
             return self._retry_launch(e)
         self._kernel_err_streak = 0
@@ -3642,7 +3496,7 @@ class ContinuousBatchingEngine:
             if req is None:
                 continue
             old_pos = int(self._pos[slot])
-            if self._graceful and bad_np[slot]:
+            if bad_np[slot]:
                 # the whole verify output for this slot is discarded (its
                 # correction token came from a zeroed row); quarantine it
                 self.stats["nan_guard_trips"] += 1
@@ -3654,8 +3508,6 @@ class ContinuousBatchingEngine:
             try:
                 self._host_fault("slot_error", slot=slot, rid=req.rid)
             except FaultInjected as e:
-                if not self._graceful:
-                    raise
                 self._fail_slot(slot, "FAILED", str(e), donate=False)
                 continue
             n = int(n_np[slot])        # 1..q_lens: accepted run + correction
@@ -3678,7 +3530,7 @@ class ContinuousBatchingEngine:
                             and tok == req.eos_token_id)):
                     done = True
                     break
-            if self.slo is not None and banked:
+            if banked:
                 # one banking event: the accepted run arrives in one fetch
                 self.slo.tokens(req.rid, banked, now)
             # rejection rollback: pos advances only past ACCEPTED tokens;
@@ -3706,25 +3558,16 @@ class ContinuousBatchingEngine:
     def serve(self, requests: list[Request]) -> dict[int, list[int]]:
         """Run all requests to completion; returns {rid: generated tokens}.
 
-        Graceful mode (the default): an invalid request is marked
-        ``REJECTED`` (with ``error``) and the rest are served — one bad
-        sampling param must not zero a whole batch's goodput.  With
-        ``PADDLE_TPU_GRACEFUL=0`` validation is all-or-nothing: any bad
-        request raises before anything is enqueued (the pre-fault-tolerance
-        contract)."""
-        if self._graceful:
-            for r in requests:
-                try:
-                    self.add_request(r)
-                except ValueError as e:
-                    self._reqs[r.rid] = r
-                    with RecordEvent("serving/rejected"):
-                        self._terminal(r, "REJECTED", str(e))
-        else:
-            for r in requests:
-                self._validate(r)  # all-or-nothing: nothing enqueued if any is bad
-            for r in requests:
+        An invalid request is marked ``REJECTED`` (with ``error``) and the
+        rest are served — one bad sampling param must not zero a whole
+        batch's goodput."""
+        for r in requests:
+            try:
                 self.add_request(r)
+            except ValueError as e:
+                self._reqs[r.rid] = r
+                with RecordEvent("serving/rejected"):
+                    self._terminal(r, "REJECTED", str(e))
         while self.step() or self._queue:
             pass
         return {r.rid: r.output_ids for r in requests}
@@ -3737,7 +3580,7 @@ class ContinuousBatchingEngine:
     def n_traces(self) -> int | None:
         """Total compiled program variants across this engine's jitted
         programs (decode greedy/sampling, prefill(s), COW copy) — the
-        bench's jit-cache-churn telemetry: the expected count is small and
+        jit-cache-churn telemetry: the expected count is small and
         static (one decode variant per sampling mode actually used + one
         prefill per warmed bucket), so growth across a serve is a silent
         recompile in the hot loop (paddle_tpu.analysis.n_traces)."""
@@ -3777,19 +3620,18 @@ class ContinuousBatchingEngine:
         zi = jnp.zeros((B,), jnp.int32)
         body = functools.partial(
             self._decode_impl_paged if self.paged else self._decode_impl,
-            sampling=False, graceful=self._graceful)
+            sampling=False)
         args = [self.params, self.cache_k, self.cache_v, zi, zi,
                 jnp.ones((B,), bool), jnp.zeros((B,), jnp.float32),
                 jnp.ones((B,), jnp.float32), zi]
         if self.paged:
             args.append(jnp.asarray(self._table))
         if self.tp > 1:
-            body = self._tp_shard(body, n_rep=2 if self._graceful else 1)
+            body = self._tp_shard(body, n_rep=2)
         # telemetry must not contaminate the dispatch counters: the trace
         # below executes the kernels' Python dispatch, which would tick
-        # KERNEL/FLASH/FUSED_*_CALLS by one launch the serve never ran —
-        # exactly the per-rung contamination reset_kernel_counters() exists
-        # to prevent.  Snapshot and restore around the trace.
+        # KERNEL/FLASH/FUSED_*_CALLS by one launch the serve never ran.
+        # Snapshot and restore around the trace.
         from ..ops.pallas import paged_attention as _pa
 
         counter_names = ("KERNEL_CALLS", "FALLBACK_CALLS",
@@ -3819,8 +3661,7 @@ class ContinuousBatchingEngine:
         win is visible here before any wall clock: the unfused paged path
         traces 1 pallas_call + 2 scatters per layer (plus the rope/gather
         glue XLA must fuse around them), the fused path traces 1
-        pallas_call and 0 scatters — the bench rungs report this dict as
-        the launch-count detail (eqns inside the chunk scan's per-step
+        pallas_call and 0 scatters (eqns inside the chunk scan's per-step
         body count once, matching the per-layer dispatch they model)."""
         from ..analysis.cost_model import eqn_census
 
@@ -3836,14 +3677,11 @@ class ContinuousBatchingEngine:
         (analysis/cost_model.py): peak live HBM, launch census, per-launch
         VMEM fit, and the kernel-contract aggregate (bounds / race /
         alias verdicts over every pallas launch,
-        analysis/kernel_contracts.py) — embedded by the cb bench rungs
-        next to ``decode_step_launches`` so a rung's detail carries the
-        program's static cost AND its kernel-soundness verdicts alongside
-        its measured wall clock.  The host-contract sections
-        (analysis/host_contracts.py) ride along the same way: this engine
-        IS the async host runtime the pass verifies, so the rung detail
-        carries the overlap-window race/blocking verdicts and
-        state-machine coverage beside the kernel ones.  Trace-only, like
+        analysis/kernel_contracts.py).  The host-contract sections
+        (analysis/host_contracts.py) ride along: this engine IS the async
+        host runtime the pass verifies, so the card carries the
+        overlap-window race/blocking verdicts and state-machine coverage
+        beside the kernel ones.  Trace-only, like
         the launch telemetry; collective bytes are not compiled here (the
         TP gate target owns that figure) and trace-family accounting
         lives with ``n_traces()``."""

@@ -92,7 +92,7 @@ NEG_INF = -1e30
 _LANES = 128        # a vreg's lanes: the width per-row statistics are kept at
 
 # trace-time counters: how often the public entry took the Pallas kernel path
-# vs the composed-XLA fallback (bench.py records both in its detail output)
+# vs the composed-XLA fallback
 KERNEL_CALLS = 0
 FALLBACK_CALLS = 0
 # the kernel path's last call, written as it is traced: ``tiles``, the

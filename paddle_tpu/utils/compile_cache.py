@@ -7,7 +7,7 @@ the environment says or ONE fixed directory inside the checkout — never a
 temporary name, a process id or a time.
 
 Called by the entry points that compile for the chip (``chip_smoke.py``,
-``bench.py``'s workers) before their first compile, and by nothing at
+``benchmarks/run.py``) before their first compile, and by nothing at
 package import: tests and library users keep JAX's own default.
 """
 

@@ -24,21 +24,6 @@ through :func:`env_bool`, which enforces the '0'/'1' vocabulary):
   mixed prefill/decode step (docs/chunked_prefill.md); ``0`` forces it off
   even when the engine was constructed with ``enable_chunked_prefill=True``,
   reverting to the bucketed whole-prompt prefill path byte-for-byte.
-* ``PADDLE_TPU_GRACEFUL`` (default on) — fault-tolerant serving
-  (docs/fault_tolerance.md): per-request failure isolation, the overload
-  degradation ladder, the in-graph NaN/inf logit guard, and graceful
-  rejection in ``serve()``; ``0`` restores the pre-fault-tolerance engine
-  byte-identically (faults raise out of ``step()`` again).
-* ``PADDLE_TPU_METRICS`` (default on) — serving observability
-  (inference/observability.py, docs/observability.md): the typed
-  MetricsRegistry behind ``engine.stats``/``fleet.stats``, request-
-  lifecycle tracing spans, and SLO (TTFT/TBT/queue-wait) accounting.
-  All recording is host-side post-step, so token streams are identical
-  either way; ``0`` restores the plain pre-observability stats dicts.
-* ``PADDLE_TPU_FLIGHT_RECORDER`` (default on) — the bounded ring buffer
-  of recent engine/fleet events dumped (with a metrics snapshot) on
-  request failure, ``EngineAuditError``, or replica death; ``0`` disables
-  the recorder and its dumps entirely.
 * ``PADDLE_TPU_HOST_KV_TIER`` (default on) — hierarchical KV: the
   host-RAM spill tier behind the prefix cache (inference/kv_tier.py,
   docs/kv_tier.md).  ``0`` forces it off even when the engine was
@@ -47,16 +32,6 @@ through :func:`env_bool`, which enforces the '0'/'1' vocabulary):
   pages again and admission stops at the HBM match.
   ``PADDLE_TPU_PREFIX_CACHE=0`` neutralizes the tier too — with no
   content address there is nothing to demote or match through.
-* ``PADDLE_TPU_ASYNC_HOST`` (default on) — the async host runtime
-  (docs/async_runtime.md): the engine maintains its failover journal
-  incrementally (O(changed rids) per step instead of a full
-  ``snapshot()`` rebuild per fleet step/dispatch) and overlaps the
-  token-independent half of each step's host work (journal maintenance,
-  metrics, queue bookkeeping) with the in-flight device step via JAX
-  async dispatch, fetching tokens as late as possible.  Token streams
-  are identical either way — only host scheduling moves; ``0`` restores
-  the serial fetch-then-bookkeep loop and the per-step full-``snapshot``
-  fleet journal byte-identically.
 
 (``PADDLE_TPU_DISABLE_PALLAS`` is the token-set switch; its vocabulary lives
 with the kernels — ops/pallas/__init__.py ``KNOWN_KERNELS``, cross-checked
@@ -131,7 +106,7 @@ import os
 import warnings
 
 __all__ = ["env_token_set", "env_bool", "env_fault_spec", "env_tp",
-           "env_int", "BOOL_FLAGS"]
+           "env_int", "BOOL_FLAGS", "RETIRED_FLAGS", "warn_retired_flags"]
 
 #: '0'/'1' switches -> their library defaults (documentation + test anchor;
 #: callers still pass the default explicitly at the read site so a flag read
@@ -141,12 +116,14 @@ BOOL_FLAGS = {
     "PADDLE_TPU_ENGINE_AUDIT": False,
     "PADDLE_TPU_SPECULATE": True,
     "PADDLE_TPU_CHUNKED_PREFILL": True,
-    "PADDLE_TPU_GRACEFUL": True,
-    "PADDLE_TPU_METRICS": True,
-    "PADDLE_TPU_FLIGHT_RECORDER": True,
     "PADDLE_TPU_HOST_KV_TIER": True,
-    "PADDLE_TPU_ASYNC_HOST": True,
 }
+
+#: off-switches of the serving engine that were removed with the arms they
+#: selected: the engine always isolates faults, overlaps its host work,
+#: keeps its metrics registry and records its flight ring
+RETIRED_FLAGS = ("PADDLE_TPU_GRACEFUL", "PADDLE_TPU_ASYNC_HOST",
+                 "PADDLE_TPU_METRICS", "PADDLE_TPU_FLIGHT_RECORDER")
 
 _warned: set[tuple[str, str]] = set()
 
@@ -156,6 +133,18 @@ def _warn_once(name: str, raw: str, msg: str) -> None:
         return
     _warned.add((name, raw))
     warnings.warn(msg, stacklevel=3)
+
+
+def warn_retired_flags() -> None:
+    """Called where a serving engine or fleet is built: an operator who still
+    exports a retired switch (to make faults raise out of ``step()``, say) is
+    told once that it no longer changes anything."""
+    for name in RETIRED_FLAGS:
+        raw = os.environ.get(name, "")
+        if raw:
+            _warn_once(name, raw,
+                       f"{name}={raw!r} has no effect: the switch is retired "
+                       f"and the serving engine runs as it did with {name}=1")
 
 
 def env_token_set(name: str, known: frozenset[str] | set[str]) -> set[str]:

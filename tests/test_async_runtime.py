@@ -1,13 +1,12 @@
 """Async host runtime tests (ISSUE 16, docs/async_runtime.md).
 
-The correctness bar: ``PADDLE_TPU_ASYNC_HOST=0`` rebuilds the serial
-fetch-then-bookkeep loop (and the router's per-step full ``snapshot()``
-journal) byte-identically, and ``=1`` — the default — is token-identical
-greedy AND seeded with prefix cache + speculation + chunked prefill +
-graceful mode all ON, at TP 1 and 2, including fleet failover under
-injected ``replica_crash`` chaos where the replay rides the incremental
-journal (zero full rebuilds) under ``PADDLE_TPU_ENGINE_AUDIT=1``'s
-per-step journal-vs-snapshot equivalence assert.
+The correctness bar: the incremental journal every step family keeps up
+inside its ``_host_overlap()`` window equals a fresh ``snapshot()`` at every
+intermediate state, and fleet failover under injected ``replica_crash``
+chaos replays from that journal (the router rebuilds no snapshot),
+token-identically greedy AND seeded with prefix cache + speculation +
+chunked prefill all ON, under ``PADDLE_TPU_ENGINE_AUDIT=1``'s per-step
+journal-vs-snapshot equivalence assert.
 """
 
 from __future__ import annotations
@@ -20,8 +19,6 @@ import pytest
 from paddle_tpu.inference.fleet import FleetRouter
 from paddle_tpu.inference.serving import ContinuousBatchingEngine, Request
 from paddle_tpu.models import llama
-from paddle_tpu.utils import envflags
-from paddle_tpu.utils.envflags import env_bool
 
 _CFG = llama.LlamaConfig.tiny(vocab=128, hidden=32, layers=2, heads=4,
                               kv_heads=2, inter=64)
@@ -58,51 +55,6 @@ def _mixed_batch(seed, n=4, prompt_len=11, new=6):
     return reqs
 
 
-def _engine(monkeypatch, async_on, tp=1, **kw):
-    monkeypatch.setenv("PADDLE_TPU_ASYNC_HOST", "1" if async_on else "0")
-    cfg, params = _tiny()
-    eng = ContinuousBatchingEngine(cfg, params, tensor_parallel=tp,
-                                   **dict(_FULL, **kw))
-    monkeypatch.delenv("PADDLE_TPU_ASYNC_HOST")
-    assert eng._async_host is async_on
-    return eng
-
-
-def _serve(monkeypatch, async_on, tp=1):
-    reqs = _mixed_batch(0)
-    eng = _engine(monkeypatch, async_on, tp=tp)
-    out = eng.serve(reqs)
-    assert all(r.status == "FINISHED" for r in reqs)
-    return out, eng
-
-
-# ---------------- kill switch + token identity ----------------
-
-def test_async_on_off_token_identity_full_features(monkeypatch):
-    """Flag on vs off: byte-identical output streams (greedy and seeded)
-    with every serving feature ON — the serial loop is the oracle the
-    async runtime must reproduce exactly.  Same engines prove the paths
-    actually ran: async-on books its work in the overlap window,
-    async-off books zero overlap and zero incremental updates."""
-    monkeypatch.setenv("PADDLE_TPU_ENGINE_AUDIT", "1")
-    on, eng = _serve(monkeypatch, True)
-    off, eng_off = _serve(monkeypatch, False)
-    assert on == off
-    assert eng.stats["host_overlap_steps"] > 0
-    assert eng.stats["journal_incremental_updates"] > 0
-    assert eng.stats["journal_full_rebuilds"] == 0  # nobody snapshotted
-    assert eng_off.stats["host_overlap_steps"] == 0
-    assert eng_off.stats["journal_incremental_updates"] == 0
-
-
-def test_async_on_off_token_identity_tp2(monkeypatch):
-    """Same identity over the 2-shard GSPMD mesh (conftest forces 8
-    virtual CPU devices) — late fetch and overlap must not reorder
-    anything the sharded step observes."""
-    assert (_serve(monkeypatch, True, tp=2)[0]
-            == _serve(monkeypatch, False, tp=2)[0])
-
-
 # ---------------- journal-vs-snapshot equivalence ----------------
 
 def _norm(d):
@@ -112,22 +64,35 @@ def _norm(d):
                        for e in d["queued"]]}
 
 
-def test_journal_equals_snapshot_mid_serve(monkeypatch):
+#: one engine per step family: the counter says its launch path ran
+_FAMILIES = {
+    "decode": (dict(chunk=2, enable_chunked_prefill=False,
+                    enable_speculation=False), "decode_steps"),
+    "mixed": (dict(enable_speculation=False), "mixed_steps"),
+    "spec": (dict(enable_chunked_prefill=False), "spec_steps"),
+}
+
+
+@pytest.mark.parametrize("family", sorted(_FAMILIES))
+def test_journal_equals_snapshot_mid_serve(family):
     """The incremental journal and a fresh full ``snapshot()`` agree at
     every intermediate state — queued, seating, mid-chunk prefill,
     tokens banked (``deadline_remaining_s`` normalized: both sides
-    recompute it lazily at their own read instants)."""
-    eng = _engine(monkeypatch, True)
+    recompute it lazily at their own read instants) — whichever of the
+    three step families (``_step``, ``_mixed_step``, ``_spec_step``) does
+    the banking."""
+    kw, counter = _FAMILIES[family]
+    cfg, params = _tiny()
+    eng = ContinuousBatchingEngine(cfg, params, **dict(_FULL, **kw))
     for r in _mixed_batch(2, n=4):
         eng.add_request(r)
     assert _norm(eng.journal()) == _norm(eng.snapshot())  # all queued
-    for _ in range(6):
-        eng.step()
-        assert _norm(eng.journal()) == _norm(eng.snapshot())
     while eng.step():
-        pass
-    assert _norm(eng.journal()) == _norm(eng.snapshot())  # drained
+        assert _norm(eng.journal()) == _norm(eng.snapshot())
     assert eng.journal()["running"] == eng.journal()["queued"] == []
+    assert eng.stats[counter] > 0
+    assert eng.stats["host_overlap_steps"] > 0
+    assert eng.stats["journal_incremental_updates"] > 0
 
 
 def test_fleet_audit_catches_journal_divergence(monkeypatch):
@@ -136,7 +101,6 @@ def test_fleet_audit_catches_journal_divergence(monkeypatch):
     from paddle_tpu.analysis.engine_audit import EngineAuditError
 
     monkeypatch.setenv("PADDLE_TPU_ENGINE_AUDIT", "1")
-    monkeypatch.setenv("PADDLE_TPU_ASYNC_HOST", "1")
     cfg, params = _tiny()
     fleet = FleetRouter(cfg, params, n_replicas=2, **_FULL)
     fleet.add_request(Request(rid=0, prompt_ids=np.arange(
@@ -155,34 +119,20 @@ def test_fleet_audit_catches_journal_divergence(monkeypatch):
 
 # ---------------- fleet: steady state + chaos failover ----------------
 
-def test_fleet_serial_arm_pays_full_rebuilds(monkeypatch):
-    """The off arm restores the historical router behaviour: one full
-    snapshot() rebuild per busy-replica step and per dispatch, zero
-    overlap, zero incremental updates."""
-    cfg, params = _tiny()
-    monkeypatch.setenv("PADDLE_TPU_ASYNC_HOST", "0")
-    off = FleetRouter(cfg, params, n_replicas=2, **_FULL)
-    off.serve(_mixed_batch(3))
-    assert off.stats["journal_full_rebuilds"] > 0
-    assert off.stats["host_overlap_steps"] == 0
-    assert off.stats["journal_incremental_updates"] == 0
-
-
 def test_fleet_failover_token_identity_via_incremental_journal(
         monkeypatch):
-    """replica_crash mid-serve with async ON + per-step equivalence
-    audit: every accepted request's stream is token-identical to an
+    """replica_crash mid-serve with the per-step equivalence audit:
+    every accepted request's stream is token-identical to an
     uninterrupted fleet's, and the replay consumed the INCREMENTAL
-    journal — one boundary pull, zero router snapshot rebuilds.  The
-    uninterrupted reference doubles as the steady-state assert: a
-    fault-free async fleet never rebuilds a snapshot."""
+    journal — one boundary pull.  The uninterrupted reference doubles as
+    the steady-state assert: a fault-free fleet never has a replica
+    rebuild a snapshot."""
     cfg, params = _tiny()
     ref_reqs = _mixed_batch(4, new=8)
-    monkeypatch.setenv("PADDLE_TPU_ASYNC_HOST", "1")
     ref_fleet = FleetRouter(cfg, params, n_replicas=2, **_FULL)
     ref = ref_fleet.serve(ref_reqs)
-    assert ref_fleet.stats["journal_full_rebuilds"] == 0
     assert ref_fleet.stats["host_overlap_steps"] > 0
+    assert ref_fleet.stats["journal_incremental_updates"] == 0
     assert sum(e.stats["journal_full_rebuilds"]
                for e in ref_fleet.replicas) == 0
     monkeypatch.setenv("PADDLE_TPU_ENGINE_AUDIT", "1")
@@ -196,27 +146,7 @@ def test_fleet_failover_token_identity_via_incremental_journal(
     assert all(r.status == "FINISHED" for r in reqs)
     assert fleet.stats["failovers"] == 1
     assert fleet.stats["journal_incremental_updates"] >= 1  # death pull
-    assert fleet.stats["journal_full_rebuilds"] == 0
     assert fleet.health.count("DEAD") == 1
-
-
-# ---------------- flag registry + schema ----------------
-
-def test_flag_registered_with_docstring(monkeypatch):
-    assert envflags.BOOL_FLAGS["PADDLE_TPU_ASYNC_HOST"] is True
-    assert "PADDLE_TPU_ASYNC_HOST" in envflags.__doc__
-
-
-def test_flag_typo_warns_once_and_falls_back(monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_ASYNC_HOST", "off")
-    envflags._warned.clear()
-    with pytest.warns(UserWarning, match="PADDLE_TPU_ASYNC_HOST"):
-        assert env_bool("PADDLE_TPU_ASYNC_HOST", True) is True
-    import warnings as _w
-
-    with _w.catch_warnings():          # once per (flag, raw) value
-        _w.simplefilter("error")
-        assert env_bool("PADDLE_TPU_ASYNC_HOST", True) is True
 
 
 def test_journal_counters_in_schemas():
@@ -224,6 +154,8 @@ def test_journal_counters_in_schemas():
                                                     FLEET_STAT_SCHEMA)
 
     for schema in (ENGINE_STAT_SCHEMA, FLEET_STAT_SCHEMA):
-        for key in ("journal_incremental_updates", "journal_full_rebuilds",
-                    "host_overlap_steps"):
+        for key in ("journal_incremental_updates", "host_overlap_steps"):
             assert key in schema
+    # snapshot() is the engine's; the router pulls journals and builds none
+    assert "journal_full_rebuilds" in ENGINE_STAT_SCHEMA
+    assert "journal_full_rebuilds" not in FLEET_STAT_SCHEMA

@@ -1,14 +1,13 @@
 """Fault-tolerant serving tests (ISSUE 6, docs/fault_tolerance.md).
 
-The correctness bar: no injected fault may escape ``step()`` in graceful
-mode — the offending request terminates (pages and cache refs released,
+The correctness bar: no injected fault may escape ``step()`` — the
+offending request terminates (pages and cache refs released,
 pool accounting closing exactly) and every SURVIVING request's token
 stream is identical to a run that never contained the poison request,
 for greedy AND seeded sampled requests alike (each serve below carries a
 mixed batch, so every assertion covers both sampling modes at once).
-``PADDLE_TPU_GRACEFUL=0`` must restore the brittle pre-fault-tolerance
-engine: the same faults raise out of ``step()``/``serve()``.  The chaos
-runs all execute under ``PADDLE_TPU_ENGINE_AUDIT=1`` — every ladder rung
+The switch that used to turn this off is retired: setting it warns and
+changes nothing.  The chaos runs all execute under ``PADDLE_TPU_ENGINE_AUDIT=1`` — every ladder rung
 must leave the auditor's invariants (including the new I8 terminal-
 ownership check) green.
 """
@@ -26,6 +25,7 @@ from paddle_tpu.inference.faults import FaultInjected, FaultPlan
 from paddle_tpu.inference.serving import (ContinuousBatchingEngine, Request,
                                           TERMINAL_STATUSES)
 from paddle_tpu.models import llama
+from paddle_tpu.utils.envflags import RETIRED_FLAGS
 
 
 def _tiny():
@@ -67,7 +67,7 @@ def _pool_closes(eng):
     assert all(r is None for r in eng._slot_req)
 
 
-# ---------------- chaos matrix: graceful on ----------------
+# ---------------- chaos matrix ----------------
 #
 # >= 5 fault kinds; every run is a mixed greedy+seeded-sampled batch under
 # PADDLE_TPU_ENGINE_AUDIT=1.  Survivor token-identity is asserted against a
@@ -269,77 +269,46 @@ def test_chaos_spec_and_chunked_paths(monkeypatch):
         assert got[r.rid] == ref[r.rid]
 
 
-# ---------------- chaos matrix: graceful off ----------------
-#
-# PADDLE_TPU_GRACEFUL=0 restores the pre-fault-tolerance engine: the same
-# faults raise out of step()/serve() (and nan_logits is inert — the
-# graceful-off compiled program has no poison operand).
+# ---------------- the retired off-switches ----------------
 
-def _off_engine(monkeypatch, spec, **kw):
+@pytest.mark.parametrize("flag", RETIRED_FLAGS)
+def test_retired_flag_warns_once_and_changes_nothing(monkeypatch, flag):
+    """An operator who still exports one of the four removed off-switches
+    is told so once, by name, where the engine is built; the engine is the
+    default one all the same: registry-backed stats, a working cancel(),
+    and a poisoned request quarantined without touching its neighbours."""
+    from paddle_tpu.inference.observability import StatsView
+    from paddle_tpu.utils import envflags
+
     cfg, params = _tiny()
-    monkeypatch.setenv("PADDLE_TPU_GRACEFUL", "0")
-    monkeypatch.setenv("PADDLE_TPU_FAULT_INJECT", spec)
-    return _engine(cfg, params, **kw)
-
-
-def test_graceful_off_alloc_fail_raises_diagnosable(monkeypatch):
-    """Graceful-off pool exhaustion raises the pre-PR RuntimeError — now
-    naming the rid, pages needed vs available, and evictable-cache count
-    (the satellite: the old message was undiagnosable).  The clause fires
-    at step 9 — the 9-token prompt's third-block grab (pos crosses 16) —
-    with no victims to preempt, the exact single-request-exhaustion the
-    old opaque message covered."""
-    rs = np.random.RandomState(5)
-    eng = _off_engine(monkeypatch, "alloc_fail@step=9")
-    eng.add_request(Request(rid=42, prompt_ids=rs.randint(0, 128, (9,))
-                            .astype(np.int32), max_new_tokens=30))
-    with pytest.raises(RuntimeError) as ei:
-        for _ in range(40):
-            eng.step()
-    msg = str(ei.value)
-    assert "rid=42" in msg
-    assert "free" in msg and "evictable" in msg and "block" in msg
-
-
-def test_graceful_off_kernel_error_raises(monkeypatch):
-    rs = np.random.RandomState(6)
-    eng = _off_engine(monkeypatch, "kernel_error@step=2")
-    reqs = _mixed_batch(rs, n=2)
-    with pytest.raises(FaultInjected):
-        eng.serve(reqs)
-
-
-def test_graceful_off_slot_error_raises(monkeypatch):
-    rs = np.random.RandomState(7)
-    eng = _off_engine(monkeypatch, "slot_error@rid=0,step=3")
-    reqs = _mixed_batch(rs, n=2)
-    with pytest.raises(FaultInjected):
-        eng.serve(reqs)
-
-
-def test_graceful_off_cache_error_raises(monkeypatch):
-    rs = np.random.RandomState(8)
-    eng = _off_engine(monkeypatch, "cache_error@step=1",
-                      enable_prefix_caching=True)
-    reqs = _mixed_batch(rs, n=2, prompt_len=17)
-    with pytest.raises(FaultInjected):
-        eng.serve(reqs)
-
-
-def test_graceful_off_nan_logits_inert_and_byte_identical(monkeypatch):
-    """nan_logits requires the graceful poison operand — graceful-off the
-    compiled program is the pre-fault-tolerance one (no guard, no poison),
-    so the clause is inert and the serve completes with streams identical
-    to a graceful-on fault-free serve (the kill switch changes failure
-    HANDLING, never tokens)."""
-    rs = np.random.RandomState(9)
-    ref = _reference_serve(_mixed_batch(np.random.RandomState(9)))
-    eng = _off_engine(monkeypatch, "nan_logits@slot=0,step=2")
-    reqs = _mixed_batch(rs)
+    monkeypatch.setenv(flag, "0")
+    monkeypatch.setenv("PADDLE_TPU_FAULT_INJECT", "nan_logits@slot=0,step=3")
+    envflags._warned.clear()
+    with pytest.warns(UserWarning, match=f"{flag}='0' has no effect"):
+        eng = _engine(cfg, params)
+    with warnings.catch_warnings(record=True) as again:
+        warnings.simplefilter("always")     # told once, not at every build
+        _engine(cfg, params)
+    assert not [w for w in again if flag in str(w.message)]
+    assert isinstance(eng.stats, StatsView)
+    assert eng.metrics is not None and eng.slo is not None
+    assert eng._flight is not None
+    reqs = _mixed_batch(np.random.RandomState(1))
+    extra = Request(rid=99, prompt_ids=np.arange(9, dtype=np.int32),
+                    max_new_tokens=4)
+    eng.add_request(extra)
+    assert eng.cancel(99) is True and extra.status == "CANCELLED"
     got = eng.serve(reqs)
-    assert got == ref
-    assert all(r.status == "FINISHED" for r in reqs)
-    assert eng.stats["nan_guard_trips"] == 0
+    failed = [r for r in reqs if r.status == "FAILED"]
+    assert len(failed) == 1 and "non-finite logits" in failed[0].error
+    assert eng.stats["nan_guard_trips"] == 1
+    assert eng.stats["host_overlap_steps"] > 0
+    assert len(eng._flight) > 0
+    ref = _reference_serve([r for r in _mixed_batch(np.random.RandomState(1))
+                            if r.rid != failed[0].rid], monkeypatch)
+    for rid, toks in ref.items():
+        assert got[rid] == toks
+    _pool_closes(eng)
 
 
 # ---------------- overload degradation ladder ----------------
@@ -580,14 +549,6 @@ def test_cancel_mid_prefill_frees_cursor_pages(monkeypatch):
     assert eng.step() is False
 
 
-def test_cancel_requires_graceful(monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_GRACEFUL", "0")
-    cfg, params = _tiny()
-    eng = _engine(cfg, params)
-    with pytest.raises(RuntimeError, match="GRACEFUL"):
-        eng.cancel(0)
-
-
 def test_bounded_queue_backpressure(monkeypatch):
     cfg, params = _tiny()
     rs = np.random.RandomState(19)
@@ -605,16 +566,6 @@ def test_bounded_queue_backpressure(monkeypatch):
     while eng.step() or eng._queue:
         pass
     assert sum(1 for r in reqs if r.status == "FINISHED") == 2
-
-
-def test_bounded_queue_graceful_off_raises(monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_GRACEFUL", "0")
-    cfg, params = _tiny()
-    rs = np.random.RandomState(20)
-    eng = _engine(cfg, params, max_batch=1, max_queue=0)
-    with pytest.raises(RuntimeError, match="queue full"):
-        eng.add_request(Request(rid=0, prompt_ids=rs.randint(0, 128, (9,))
-                                .astype(np.int32)))
 
 
 # ---------------- validation satellites ----------------
@@ -860,14 +811,3 @@ def test_fault_spec_bad_key_and_value(monkeypatch):
     envflags._warned.clear()
     with pytest.warns(UserWarning, match="two"):
         assert not FaultPlan.from_env()
-
-
-def test_graceful_flag_registered_and_validated(monkeypatch):
-    from paddle_tpu.utils.envflags import BOOL_FLAGS, env_bool
-    from paddle_tpu.utils import envflags
-
-    assert BOOL_FLAGS["PADDLE_TPU_GRACEFUL"] is True
-    monkeypatch.setenv("PADDLE_TPU_GRACEFUL", "off")
-    envflags._warned.clear()
-    with pytest.warns(UserWarning, match="GRACEFUL"):
-        assert env_bool("PADDLE_TPU_GRACEFUL", True) is True  # typo: default

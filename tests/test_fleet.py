@@ -593,13 +593,6 @@ def test_valid_fleet_spec_does_not_warn(monkeypatch):
     assert bool(plan)
 
 
-def test_fleet_requires_graceful(monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_GRACEFUL", "0")
-    cfg, params = _tiny()
-    with pytest.raises(RuntimeError, match="GRACEFUL"):
-        FleetRouter(cfg, params, n_replicas=2, **_PLAIN)
-
-
 # ---------------- fleet-level cancel ----------------
 
 def test_fleet_cancel_cancels_every_copy(monkeypatch):

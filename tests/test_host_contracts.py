@@ -269,7 +269,21 @@ def test_real_modules_pinned_clean(monkeypatch):
             assert len(sec["covered_edges"]) == len(sec["declared_edges"])
     summary = host_contracts_summary(sections)
     assert summary["violations"] == 10
-    assert summary["machines"] == 2 and summary["windows"] == 6
+    assert summary["machines"] == 2 and summary["windows"] == 3
+
+
+@pytest.mark.parametrize("method", ["_step", "_mixed_step", "_spec_step"])
+def test_step_family_has_one_window_and_one_launch(method):
+    """Each step family launches its compiled program from ONE call site and
+    opens ONE ``_host_overlap()`` window behind it: a second arm of either
+    (a launch written twice for an option) is what lost a request inside
+    ``_mixed_step`` rewrites before."""
+    _, sections = check_host_contracts(target="host")
+    (sec,) = [s for s in sections if s["kind"] == "overlap"
+              and s["method"] == f"ContinuousBatchingEngine.{method}"]
+    assert len(sec["windows"]) == 1, sec["windows"]
+    assert len(sec["launches"]) == 1, sec["launches"]
+    assert sec["launches"][0] < sec["windows"][0]
 
 
 def test_effect_analysis_deterministic(monkeypatch):

@@ -258,31 +258,6 @@ def test_demote_readmit_roundtrip_through_engine():
     assert "tier_demote" in kinds and "tier_readmit" in kinds
 
 
-def test_tier_restores_on_graceful_off_chunked(monkeypatch):
-    """Graceful-off chunked admission allocates the whole prompt's private
-    pages upfront, so the cursor-driven restore path has no boundary to
-    append shared pages at — the tier must instead restore AT ADMISSION
-    (like the bucketed path) rather than silently no-oping while still
-    paying demotion costs (review regression)."""
-    monkeypatch.setenv("PADDLE_TPU_GRACEFUL", "0")
-    cfg, params = _tiny()
-    rs = np.random.RandomState(21)
-    P = rs.randint(0, 128, (30,)).astype(np.int32)
-    kw = dict(max_batch=1, max_seq=64, chunk=1, paged=True, block_size=8,
-              num_blocks=8, enable_prefix_caching=True,
-              enable_chunked_prefill=True, prefill_chunk=5)
-    eng = ContinuousBatchingEngine(cfg, params, **kw,
-                                   enable_host_kv_tier=True)
-    first = eng.serve([Request(rid=0, prompt_ids=P, max_new_tokens=4)])
-    for i in range(3):
-        q = rs.randint(0, 128, (40,)).astype(np.int32)
-        eng.serve([Request(rid=10 + i, prompt_ids=q, max_new_tokens=4)])
-    again = eng.serve([Request(rid=1, prompt_ids=P, max_new_tokens=4)])
-    assert again[1] == first[0]
-    assert eng.stats["tier_readmits"] > 0, \
-        "graceful-off chunked engine never restored a demoted block"
-
-
 def test_tier_tp2_token_identity():
     """Tier-on TP=2 must stream the exact tier-off TP=1 tokens (the
     conftest forces an 8-device CPU mesh; the H2D pool write lands on the

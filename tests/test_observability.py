@@ -4,7 +4,7 @@ Covers the four pillars and their contracts:
 
 * MetricsRegistry — typed counters/gauges/log2-bucket histograms, labels,
   Prometheus exposition, and the dict-compatible StatsView the engines'
-  ``stats`` migrated onto (every counter key read anywhere in tests/bench
+  ``stats`` migrated onto (every counter key read anywhere in tests/
   must be registered with a help string — enforced by a source scan);
 * request-lifecycle tracing — queued/prefill/decode spans + terminal
   markers per request, cross-replica failover/hedge flow links, one chrome
@@ -15,12 +15,9 @@ Covers the four pillars and their contracts:
 * FlightRecorder — bounded ring, dumps (with metrics snapshot) on request
   FAILURE, EngineAuditError, and replica death.
 
-THE overriding contract: recording is host-side post-step, so token
-streams are byte-identical with observability on vs the
-``PADDLE_TPU_METRICS=0`` / ``PADDLE_TPU_FLIGHT_RECORDER=0`` kill switches
-— asserted with prefix cache + speculation + chunked prefill + graceful +
-TP all on — and a metric recorded via callback from INSIDE a jitted step
-fails the host_sync lint gate.
+THE overriding contract: recording is host-side post-step — a metric
+recorded via callback from INSIDE a jitted step fails the host_sync lint
+gate.
 """
 
 from __future__ import annotations
@@ -164,13 +161,12 @@ def test_stats_view_behaves_like_the_old_dict():
 
 
 def test_every_stats_key_read_in_tests_and_bench_is_registered():
-    """Introspection satellite: scan tests/ + bench.py for stats["..."]
-    reads and require each key in a schema, with a non-empty help."""
+    """Introspection satellite: scan tests/ for stats["..."] reads and
+    require each key in a schema, with a non-empty help."""
     root = pathlib.Path(__file__).resolve().parent.parent
     pat = re.compile(r"stats\[[\"']([a-z_]+)[\"']\]")
     keys: set[str] = set()
-    for path in [*sorted((root / "tests").glob("test_*.py")),
-                 root / "bench.py"]:
+    for path in sorted((root / "tests").glob("test_*.py")):
         keys |= set(pat.findall(path.read_text()))
     known = set(ENGINE_STAT_SCHEMA) | set(FLEET_STAT_SCHEMA)
     assert keys <= known, f"unregistered stat keys: {sorted(keys - known)}"
@@ -186,73 +182,6 @@ def test_engine_stats_keys_match_schema_exactly():
     helps = eng.metrics.describe()
     for key in ENGINE_STAT_SCHEMA:
         assert helps[f"paddle_tpu_serving_{key}"].strip()
-
-
-# ---------------- kill switches ----------------
-
-def test_metrics_off_restores_plain_dict(monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_METRICS", "0")
-    eng = _engine()
-    assert type(eng.stats) is dict
-    assert eng.metrics is None and eng.slo is None
-    assert set(eng.stats) == set(ENGINE_STAT_SCHEMA)
-    assert eng.stats["decode_time_s"] == 0.0
-
-
-def test_flight_recorder_off(monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_FLIGHT_RECORDER", "0")
-    eng = _engine()
-    assert eng._flight is None
-    eng.serve(_requests(2))     # serving still works, nothing recorded
-
-
-def test_flags_registered_and_typo_warns(monkeypatch):
-    from paddle_tpu.utils import envflags
-    from paddle_tpu.utils.envflags import BOOL_FLAGS, env_bool
-
-    assert BOOL_FLAGS["PADDLE_TPU_METRICS"] is True
-    assert BOOL_FLAGS["PADDLE_TPU_FLIGHT_RECORDER"] is True
-    for flag in ("PADDLE_TPU_METRICS", "PADDLE_TPU_FLIGHT_RECORDER"):
-        monkeypatch.setenv(flag, "off")
-        envflags._warned.clear()
-        with pytest.warns(UserWarning, match=flag):
-            assert env_bool(flag, True) is True    # typo -> default
-
-
-def test_token_identity_with_observability_on_vs_off(monkeypatch):
-    """THE acceptance bar: greedy AND seeded sampled streams byte-identical
-    with metrics/tracing/flight-recorder on vs both kill switches, with
-    prefix cache + speculation + chunked prefill + graceful + TP=2 all
-    on (the conftest forces an 8-device CPU mesh)."""
-    rs = np.random.RandomState(7)
-    shared = np.arange(16, dtype=np.int32)
-
-    def reqs():
-        out = []
-        for i in range(4):
-            tail = rs.randint(0, 128, (6,)).astype(np.int32)
-            out.append(Request(rid=i,
-                               prompt_ids=np.concatenate([shared, tail]),
-                               max_new_tokens=8,
-                               temperature=0.7 if i % 2 else 0.0,
-                               seed=11 + i))
-        return out
-    rs_state = rs.get_state()
-    outs = {}
-    for obs_on in (True, False):
-        rs.set_state(rs_state)
-        if obs_on:
-            monkeypatch.delenv("PADDLE_TPU_METRICS", raising=False)
-            monkeypatch.delenv("PADDLE_TPU_FLIGHT_RECORDER", raising=False)
-        else:
-            monkeypatch.setenv("PADDLE_TPU_METRICS", "0")
-            monkeypatch.setenv("PADDLE_TPU_FLIGHT_RECORDER", "0")
-        eng = _engine(num_blocks=24, enable_prefix_caching=True,
-                      enable_speculation=True, num_draft_tokens=3,
-                      enable_chunked_prefill=True, prefill_chunk=8,
-                      tensor_parallel=2)
-        outs[obs_on] = eng.serve(reqs())
-    assert outs[True] == outs[False]
 
 
 # ---------------- lifecycle tracing ----------------
@@ -271,7 +200,7 @@ def test_request_spans_emitted_and_export_drains(tmp_path):
     assert decode_tids == {0, 1}
     # drain-on-export: the buffer is the export's, not a leak
     assert profiler.host_events_len() == 0
-    # span counts are mirrored on the tracer (bench rung detail)
+    # span counts are mirrored on the tracer
     assert eng._tracer.counts["decode"] == 2
     assert eng._tracer.counts["queued"] == 2
 
@@ -515,18 +444,6 @@ def test_fleet_hedge_emits_linked_spans():
     assert "hedge" in kinds and "health" in kinds
 
 
-def test_fleet_metrics_off_plain_dicts(monkeypatch):
-    monkeypatch.setenv("PADDLE_TPU_METRICS", "0")
-    fleet = _fleet(n=2)
-    assert type(fleet.stats) is dict and fleet.slo is None
-    # absent evidence reads as absent: no registry, so bench embeds null
-    # exposition rather than an empty string
-    assert fleet.metrics is None
-    reqs = _requests(2, new=3, seed=9)
-    got = fleet.serve(reqs)
-    assert all(len(v) == 3 for v in got.values())
-
-
 def test_process_names_survive_drain_on_export(tmp_path):
     """Periodic-export regression: the replica lane-name metadata must
     re-emit after export() drains the buffer, or every trace after the
@@ -544,9 +461,6 @@ def test_process_names_survive_drain_on_export(tmp_path):
 
 # ---------------- the step measured from inside ----------------
 
-_STEP_COUNTS = ("step_rows_computed", "step_rows_live", "prefill_rows_packed",
-                "slot_steps_live", "slot_steps_total", "kv_page_steps_in_use",
-                "kv_page_steps_total")
 _PHASES = ["serving/admit", "serving/pack", "serving/dispatch",
            "serving/host_overlap", "serving/fetch", "serving/bank"]
 
@@ -607,12 +521,10 @@ def _step_trees(log):
     return trees
 
 
-@pytest.mark.parametrize("metrics", ["1", "0"])
-def test_step_counters_after_chunked_serve(monkeypatch, metrics):
+def test_step_counters_after_chunked_serve():
     """Chunked prefill, no prefix cache, no preemption: every prompt row is
     packed exactly once, and each mean over the serve is a ratio of two
-    counters — with the registry on and with the plain dict."""
-    monkeypatch.setenv("PADDLE_TPU_METRICS", metrics)
+    counters."""
     eng = _engine(enable_chunked_prefill=True, prefill_chunk=4,
                   num_blocks=24)
     reqs = _requests(3, new=5)
@@ -633,18 +545,6 @@ def test_step_counters_after_chunked_serve(monkeypatch, metrics):
     decode_launches = len(fills) - st["mixed_steps"]
     assert st["step_rows_computed"] == eng.max_batch * (
         4 * st["mixed_steps"] + eng.chunk * decode_launches)
-
-
-def test_step_counters_same_with_metrics_off(monkeypatch):
-    counts = {}
-    for metrics in ("1", "0"):
-        monkeypatch.setenv("PADDLE_TPU_METRICS", metrics)
-        eng = _engine(enable_chunked_prefill=True, prefill_chunk=4,
-                      num_blocks=24)
-        _drive(eng, _requests(3, new=5))
-        counts[metrics] = {k: eng.stats[k] for k in _STEP_COUNTS}
-        assert all(isinstance(v, int) for v in counts[metrics].values())
-    assert counts["1"] == counts["0"]
 
 
 def test_prefill_rows_of_a_whole_prompt_engine():
@@ -793,11 +693,9 @@ def test_record_event_arguments_ride_the_host_buffer(tmp_path):
 
 # ---------------- lint gate ----------------
 
-def test_serving_target_host_sync_clean_with_metrics_on(monkeypatch):
-    """The gate's serving programs stay callback-free with metrics ON
-    (targets force PADDLE_TPU_METRICS=1, so an ambient =0 cannot hide a
-    regression)."""
-    monkeypatch.setenv("PADDLE_TPU_METRICS", "0")    # ambient kill switch
+def test_serving_target_host_sync_clean_with_metrics_on():
+    """The gate's serving programs stay callback-free with the engine's
+    metrics registry, tracer and flight recorder recording."""
     from paddle_tpu.analysis import targets
 
     t = targets.build("serving_decode_step")

@@ -130,24 +130,6 @@ def test_serve_rejects_bad_request_and_serves_the_rest():
     assert list(got) == [2] and len(got[2]) == 2
 
 
-def test_serve_validates_all_before_enqueue_graceful_off(monkeypatch):
-    """PADDLE_TPU_GRACEFUL=0 restores the pre-fault-tolerance contract:
-    validation is all-or-nothing and any bad request raises before anything
-    is enqueued."""
-    monkeypatch.setenv("PADDLE_TPU_GRACEFUL", "0")
-    cfg, params = _tiny()
-    eng = ContinuousBatchingEngine(cfg, params, max_batch=1, max_seq=32)
-    good = Request(rid=0, prompt_ids=np.arange(4, dtype=np.int32), max_new_tokens=2)
-    bad = Request(rid=1, prompt_ids=np.arange(100, dtype=np.int32))
-    with pytest.raises(ValueError):
-        eng.serve([good, bad])
-    # the failed batch left nothing behind — a later serve is unaffected
-    assert eng._queue == []
-    other = Request(rid=2, prompt_ids=np.arange(3, dtype=np.int32), max_new_tokens=2)
-    got = eng.serve([other])
-    assert list(got) == [2] and len(got[2]) == 2
-
-
 def test_cache_boundary_uses_last_position():
     """A prompt of max_seq-1 tokens can still generate 2 tokens: decode may
     write K/V at position max_seq-1 (the off-by-one the review caught)."""

@@ -9,7 +9,8 @@ Two layers of proof:
   regression moves these numbers);
 * a tiny-config live-arrays check that building the engine with ``quant=``
   and dropping the caller's fp tree actually FREES the fp matmul weights —
-  the "free the fp tree before serving" step bench.py relies on at 3B.
+  the "free the fp tree before serving" step a quantized 3B deployment
+  relies on.
 """
 
 from __future__ import annotations
@@ -28,7 +29,7 @@ from paddle_tpu.models import llama
 GIB = 1024 ** 3
 V5E_HBM_BYTES = 16 * GIB
 
-# the exact ~3B config bench.py serves (cb_3b_* rungs)
+# a ~3B serving config
 CFG_3B = dict(vocab_size=32000, hidden_size=2560, intermediate_size=6912,
               num_hidden_layers=32, num_attention_heads=20,
               num_key_value_heads=4)
@@ -70,7 +71,7 @@ def test_3b_int4_serving_fits_v5e_budget():
     fp_bytes = _tree_bytes(_shapes(cfg))
     cache_bytes = _paged_cache_bytes(cfg, **ENGINE_3B)
 
-    # the fp tree alone is ~4.5 GB — the reason bench.py's rungs del the fp
+    # the fp tree alone is ~4.5 GB — the reason a quantized serve dels the fp
     # params before serving, and why int4 is the 16 GB story at 3B+
     assert fp_bytes > 4.0 * GIB, f"fp tree {fp_bytes / GIB:.2f} GiB"
 
@@ -89,7 +90,7 @@ def test_3b_int4_serving_fits_v5e_budget():
 
     # freeing the fp tree reclaims more bytes than the ENTIRE int4 live set
     # (~4.4 vs ~1.4 GiB): keeping it resident would more than triple the
-    # serving footprint — the accounting reason bench.py dels the fp params
+    # serving footprint — the accounting reason to del the fp params
     int4_bytes = _tree_bytes(_shapes(cfg, "int4"))
     assert fp_bytes > int4_bytes + cache_bytes
 
@@ -112,7 +113,7 @@ def test_quantized_engine_frees_fp_matmul_weights():
     params = llama.init_params(cfg, jax.random.key(0))
     eng = ContinuousBatchingEngine(cfg, params, max_batch=2, max_seq=64,
                                    quant="int8", paged=True, block_size=8)
-    del params  # what bench.py's quantized rungs do before serving
+    del params  # what a quantized deployment does before serving
     after = live_bytes()
 
     expected = (_tree_bytes(_shapes(cfg, "int8"))
