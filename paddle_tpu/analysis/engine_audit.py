@@ -51,6 +51,15 @@ I8  terminal ownership (docs/fault_tolerance.md) — a request in a terminal
     violation means a failed request's pages leaked or a zombie is still
     being scheduled.
 
+I11 mixed-step row bound (engines with ``enable_chunked_prefill``;
+    checked by ``_mixed_step`` itself before EVERY launch, audit on or off)
+    — the rows the host marked live number at most ``_mixed_rows``, the
+    packed rows the compiled mixed program computes
+    (docs/chunked_prefill.md "Packed rows").  The program keeps the first
+    ``_mixed_rows`` live rows and would drop the rest without a word, so a
+    packing that outgrows the bound raises instead of launching: a dropped
+    row is a token some request never gets.
+
 I10 hierarchical-KV tier (docs/kv_tier.md; engines with a host tier
     attached) — every cached block is in exactly one of {HBM pool, host
     tier, dead}: demotion MOVES a block D2H (the victim leaves the prefix

@@ -316,9 +316,10 @@ ENGINE_STAT_SCHEMA = {
     # (_count_launch) or per step() that found work, so a mean over any
     # window is the ratio of two deltas taken at the same boundary
     "step_rows_computed": ("counter",
-                           "Rows the launched step programs computed "
-                           "whatever was live (mixed: max_batch x "
-                           "prefill_chunk; decode: max_batch x chunk; "
+                           "Rows the launched step programs' matmuls ran "
+                           "whatever was live (mixed: the packed rows, "
+                           "max(token_budget, max_batch) in whole "
+                           "sublanes; decode: max_batch x chunk; "
                            "verify: max_batch x (1 + draft tokens))"),
     "step_rows_live": ("counter",
                        "Rows of those that carried a token someone waits "

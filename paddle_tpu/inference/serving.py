@@ -821,6 +821,14 @@ class ContinuousBatchingEngine:
             self._token_budget = (int(token_budget)
                                   if token_budget is not None
                                   else self._prefill_chunk + max_batch)
+            # the rows the mixed program's matmuls run (_mixed_one): the
+            # most _mixed_step can mark live — decode slots pack first, and
+            # the 1-row floor adds a row only beside a slot that is still
+            # prefilling — in whole sublanes of the dtype, within [B, T]
+            sub = 32 // jnp.dtype(cfg.dtype).itemsize
+            self._mixed_rows = min(
+                -(-max(self._token_budget, max_batch) // sub) * sub,
+                max_batch * self._prefill_chunk)
             # per-slot prefill progress: _prefill_ids[s] holds the FULL id
             # stream (prompt, or prompt + generated-so-far on a preemption
             # resume) while the slot is still streaming in; _prefilled[s]
@@ -832,7 +840,7 @@ class ContinuousBatchingEngine:
             # the runtime auditor's I7 checks the two sets stay disjoint
             self._last_pack: tuple[tuple[int, ...], tuple[int, ...]] = ((),
                                                                         ())
-            # ONE compiled [B, T] program per sampling mode for the whole
+            # ONE compiled program per sampling mode for the whole
             # serve: chunk packing / per-slot progress are q_lens/pos DATA,
             # so prefill goes from log2(max_seq) bucketed variants to O(1)
             self._mixed_greedy = self._jit_step(
@@ -1573,14 +1581,26 @@ class ContinuousBatchingEngine:
         Decode-ready slots ride as q_lens == 1 lanes (row 0 = the pending
         token — exactly ``_decode_one``'s computation at their position);
         prefilling slots carry a prefill_chunk-row slice of their prompt.
-        Every live row's K/V scatters into its page and attention runs the
-        ragged chunked-prefill kernel (per-row visibility pos+t+1 — the
-        verify kernel's causal law with T free).  ONLY each slot's last
-        live row projects through the lm_head: a mid-prompt chunk's emit is
-        garbage the host ignores, the FINAL chunk's emit row sits at the
-        last prompt token's position so its logits ARE the first decode
-        step's (TTFT costs no extra launch), and a [B, V] head is T times
-        cheaper than the [B, T, V] one the mixed step never needs."""
+
+        Everything row-wise — embedding, norms, q/k/v, rope, ``wo``, the
+        residuals and the MLP — runs over the ``P = _mixed_rows`` PACKED
+        live rows ([1, P, h]; docs/chunked_prefill.md "Packed rows"), not
+        over [B, T]: ``idx`` [P] names the p-th live row (row-major; the
+        tail names dead rows, whose results nothing reads) and ``inv``
+        [B, T] the packed row of (b, t), or P — past the end, read as
+        zeros — where the row is dead.  Both are derived here from
+        ``valid_t`` and both are applied as gathers; the host's [B, T]
+        staging and its per-slot banking never see them.  Only the [B, T]
+        consumers unpack: every live row's K/V scatters into its page and
+        attention runs the ragged chunked-prefill kernel (per-row
+        visibility pos+t+1 — the verify kernel's causal law with T free)
+        over the unpacked q, whose output is gathered back to the packed
+        rows.  ONLY each slot's last live row projects through the
+        lm_head: a mid-prompt chunk's emit is garbage the host ignores,
+        the FINAL chunk's emit row sits at the last prompt token's
+        position so its logits ARE the first decode step's (TTFT costs no
+        extra launch), and a [B, V] head is T times cheaper than the
+        [B, T, V] one the mixed step never needs."""
         from .. import inference as _inf
         from ..ops import decode_attention as _da
         from ..ops.pallas import rope as rope_mod
@@ -1589,9 +1609,9 @@ class ContinuousBatchingEngine:
         B = self.max_batch
         S = self.max_seq
         T = tokens.shape[1]
+        P = self._mixed_rows
         nh = cfg.num_attention_heads
         bs_ = self.block_size
-        x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
         cos_full, sin_full = rope_mod.rope_cos_sin(S, cfg.head_dim,
                                                    base=cfg.rope_theta,
                                                    dtype=cfg.dtype)
@@ -1599,18 +1619,32 @@ class ContinuousBatchingEngine:
         valid_t = (active[:, None] & (jnp.arange(T)[None, :] < q_lens[:, None])
                    & (pos_t < S))
         safe_t = jnp.where(valid_t, pos_t, 0)
-        cos = jnp.take(cos_full[0], safe_t, axis=0)            # [B, T, d]
-        sin = jnp.take(sin_full[0], safe_t, axis=0)
         lane = jnp.arange(B)[:, None]
         blk = table[lane, safe_t // bs_]                       # [B, T]
         off = safe_t % bs_
         drop_blk = jnp.where(valid_t, blk, self.num_blocks)    # oob -> drop
 
+        # the two row maps: a stable sort brings the live rows first in
+        # row-major order; a running count gives each live row its place
+        live = valid_t.reshape(B * T)
+        idx = jnp.argsort(~live, stable=True)[:P].astype(jnp.int32)
+        inv = jnp.where(live, jnp.cumsum(live, dtype=jnp.int32) - 1,
+                        P).reshape(B, T)
+        x = jnp.take(params["embed"], tokens.reshape(B * T)[idx],
+                     axis=0).astype(cfg.dtype)[None]           # [1, P, h]
+        safe_p = safe_t.reshape(B * T)[idx]
+        cos = jnp.take(cos_full[0], safe_p, axis=0)[None]      # [1, P, d]
+        sin = jnp.take(sin_full[0], safe_p, axis=0)[None]
+
+        def unpack(rows):
+            # [1, P, ...] packed -> [B, T, ...]; dead rows read as zeros
+            return jnp.take(rows[0], inv, axis=0, mode="fill", fill_value=0)
+
         if self.kv_quant is not None:
-            write = self._quant_rows_write(table, pos_t, valid_t,
-                                           view=False)
+            write_bt = self._quant_rows_write(table, pos_t, valid_t,
+                                              view=False)
         else:
-            def write(ck, k):
+            def write_bt(ck, k):
                 # ck [num_blocks, nkv, bs, hd]; k [B, T, nkv, hd].
                 # Allocator invariant: distinct slots own disjoint pages,
                 # distinct rows hit distinct positions — no scatter
@@ -1619,6 +1653,9 @@ class ContinuousBatchingEngine:
                 out = ck.at[drop_blk, :, off].set(k, mode="drop")
                 return out, out
 
+        def write(ck, k):
+            return write_bt(ck, unpack(k))
+
         # total written length per slot incl. this chunk; inactive lanes
         # attend one stale position (finite, masked out downstream like the
         # dense path's garbage lanes)
@@ -1626,8 +1663,10 @@ class ContinuousBatchingEngine:
         seq_now = jnp.minimum(seq_base + jnp.where(active, q_lens, 1), S)
 
         def attend_fn(q, k_pool, v_pool):
-            # q [B, T, nh, hd] post-rope (the prefill kernel's kv_quant
-            # mode dequantizes quantized pools on read)
+            # q [1, P, nh, hd] post-rope -> the kernel's [B, T, nh, hd]
+            # (its kv_quant mode dequantizes quantized pools on read);
+            # its output goes back to the packed rows
+            q = unpack(q)
             if self.kv_quant is not None:
                 o = _da.paged_prefill_attention(
                     q, k_pool["q"], v_pool["q"], table, seq_now, q_lens,
@@ -1636,14 +1675,19 @@ class ContinuousBatchingEngine:
             else:
                 o = _da.paged_prefill_attention(q, k_pool, v_pool, table,
                                                 seq_now, q_lens)
-            return o.reshape(B, T, nh * cfg.head_dim)
+            return o.reshape(B * T, nh * cfg.head_dim)[idx][None]
 
         x, ak, av = _inf.transformer_apply(cfg, params, x, cache_k, cache_v,
                                            write, None, cos, sin,
                                            attend_fn=attend_fn,
                                            tp_axis=self._tp_axis)
-        last = jnp.take_along_axis(
-            x, (q_lens - 1).astype(jnp.int32)[:, None, None], axis=1)[:, 0]
+        # the emit row: a lane's live rows are a prefix of its T, so its
+        # last one is row n_live - 1 (a lane with none is clamped to a row
+        # the guard masks by ``active`` and the host never reads)
+        n_live = valid_t.sum(axis=1, dtype=jnp.int32)
+        emit = jnp.take_along_axis(
+            inv, jnp.maximum(n_live - 1, 0)[:, None], axis=1)[:, 0]
+        last = x[0][jnp.minimum(emit, P - 1)]                  # [B, h]
         return _inf.lm_head_logits(cfg, params, last), ak, av
 
     def _mixed_impl_paged(self, params, cache_k, cache_v, tokens, pos,
@@ -3168,7 +3212,8 @@ class ContinuousBatchingEngine:
     def _mixed_step(self) -> bool:
         """One unified prefill/decode round (docs/chunked_prefill.md): pack
         up to ``token_budget`` rows as [decode slots | prefill chunks] and
-        dispatch ONE compiled [B, T] launch.  Decode rows pack FIRST — every
+        dispatch ONE compiled launch ([B, T] staged, ``_mixed_rows`` packed
+        rows computed).  Decode rows pack FIRST — every
         decode-ready slot advances exactly one token, so decode never waits
         on a prompt (``decode_stall_steps`` stays 0) — then prefill chunks
         fill the remaining budget oldest-slot-first, at most
@@ -3273,9 +3318,20 @@ class ContinuousBatchingEngine:
         n_decode = sum(1 for s in decode_slots if active[s])
         n_seated = sum(r is not None for r in self._slot_req)
         prefill_rows = int(sum(chunk_rows.values()))
+        P = self._mixed_rows
+        staged = int(q_lens[active].sum())
+        if staged > P:
+            # the program packs the first P live rows and would drop the
+            # rest without a word: a lost row is a lost token
+            from ..analysis.engine_audit import EngineAuditError
+
+            self._flight.dump("engine_audit_error")
+            raise EngineAuditError(
+                f"engine audit I11 violated: mixed step staged {staged} "
+                f"live rows, the program computes {P}")
         self._phase("serving/dispatch", program="mixed",
                     decode_rows=n_decode, prefill_rows=prefill_rows,
-                    rows_computed=B * T)
+                    rows_computed=P)
         t0 = time.perf_counter()
         self._note_launch(t0)
         # step-packing summary: O(1) per step, the flight recorder's
@@ -3375,7 +3431,7 @@ class ContinuousBatchingEngine:
                 if (self._slot_req[s] is not None
                         and new_cur >= self.max_seq):
                     self._retire(s)
-        self._count_launch(B * T, n_decode + prefill_rows, n_seated,
+        self._count_launch(P, n_decode + prefill_rows, n_seated,
                            prefill_rows)
         self._maybe_audit()
         return True

@@ -508,3 +508,141 @@ def test_audit_i7_detects_chunk_outrunning_allocation(monkeypatch):
     eng._written[0] = 19
     with pytest.raises(EngineAuditError, match="I[67]"):
         audit_engine(eng)
+
+
+# ------------- packed rows against the dense [B, T] arithmetic -------------
+
+
+def _dense_mixed_one(eng, params, cache_k, cache_v, tokens, pos, active,
+                     q_lens, table):
+    """The mixed step as it was before the program packed its live rows:
+    every row-wise operation over the whole [B, T] stream, dead rows and
+    all.  Kept here as the oracle for ``_mixed_one``; returns the logits of
+    EVERY row ([B, T, V]) and the caches."""
+    from paddle_tpu import inference as _inf
+    from paddle_tpu.ops import decode_attention as _da
+    from paddle_tpu.ops.pallas import rope as rope_mod
+
+    cfg = eng.cfg
+    B, S, T = eng.max_batch, eng.max_seq, tokens.shape[1]
+    nh, bs_ = cfg.num_attention_heads, eng.block_size
+    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    cos_full, sin_full = rope_mod.rope_cos_sin(S, cfg.head_dim,
+                                               base=cfg.rope_theta,
+                                               dtype=cfg.dtype)
+    pos_t = pos[:, None] + jnp.arange(T)[None, :]
+    valid_t = (active[:, None] & (jnp.arange(T)[None, :] < q_lens[:, None])
+               & (pos_t < S))
+    safe_t = jnp.where(valid_t, pos_t, 0)
+    cos = jnp.take(cos_full[0], safe_t, axis=0)
+    sin = jnp.take(sin_full[0], safe_t, axis=0)
+    blk = table[jnp.arange(B)[:, None], safe_t // bs_]
+    off = safe_t % bs_
+    drop_blk = jnp.where(valid_t, blk, eng.num_blocks)
+
+    if eng.kv_quant is not None:
+        write = eng._quant_rows_write(table, pos_t, valid_t, view=False)
+    else:
+        def write(ck, k):
+            out = ck.at[drop_blk, :, off].set(k, mode="drop")
+            return out, out
+
+    seq_base = jnp.where(active & (pos < S), pos, 0)
+    seq_now = jnp.minimum(seq_base + jnp.where(active, q_lens, 1), S)
+
+    def attend_fn(q, k_pool, v_pool):
+        if eng.kv_quant is not None:
+            o = _da.paged_prefill_attention(
+                q, k_pool["q"], v_pool["q"], table, seq_now, q_lens,
+                kv_quant=eng.kv_quant, k_scale=k_pool["scale"],
+                v_scale=v_pool["scale"])
+        else:
+            o = _da.paged_prefill_attention(q, k_pool, v_pool, table,
+                                            seq_now, q_lens)
+        return o.reshape(B, T, nh * cfg.head_dim)
+
+    x, ak, av = _inf.transformer_apply(cfg, params, x, cache_k, cache_v,
+                                       write, None, cos, sin,
+                                       attend_fn=attend_fn)
+    return _inf.lm_head_logits(cfg, params, x), ak, av
+
+
+# (token_budget, pos [B], q_lens [B], active [B]) for max_batch 4,
+# prefill_chunk 8, max_seq 32: the packings _mixed_step can hand over
+_PACKINGS = {
+    "decode_rows_only": (None, [5, 17, 9, 2], [1, 1, 1, 1], [1, 1, 1, 0]),
+    "full_chunk_beside_decode": (None, [8, 21, 0, 13], [8, 1, 1, 1],
+                                 [1, 1, 0, 1]),
+    "two_chunks_split_the_budget": (None, [16, 4, 8, 30], [8, 1, 3, 1],
+                                    [1, 1, 1, 1]),
+    "one_row_chunks": (None, [6, 11, 19, 3], [1, 1, 1, 1], [1, 1, 1, 1]),
+    "chunk_crosses_max_seq": (None, [28, 7, 0, 0], [8, 1, 5, 1],
+                              [1, 1, 1, 0]),
+    "inactive_between_active": (None, [3, 9, 12, 20], [6, 8, 4, 7],
+                                [1, 0, 1, 0]),
+    "floor_row_past_a_small_budget": (2, [9, 4, 14, 0], [1, 1, 1, 1],
+                                      [1, 1, 1, 1]),
+    "every_row_live": (32, [0, 8, 16, 24], [8, 8, 8, 8], [1, 1, 1, 1]),
+}
+
+
+@pytest.mark.parametrize("kv_quant", [None, "int8"])
+@pytest.mark.parametrize("packing", sorted(_PACKINGS))
+def test_packed_rows_match_the_dense_program(packing, kv_quant):
+    """``_mixed_one`` runs its row-wise arithmetic over the packed live
+    rows; the dense [B, T] program above is what it replaced.  On
+    hand-made packings, over a pool that already holds context, every
+    active lane's emit-row logits and the WHOLE pool after the step agree:
+    a row sent to the wrong slot, dropped or written twice shows in
+    either."""
+    budget, pos, q_lens, active = _PACKINGS[packing]
+    cfg, params = _tiny()
+    B, T, S = 4, 8, 32
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_batch=B, max_seq=S, chunk=2, paged=True,
+        block_size=8, num_blocks=20, enable_chunked_prefill=True,
+        prefill_chunk=T, token_budget=budget, kv_quant=kv_quant)
+    pos, q_lens = np.asarray(pos, np.int32), np.asarray(q_lens, np.int32)
+    active = np.asarray(active, bool)
+    live = int(np.minimum(q_lens, S - pos)[active].sum())
+    assert live <= eng._mixed_rows <= B * T
+    rs = np.random.RandomState(len(packing))
+    tokens = rs.randint(1, 128, (B, T)).astype(np.int32)   # junk in dead rows
+    # every slot owns its four pages, in an order that is not the identity
+    table = rs.permutation(16).astype(np.int32).reshape(B, 4)
+
+    def pool(c):
+        if kv_quant is None:
+            return jnp.asarray(rs.standard_normal(c.shape), c.dtype)
+        return {"q": jnp.asarray(rs.randint(-127, 128, c["q"].shape),
+                                 jnp.int8),
+                "scale": jnp.asarray(rs.uniform(0.005, 0.02,
+                                                c["scale"].shape),
+                                     jnp.float32)}
+
+    ck, cv = pool(eng.cache_k), pool(eng.cache_v)
+    args = (eng.params, ck, cv, jnp.asarray(tokens), jnp.asarray(pos),
+            jnp.asarray(active), jnp.asarray(q_lens), jnp.asarray(table))
+    got, gk, gv = jax.jit(eng._mixed_one)(*args)
+    every, wk, wv = jax.jit(
+        lambda *a: _dense_mixed_one(eng, *a))(*args)
+    assert got.shape == (B, cfg.vocab_size)
+    n_live = np.minimum(q_lens, S - pos)
+    for b in np.flatnonzero(active):
+        np.testing.assert_allclose(np.asarray(got[b]),
+                                   np.asarray(every[b, n_live[b] - 1]),
+                                   rtol=2e-5, atol=2e-5, err_msg=f"lane {b}")
+    # the page past the allocator's range is the fused decode step's trash
+    # can: dead rows land there (zeros now, projections of junk before)
+    held = lambda c: jax.tree_util.tree_map(
+        lambda a: a[:, :eng.num_blocks], c)
+    for g, w in ((held(gk), held(wk)), (held(gv), held(wv))):
+        if kv_quant is None:
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=2e-5, atol=2e-5)
+        else:
+            # a code may fall either side of a rounding edge
+            assert np.abs(np.asarray(g["q"], np.int32)
+                          - np.asarray(w["q"], np.int32)).max() <= 1
+            np.testing.assert_allclose(np.asarray(g["scale"]),
+                                       np.asarray(w["scale"]), rtol=2e-5)

@@ -521,12 +521,15 @@ def _step_trees(log):
     return trees
 
 
-def test_step_counters_after_chunked_serve():
+@pytest.mark.parametrize("chunk,budget,rows", [(4, None, 8), (8, 8, 8),
+                                               (8, None, 16)])
+def test_step_counters_after_chunked_serve(chunk, budget, rows):
     """Chunked prefill, no prefix cache, no preemption: every prompt row is
     packed exactly once, and each mean over the serve is a ratio of two
     counters."""
-    eng = _engine(enable_chunked_prefill=True, prefill_chunk=4,
-                  num_blocks=24)
+    eng = _engine(enable_chunked_prefill=True, prefill_chunk=chunk,
+                  token_budget=budget, num_blocks=24)
+    assert eng._mixed_rows == rows
     reqs = _requests(3, new=5)
     fills = _drive(eng, reqs)
     st = dict(eng.stats)
@@ -540,11 +543,13 @@ def test_step_counters_after_chunked_serve():
     assert (st["kv_page_steps_in_use"] / st["kv_page_steps_total"]
             == pytest.approx(np.mean(fills), rel=1e-12))
     assert 0.0 <= st["step_host_s"] <= st["step_total_s"]
-    # a mixed step computes max_batch x prefill_chunk rows, a decode chunk
-    # max_batch x chunk, whatever is live
+    # a mixed step computes the packed rows its matmuls run (the most a
+    # step can mark live, in whole sublanes, within max_batch x
+    # prefill_chunk), a decode chunk max_batch x chunk, whatever is live
     decode_launches = len(fills) - st["mixed_steps"]
-    assert st["step_rows_computed"] == eng.max_batch * (
-        4 * st["mixed_steps"] + eng.chunk * decode_launches)
+    assert st["step_rows_computed"] == (
+        rows * st["mixed_steps"]
+        + eng.max_batch * eng.chunk * decode_launches)
 
 
 def test_prefill_rows_of_a_whole_prompt_engine():
@@ -588,10 +593,16 @@ def test_idle_poll_opens_no_span_and_no_clock(recorded):
     assert profiler.host_events_len() == buffered
 
 
-def test_step_spans_nest_in_order_with_arguments(recorded):
+@pytest.mark.parametrize("chunk,budget,rows,first", [(4, None, 8, 6),
+                                                     (8, 8, 8, 8)])
+def test_step_spans_nest_in_order_with_arguments(recorded, chunk, budget,
+                                                 rows, first):
     """One mixed step and one decode step each yield ``serving/step`` with
-    its six children in order, nested, carrying their arguments."""
-    eng = _engine(enable_chunked_prefill=True, prefill_chunk=4)
+    its six children in order, nested, carrying their arguments: a mixed
+    step's ``rows_computed`` is the packed rows, not max_batch x
+    prefill_chunk."""
+    eng = _engine(enable_chunked_prefill=True, prefill_chunk=chunk,
+                  token_budget=budget)
     _drive(eng, _requests(2, new=6))
     trees = _step_trees(recorded)
     assert [t[0]["step"] for t in trees] == list(range(1, len(trees) + 1))
@@ -604,7 +615,8 @@ def test_step_spans_nest_in_order_with_arguments(recorded):
             assert kw == {} or name == "serving/dispatch"
     mixed, decode = by_program["mixed"], by_program["decode"]
     assert mixed == {"program": "mixed", "decode_rows": 0,
-                     "prefill_rows": 6, "rows_computed": 2 * 4}
+                     "prefill_rows": first, "rows_computed": rows}
+    assert rows == eng._mixed_rows <= eng.max_batch * chunk
     assert decode == {"program": "decode", "prefill_rows": 0,
                       "decode_rows": 2 * eng.chunk,
                       "rows_computed": 2 * eng.chunk}

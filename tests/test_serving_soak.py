@@ -173,3 +173,78 @@ def test_every_admitted_request_is_answered(engines, mix, pool, seed):
     assert (prompt_rows - d["prefill_tokens_cached"]
             <= d["prefill_rows_packed"]
             <= d["prefill_tokens_computed"] + admissions)
+
+
+# ---- the row bound: the mixed program's matmuls run P packed rows ----
+
+#: token_budget -> the rows the mixed program computes (float32: sublanes
+#: of 8): under max_batch the 1-row floor keeps prompts moving and max_batch
+#: bounds the rows; 13 rounds up; None is the default, chunk + max_batch;
+#: 128 is max_batch x prefill_chunk, every row of the staging
+_ROW_BOUNDS = {4: 8, 8: 8, 13: 16, None: 24, 128: 128}
+_BOUND_CASES = [(b, mix, pool) for b in _ROW_BOUNDS
+                for mix, pool in (("chat", "tight"), ("docs", "roomy"))]
+
+
+def _bounded_engine(monkeypatch, budget, **pool):
+    monkeypatch.setenv("PADDLE_TPU_ENGINE_AUDIT", "1")
+    cfg = llama.LlamaConfig.tiny(hidden=32, heads=2, kv_heads=1, inter=64)
+    cfg.dtype = jnp.float32
+    return ContinuousBatchingEngine(
+        cfg, llama.init_params(cfg, jax.random.key(0)), **_GEOMETRY,
+        **pool, token_budget=budget)
+
+
+@pytest.mark.parametrize(
+    "budget,mix,pool", _BOUND_CASES,
+    ids=[f"budget{b}-{m}-{p}" for b, m, p in _BOUND_CASES])
+def test_no_step_packs_more_rows_than_the_program_computes(
+        monkeypatch, budget, mix, pool):
+    eng = _bounded_engine(monkeypatch, budget, **_POOLS[pool])
+    rows = _ROW_BOUNDS[budget]
+    assert eng._mixed_rows == rows
+    step, st, seen = eng.step, eng.stats, []
+
+    def checked():
+        was = (st["step_rows_live"], st["step_rows_computed"],
+               st["mixed_steps"])
+        busy = step()
+        live, computed, mixed = (st["step_rows_live"] - was[0],
+                                 st["step_rows_computed"] - was[1],
+                                 st["mixed_steps"] - was[2])
+        assert 0 <= live <= computed, (live, computed)
+        if mixed:
+            assert computed == rows, (computed, rows)
+            seen.append(live)
+        return busy
+
+    eng.step = checked
+    _, d = _soak(eng, mix, 4)
+    # the rows a step may pack: the budget, or one a decode slot and the
+    # floor's row beside them; the docs backlog fills it
+    most = min(max(budget or 24, eng.max_batch), rows)
+    assert 0 < max(seen) <= most
+    if mix == "docs":
+        assert max(seen) >= min(budget or 24, most)
+    if pool == "tight" and rows >= 16:
+        assert d["preemptions"] > 0     # under pressure all the while
+
+
+def test_an_overfull_packing_raises_and_drops_no_row(monkeypatch):
+    """``token_budget`` raised behind the engine's back: the host packs
+    more live rows than the program was built to compute.  The launch must
+    not happen — the program would compute the first P rows and lose the
+    rest in silence."""
+    from paddle_tpu.analysis.engine_audit import EngineAuditError
+
+    eng = _bounded_engine(monkeypatch, 8, num_blocks=160)
+    assert eng._mixed_rows == 8
+    eng._token_budget = 64
+    rs = np.random.RandomState(0)
+    for i in range(4):
+        eng.add_request(Request(
+            rid=i, prompt_ids=rs.randint(0, 256, 40).astype(np.int32),
+            max_new_tokens=4))
+    with pytest.raises(EngineAuditError, match="I11"):
+        eng.step()
+    assert eng.stats["mixed_steps"] == eng.stats["step_rows_computed"] == 0
