@@ -206,8 +206,7 @@ def audit_engine(eng) -> None:
             return [("q", pool["q"]), ("scale", pool["scale"])]
         return [("", pool)]
 
-    pool_k = eng.cache_k["q"] if isinstance(eng.cache_k, dict) \
-        else eng.cache_k
+    pool_k = eng._program.pages(eng.cache_k)
     phys = int(pool_k.shape[1])
     want = nb + (1 if getattr(eng, "_fused", False) else 0)
     if phys != want:

@@ -425,6 +425,76 @@ def _t_serving_tp_step() -> AnalysisTarget:
         analyze_kwargs={"min_gather_bytes": 1 << 16}, env=eng._lint_env)
 
 
+def _hybrid_engine():
+    """``models/olmo_hybrid`` (one period L L L F at lint size) behind the
+    serving engine's chunked geometry: the step programs and the cache are
+    the model's (docs/hybrid_serving.md), the scheduler the engine's."""
+    import os
+
+    import jax
+
+    from ..inference.serving import ContinuousBatchingEngine
+    from ..models import olmo_hybrid
+
+    cfg = olmo_hybrid.OlmoHybridConfig(
+        vocab_size=128, hidden_size=32, intermediate_size=64,
+        num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2,
+        linear_num_key_heads=2, linear_num_value_heads=2,
+        linear_key_head_dim=8, linear_value_head_dim=16)
+    pins = {"PADDLE_TPU_CHUNKED_PREFILL": "1",
+            "PADDLE_TPU_DISABLE_PALLAS": None, "PADDLE_TPU_TP": None}
+    prev = {k: os.environ.get(k) for k in pins}
+    try:
+        for k, v in pins.items():
+            os.environ.pop(k, None) if v is None else \
+                os.environ.__setitem__(k, v)
+        eng = ContinuousBatchingEngine(
+            cfg, olmo_hybrid.init_params(cfg, jax.random.key(0)),
+            max_batch=2, max_seq=64, paged=True, block_size=8,
+            enable_chunked_prefill=True, prefill_chunk=8)
+    finally:
+        for k, v in prev.items():
+            os.environ.pop(k, None) if v is None else \
+                os.environ.__setitem__(k, v)
+    eng._lint_env = pins
+    return eng
+
+
+def _t_serving_hybrid_decode_step() -> AnalysisTarget:
+    import jax.numpy as jnp
+
+    # one token a slot through three linear-attention layers (the
+    # one-token recurrence kernel against the stacked per-slot state, in
+    # place) and a full one (the fused rope + append + attention launch)
+    eng = _hybrid_engine()
+    B = eng.max_batch
+    zi = jnp.zeros((B,), jnp.int32)
+    return AnalysisTarget(
+        "serving_hybrid_decode_step", eng._decode_greedy,
+        (eng.params, eng.cache_k, eng.cache_v, zi,
+         jnp.asarray([5, 0], jnp.int32), jnp.asarray([True, False]),
+         jnp.zeros((B,), jnp.float32), jnp.ones((B,), jnp.float32), zi,
+         jnp.asarray(eng._table)), env=eng._lint_env)
+
+
+def _t_serving_hybrid_mixed_step() -> AnalysisTarget:
+    import jax.numpy as jnp
+
+    # slot 0 decoding beside slot 1's first chunk (its state started from
+    # zero by the program): the chunked recurrence kernel over [B, T], the
+    # page-granular K/V append and the ragged prefill kernel
+    eng = _hybrid_engine()
+    B, T = eng.max_batch, eng._prefill_chunk
+    zi = jnp.zeros((B,), jnp.int32)
+    return AnalysisTarget(
+        "serving_hybrid_mixed_step", eng._mixed_greedy,
+        (eng.params, eng.cache_k, eng.cache_v,
+         jnp.zeros((B, T), jnp.int32), jnp.asarray([5, 0], jnp.int32),
+         jnp.asarray([True, True]), jnp.asarray([1, T], jnp.int32),
+         jnp.zeros((B,), jnp.float32), jnp.ones((B,), jnp.float32), zi,
+         jnp.asarray(eng._table)), env=eng._lint_env)
+
+
 TARGETS = {
     "llama_train_step": _t_llama_train_step,
     "moe_llama_train_step": _t_moe_train_step,
@@ -438,6 +508,8 @@ TARGETS = {
     "serving_mixed_step": _t_serving_mixed_step,
     "serving_tier_restore": _t_serving_tier_restore,
     "serving_tp_step": _t_serving_tp_step,
+    "serving_hybrid_decode_step": _t_serving_hybrid_decode_step,
+    "serving_hybrid_mixed_step": _t_serving_hybrid_mixed_step,
 }
 
 # the CI gate runs every registered target; kept as an explicit list so an
@@ -448,7 +520,8 @@ GATE_TARGETS = ("llama_train_step", "moe_llama_train_step",
                 "serving_quant_decode_step", "serving_quant_scatter_step",
                 "serving_prefill_step", "serving_verify_step",
                 "serving_mixed_step", "serving_tier_restore",
-                "serving_tp_step")
+                "serving_tp_step", "serving_hybrid_decode_step",
+                "serving_hybrid_mixed_step")
 
 # targets that serve from the async host runtime: these additionally run
 # the module-scoped host-contract pass (host_contracts.py) — overlap-window

@@ -352,6 +352,35 @@ ENGINE_STAT_SCHEMA = {
                     "fetch: admission, packing, operand staging and "
                     "dispatch, token banking (_host_overlap() runs while "
                     "the device works and is not host time)"),
+    # linear-attention layers (a step program with per-slot recurrent
+    # state, docs/hybrid_serving.md; the dense program counts none of it)
+    "gdn_rows_computed": ("counter",
+                          "Rows a layer's recurrence kernel was given, "
+                          "live or dead: max_batch x prefill_chunk a "
+                          "mixed step, max_batch x chunk a decode step"),
+    "gdn_rows_live": ("counter",
+                      "Rows of those that carried a token (over "
+                      "gdn_rows_computed = the recurrence's live share)"),
+    "state_slot_steps_live": ("counter",
+                              "Slots whose recurrent state a launch read "
+                              "and wrote, summed over launches"),
+    "gdn_chunk_rows_live": ("counter",
+                            "The mixed steps' part of gdn_rows_live: rows "
+                            "the chunked recurrence kernel was given that "
+                            "carried a token (the decode kernel's part is "
+                            "the rest)"),
+    "state_chunk_slot_steps_live": ("counter",
+                                    "The mixed steps' part of "
+                                    "state_slot_steps_live"),
+    "state_starts": ("counter",
+                     "Recurrent states the step programs started from "
+                     "zero: a live lane whose first row sat at position 0 "
+                     "(admission, a preempted request's re-prefill, "
+                     "journal replay)"),
+    "state_bytes": ("gauge",
+                    "Device bytes of per-slot state outside the paged "
+                    "pool: every linear layer's recurrent state and conv "
+                    "window for max_batch slots (0: a dense model)"),
 }
 
 #: fleet router ``stats`` keys -> (metric kind, help); same contract.

@@ -1,5 +1,16 @@
 """Continuous-batching decode scheduler (VERDICT r2 #6).
 
+The scheduler, the page allocator and the degradation ladder below serve
+whatever model the config brings: the compiled steps take their layer
+stack and their cache from a *step program* (``engine._program``;
+docs/hybrid_serving.md).  A config with no ``serving_program`` gets the
+dense GQA decoder of ``models/llama`` (``_DenseProgram``: this file's
+``_decode_one`` / ``_mixed_one`` over ``inference.transformer_apply``, a
+paged K/V pool), which everything from "TPU-first design" on describes; a
+config that has one (``models/olmo_hybrid``: linear-attention layers with a
+per-slot recurrent state beside the pool) brings its own, and the options
+its state cannot honour are refused at construction.
+
 Reference analog: the serving stack behind the reference's fused block
 attention family — `paddle/phi/ops/yaml/fused_ops.yaml:45`
 (``block_multihead_attention_``) and `:394` (``fused_multi_transformer_``) —
@@ -309,11 +320,82 @@ class _TPShardView:
         return getattr(self._cfg, name)
 
 
-class ContinuousBatchingEngine:
-    """Slot-pool continuous batching over a Llama-family model.
+@dataclass(frozen=True)
+class StepGeometry:
+    """What a step program is built for: the engine's static shapes."""
+    max_batch: int
+    max_seq: int
+    block_size: int
+    num_blocks: int         # pages the allocator hands out
+    pool_pages: int         # pages a layer's pool holds (+1: the spill page)
+    mixed_rows: int         # packed rows the mixed step's matmuls run
+    fused: bool             # rope + append + attention as one decode launch
 
-    ``cfg``/``params`` follow paddle_tpu.models.llama conventions (the same
-    pytree the AOT GenerationEngine uses, inference/__init__.py:249).
+
+class _DenseProgram:
+    """The step program of a config that brings none: the dense GQA decoder
+    (``models/llama``), whose only state is a K/V row a position.  The
+    contract every step program keeps (docs/hybrid_serving.md):
+    ``init_cache() -> (cache_k, cache_v)``, the pair every compiled step
+    carries and donates; ``decode_one`` / ``mixed_one``, the forward passes
+    the decode and mixed steps sample from; ``pages(cache)``, the paged
+    pool inside one of the pair; ``model_id()``, what a journal's restore
+    target has to agree on; ``state_bytes()`` and
+    ``launch_counters(launch)``: the per-slot state outside the pool, and
+    what a launch adds to ``engine.stats`` beyond the engine's own
+    counters."""
+
+    def __init__(self, engine, cache_shape):
+        self._eng = engine
+        self._shape = cache_shape
+        self.decode_one = engine._decode_one
+        self.mixed_one = engine._mixed_one
+
+    def init_cache(self):
+        eng, shape = self._eng, self._shape
+        if eng.paged and eng.kv_quant is not None:
+            # quantized pools: int8 codes + per-(page, head) f32 scales as
+            # ONE pytree per pool — compiled steps, donation, the COW
+            # copy and TP sharding all treat the pair as the cache
+            # operand, so the scheduler/allocator plumbing is untouched
+            return ({"q": jnp.zeros(shape, jnp.int8),
+                     "scale": jnp.zeros(shape[:3], jnp.float32)},
+                    {"q": jnp.zeros(shape, jnp.int8),
+                     "scale": jnp.zeros(shape[:3], jnp.float32)})
+        return (jnp.zeros(shape, eng.cfg.dtype),
+                jnp.zeros(shape, eng.cfg.dtype))
+
+    def pages(self, cache):
+        return cache["q"] if isinstance(cache, dict) else cache
+
+    def model_id(self) -> str:
+        # every field that changes the teacher-forced recompute's logits
+        # belongs in the id — shapes alone would let a rope_theta or dtype
+        # mismatch resume silently wrong
+        cfg = self._eng.cfg
+        return (f"llama:v{cfg.vocab_size}:h{cfg.hidden_size}"
+                f":L{cfg.num_hidden_layers}"
+                f":nh{cfg.num_attention_heads}"
+                f":nkv{cfg.num_key_value_heads}"
+                f":i{cfg.intermediate_size}"
+                f":tie{int(bool(cfg.tie_word_embeddings))}"
+                f":dt{jnp.dtype(cfg.dtype).name}"
+                f":rope{cfg.rope_theta:g}"
+                f":eps{cfg.rms_norm_eps:g}")
+
+    def state_bytes(self) -> int:
+        return 0
+
+    def launch_counters(self, launch: dict) -> dict:
+        return {}
+
+
+class ContinuousBatchingEngine:
+    """Slot-pool continuous batching over a decoder model: ``models/llama``
+    conventions for ``cfg``/``params`` (the same pytree the AOT
+    GenerationEngine uses, inference/__init__.py:249) or, where the config
+    brings a ``serving_program`` (``models/olmo_hybrid``), that model's own
+    layers and cache (docs/hybrid_serving.md).
     """
 
     def __init__(self, cfg, params, max_batch: int = 8, max_seq: int = 512,
@@ -327,7 +409,14 @@ class ContinuousBatchingEngine:
                  max_queue: int | None = None, tensor_parallel: int = 1,
                  enable_host_kv_tier: bool = False, host_tier=None,
                  metrics=None, metrics_labels: dict | None = None):
-        """``chunk``: decode steps per compiled call.  Tokens feed back
+        """``cfg`` / ``params``: a ``models/llama`` config and its pytree
+        (the dense GQA decoder: every option below applies), or a config
+        that brings its own step program and cache through
+        ``cfg.serving_program`` (``models/olmo_hybrid``: per-slot recurrent
+        state beside the paged pool) — such a config says through
+        ``cfg.check_serving_options`` which of the options below its state
+        cannot honour, and those raise here (docs/hybrid_serving.md).
+        ``chunk``: decode steps per compiled call.  Tokens feed back
         on-device inside a lax.scan and the host fetches ``chunk`` tokens per
         round-trip — the lever against host-device latency (one round trip
         per token is what bounds single-step decode).  Retire
@@ -494,6 +583,26 @@ class ContinuousBatchingEngine:
                 else:
                     raise ValueError("; ".join(problems))
         self.tp = tp
+        # a model that brings its own step program says which of the
+        # options above its state cannot honour (docs/hybrid_serving.md):
+        # refused here, as resolved (env kill switches included), before
+        # anything is built
+        check = getattr(cfg, "check_serving_options", None)
+        if check is not None:
+            from ..utils.envflags import env_bool
+
+            on = lambda asked, flag: bool(asked) and env_bool(flag, True)
+            check(paged=paged, chunk=self.chunk, kv_quant=kv_quant,
+                  tensor_parallel=tp,
+                  enable_prefix_caching=on(enable_prefix_caching,
+                                           "PADDLE_TPU_PREFIX_CACHE"),
+                  enable_speculation=on(enable_speculation,
+                                        "PADDLE_TPU_SPECULATE"),
+                  enable_host_kv_tier=on(
+                      enable_host_kv_tier or host_tier is not None,
+                      "PADDLE_TPU_HOST_KV_TIER"),
+                  enable_chunked_prefill=on(enable_chunked_prefill,
+                                            "PADDLE_TPU_CHUNKED_PREFILL"))
         self._tp_axis = None
         self._mesh = None
         self._body_cfg = cfg       # the cfg the compiled-step bodies read
@@ -610,23 +719,6 @@ class ContinuousBatchingEngine:
             self._slot_age = np.zeros(max_batch, np.int64)
         else:
             shape = (L, max_batch, nkv, max_seq, hd)
-        if self.paged and self.kv_quant is not None:
-            # quantized pools: int8 codes + per-(page, head) f32 scales as
-            # ONE pytree per pool — compiled steps, donation, the COW
-            # copy and TP sharding all treat the pair as the cache
-            # operand, so the scheduler/allocator plumbing is untouched
-            self.cache_k = {"q": jnp.zeros(shape, jnp.int8),
-                            "scale": jnp.zeros(shape[:3], jnp.float32)}
-            self.cache_v = {"q": jnp.zeros(shape, jnp.int8),
-                            "scale": jnp.zeros(shape[:3], jnp.float32)}
-        else:
-            self.cache_k = jnp.zeros(shape, cfg.dtype)
-            self.cache_v = jnp.zeros(shape, cfg.dtype)
-        if self.tp > 1:
-            # the pool lives sharded from birth; donation keeps it sharded
-            # through every step, so no per-step resharding ever happens
-            self.cache_k = jax.device_put(self.cache_k, self._cache_sharding)
-            self.cache_v = jax.device_put(self.cache_v, self._cache_sharding)
         # automatic prefix cache (content-addressed KV block reuse).  The
         # cache-off path must stay byte-identical to the plain paged engine,
         # so EVERY cache behavior hangs off self._pcache being non-None.
@@ -847,6 +939,25 @@ class ContinuousBatchingEngine:
                 self._mixed_impl_paged, n_rep=2, sampling=False)
             self._mixed_sampling = self._jit_step(
                 self._mixed_impl_paged, n_rep=2, sampling=True)
+        # ---- the step program and its cache (docs/hybrid_serving.md) ----
+        # the compiled steps above take their layers (decode_one /
+        # mixed_one) and the cache pair they carry from the model's
+        # program; a config that brings none gets the dense decoder's
+        build = getattr(cfg, "serving_program", None)
+        if build is None:
+            self._program = _DenseProgram(self, shape)
+        else:
+            self._program = build(StepGeometry(
+                max_batch=max_batch, max_seq=max_seq,
+                block_size=self.block_size, num_blocks=self.num_blocks,
+                pool_pages=nbp, mixed_rows=self._mixed_rows,
+                fused=self._fused))
+        self.cache_k, self.cache_v = self._program.init_cache()
+        if self.tp > 1:
+            # the pool lives sharded from birth; donation keeps it sharded
+            # through every step, so no per-step resharding ever happens
+            self.cache_k = jax.device_put(self.cache_k, self._cache_sharding)
+            self.cache_v = jax.device_put(self.cache_v, self._cache_sharding)
         # ---- observability (ISSUE 11, docs/observability.md) ----
         # stats live on a typed MetricsRegistry behind a dict-compatible
         # view (keys + help strings: observability.ENGINE_STAT_SCHEMA), so
@@ -865,6 +976,7 @@ class ContinuousBatchingEngine:
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.stats = StatsView(self.metrics, ENGINE_STAT_SCHEMA,
                                self._obs_labels)
+        self.stats["state_bytes"] = self._program.state_bytes()
         self.slo = SLOTracker(self.metrics, self._obs_labels)
         self._h_hostgap = self.metrics.histogram(
             "paddle_tpu_serving_host_gap_seconds",
@@ -1277,8 +1389,8 @@ class ContinuousBatchingEngine:
 
         def one(carry, _):
             ck, cv, tok, p = carry
-            logits, ck, cv = self._decode_one(params, ck, cv, tok, p, active,
-                                              table)
+            logits, ck, cv = self._program.decode_one(params, ck, cv, tok, p,
+                                                      active, table)
             logits, bad = self._guard_logits(logits, active, poison)
             if sampling:
                 nxt = self._sample_tokens(logits, p, temp, topp, seeds)
@@ -1704,8 +1816,8 @@ class ContinuousBatchingEngine:
         sampled.  Returns (next token [B], bad [B] guard flags, caches);
         the host consumes a lane's token only when it decoded or finished
         its prompt."""
-        logits, ck, cv = self._mixed_one(params, cache_k, cache_v, tokens,
-                                         pos, active, q_lens, table)
+        logits, ck, cv = self._program.mixed_one(
+            params, cache_k, cache_v, tokens, pos, active, q_lens, table)
         # the emit row is each slot's ONLY row through the lm_head: a
         # non-finite emit (numerical blowup or the nan_logits poison
         # bit) flags the slot; the host quarantines the request instead
@@ -2695,20 +2807,8 @@ class ContinuousBatchingEngine:
         is never captured, so teacher-forced recompute makes a
         cross-degree restore token-identical by construction
         (docs/tp_serving.md)."""
-        cfg = self.cfg
-        # every field that changes the teacher-forced recompute's logits
-        # belongs in the id — shapes alone would let a rope_theta or dtype
-        # mismatch resume silently wrong
         return {
-            "model": (f"llama:v{cfg.vocab_size}:h{cfg.hidden_size}"
-                      f":L{cfg.num_hidden_layers}"
-                      f":nh{cfg.num_attention_heads}"
-                      f":nkv{cfg.num_key_value_heads}"
-                      f":i{cfg.intermediate_size}"
-                      f":tie{int(bool(cfg.tie_word_embeddings))}"
-                      f":dt{jnp.dtype(cfg.dtype).name}"
-                      f":rope{cfg.rope_theta:g}"
-                      f":eps{cfg.rms_norm_eps:g}"),
+            "model": self._program.model_id(),
             "quant": self.quant,
             # pool storage changes the teacher-forced logits (requantized
             # appends are lossy), so a kv_quant mismatch must raise; old
@@ -2965,14 +3065,19 @@ class ContinuousBatchingEngine:
         self._last_step_end = end
 
     def _count_launch(self, rows_computed: int, rows_live: int,
-                      slots_seated: int, prefill_rows: int = 0):
+                      slots_seated: int, prefill_rows: int = 0,
+                      of_program: dict | None = None):
         """Called once by each launch path (mixed, decode, verify) when its
         step has banked: what the program computed against what was live
         and the slots seated at the launch, with the numbers packing had
-        in hand; the pool as banking leaves it.  Plain counters, so a mean
-        over any window is a ratio of two deltas (docs/observability.md
-        "Step accounting")."""
+        in hand; the pool as banking leaves it.  ``of_program`` is what
+        the step program's own ``launch_counters`` made of the launch (the
+        dense program counts nothing of its own).  Plain
+        counters, so a mean over any window is a ratio of two deltas
+        (docs/observability.md "Step accounting")."""
         st = self.stats
+        for key, n in (of_program or {}).items():
+            st[key] += n
         st["step_rows_computed"] += rows_computed
         st["step_rows_live"] += rows_live
         st["prefill_rows_packed"] += prefill_rows
@@ -3106,9 +3211,15 @@ class ContinuousBatchingEngine:
         if not active_np.any():
             return False
         n_live = int(active_np.sum())
+        # the [B, k] lanes staged, those that carry a row; no slot starts
+        # in a decode step (a prompt's first row enters through a chunk)
+        of_program = self._program.launch_counters(
+            {"mixed": False, "lanes": self.max_batch * k,
+             "rows_live": n_live * k, "lanes_live": n_live, "starts": 0})
         self._phase("serving/dispatch", program="decode",
                     decode_rows=n_live * k, prefill_rows=0,
-                    rows_computed=self.max_batch * k)
+                    rows_computed=self.max_batch * k,
+                    linear_rows=of_program.get("gdn_rows_computed", 0))
         t0 = time.perf_counter()
         self._note_launch(t0)
         extra = (jnp.asarray(self._table),) if self.paged else ()
@@ -3203,7 +3314,8 @@ class ContinuousBatchingEngine:
             self._jmark(req.rid)   # token bank advanced the journal entry
             if done or old_pos + k >= self.max_seq:
                 self._retire(slot)
-        self._count_launch(self.max_batch * k, n_live * k, n_live)
+        self._count_launch(self.max_batch * k, n_live * k, n_live,
+                           of_program=of_program)
         self._maybe_audit()
         return True
 
@@ -3329,9 +3441,17 @@ class ContinuousBatchingEngine:
             raise EngineAuditError(
                 f"engine audit I11 violated: mixed step staged {staged} "
                 f"live rows, the program computes {P}")
+        # the [B, T] lanes staged, the slots that carry a row, and those
+        # whose first row sits at position 0
+        of_program = self._program.launch_counters(
+            {"mixed": True, "lanes": B * T,
+             "rows_live": n_decode + prefill_rows,
+             "lanes_live": int(active.sum()),
+             "starts": int((active & (pos == 0)).sum())})
         self._phase("serving/dispatch", program="mixed",
                     decode_rows=n_decode, prefill_rows=prefill_rows,
-                    rows_computed=P)
+                    rows_computed=P,
+                    linear_rows=of_program.get("gdn_rows_computed", 0))
         t0 = time.perf_counter()
         self._note_launch(t0)
         # step-packing summary: O(1) per step, the flight recorder's
@@ -3432,7 +3552,7 @@ class ContinuousBatchingEngine:
                         and new_cur >= self.max_seq):
                     self._retire(s)
         self._count_launch(P, n_decode + prefill_rows, n_seated,
-                           prefill_rows)
+                           prefill_rows, of_program)
         self._maybe_audit()
         return True
 

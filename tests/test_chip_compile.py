@@ -29,6 +29,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import gated_delta as gd
 from paddle_tpu.ops.pallas import paged_attention as pa
 from paddle_tpu.ops.pallas import rms_norm as rn
 
@@ -71,7 +72,7 @@ def compiled_kernels(monkeypatch):
     """Kernels lower for the chip (not the interpreter) inside the test, at
     the matmul precision the program runs with (conftest.py raises it to
     'highest' for the numpy oracles; Mosaic refuses that on bf16 operands)."""
-    for mod in (pa, fa, rn):
+    for mod in (pa, fa, rn, gd):
         monkeypatch.setattr(mod, "interpret_mode", lambda: False)
     prev = jax.config.jax_default_matmul_precision
     jax.config.update("jax_default_matmul_precision", None)
@@ -239,3 +240,37 @@ def test_flash_attention_seq8192_laguna_widths(one_chip, compiled_kernels,
              "flash_attn_win_bwd_dq")
     assert all((n in compiled.as_text()) == (window is not None)
                for n in names)
+
+
+# Olmo-Hybrid-7B's linear layers at the cell ``olmo-hybrid-chat``'s shapes:
+# 30 heads of d_k 96 / d_v 192, 32 slots, the state of 12 layers stacked
+GDN = dict(B=32, H=30, DK=96, DV=192, L=12)
+
+
+def test_gdn_decode_step(one_chip, compiled_kernels):
+    """One token a slot against the stacked float32 state, in place."""
+    B, H, DK, DV, L = GDN.values()
+    F32 = jnp.float32
+    fn = lambda q, k, v, a, b, s, f, l: gd.gdn_decode_step(
+        q, k, v, a, b, s, f, l)
+    compiled = _compile(fn, one_chip, ((B, H, DK), F32), ((B, H, DK), F32),
+                        ((B, H, DV), F32), ((B, H), F32), ((B, H), F32),
+                        ((L, B, H, DK, DV), F32), ((B,), jnp.bool_),
+                        ((), jnp.int32))
+    assert "gdn_decode_step" in compiled.as_text()
+
+
+@pytest.mark.parametrize("T", [128, 100], ids=["chunk128", "ragged100"])
+def test_gdn_chunk_prefill(one_chip, compiled_kernels, T):
+    """A mixed step's [B, T] rows in sub-chunks of 64 from the stacked
+    state (T = 100: the pad to whole sub-chunks)."""
+    B, H, DK, DV, L = GDN.values()
+    F32 = jnp.float32
+    fn = lambda q, k, v, g, b, s, ok, f, l: gd.gdn_chunk_prefill(
+        q, k, v, g, b, s, ok, f, l)
+    compiled = _compile(fn, one_chip, ((B, T, H, DK), F32),
+                        ((B, T, H, DK), F32), ((B, T, H, DV), F32),
+                        ((B, T, H), F32), ((B, T, H), F32),
+                        ((L, B, H, DK, DV), F32), ((B, T), jnp.bool_),
+                        ((B,), jnp.bool_), ((), jnp.int32))
+    assert "gdn_chunk_prefill" in compiled.as_text()

@@ -463,7 +463,8 @@ def test_every_pallas_kernel_carries_its_own_name():
                     f"{path}:{node.lineno}: pallas_call without a literal "
                     f"name=")
                 spelled += [n.value for n in names]
-    assert len(spelled) == len(set(spelled)) == 14
+    assert len(spelled) == len(set(spelled)) == 16
+    assert {"gdn_chunk_prefill", "gdn_decode_step"} <= set(spelled)
     assert all(n.isidentifier() and n == n.lower() for n in spelled)
 
     programs = []

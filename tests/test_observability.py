@@ -614,12 +614,14 @@ def test_step_spans_nest_in_order_with_arguments(recorded, chunk, budget,
         for name, kw in children:
             assert kw == {} or name == "serving/dispatch"
     mixed, decode = by_program["mixed"], by_program["decode"]
+    # linear_rows: the rows a recurrence walks; a dense model has none
     assert mixed == {"program": "mixed", "decode_rows": 0,
-                     "prefill_rows": first, "rows_computed": rows}
+                     "prefill_rows": first, "rows_computed": rows,
+                     "linear_rows": 0}
     assert rows == eng._mixed_rows <= eng.max_batch * chunk
     assert decode == {"program": "decode", "prefill_rows": 0,
                       "decode_rows": 2 * eng.chunk,
-                      "rows_computed": 2 * eng.chunk}
+                      "rows_computed": 2 * eng.chunk, "linear_rows": 0}
     # the dispatch arguments are the counters' increments
     assert sum(dict(c)["serving/dispatch"]["rows_computed"]
                for _, c in trees) == eng.stats["step_rows_computed"]

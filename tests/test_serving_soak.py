@@ -175,6 +175,41 @@ def test_every_admitted_request_is_answered(engines, mix, pool, seed):
             <= d["prefill_tokens_computed"] + admissions)
 
 
+# ---- a model that brings its own step programs and per-slot state ----
+
+@pytest.fixture(scope="module")
+def hybrid_engine():
+    """``models/olmo_hybrid`` (one period: three linear-attention layers
+    and a full one) behind the tight pool: streams are preempted and
+    re-prefilled, slots are reused, and a slot's recurrent state has to
+    start from zero every time (docs/hybrid_serving.md)."""
+    from paddle_tpu.models import olmo_hybrid
+
+    mp = pytest.MonkeyPatch()
+    mp.setenv("PADDLE_TPU_ENGINE_AUDIT", "1")
+    cfg = olmo_hybrid.OlmoHybridConfig(
+        vocab_size=256, hidden_size=32, intermediate_size=64,
+        num_hidden_layers=4, num_attention_heads=2, linear_num_key_heads=2,
+        linear_num_value_heads=2, linear_key_head_dim=8,
+        linear_value_head_dim=16, dtype=jnp.float32)
+    eng = ContinuousBatchingEngine(
+        cfg, olmo_hybrid.init_params(cfg, jax.random.key(0), std=0.2),
+        **_GEOMETRY, **_POOLS["tight"])
+    assert eng._audit_every_step and eng._program.state_bytes() > 0
+    yield eng
+    mp.undo()
+
+
+@pytest.mark.parametrize("mix", ["chat", "docs"])
+def test_every_admitted_request_is_answered_by_a_hybrid(hybrid_engine, mix):
+    reqs, d = _soak(hybrid_engine, mix, 5)
+    assert d["preemptions"] > 0
+    # every request started a state, and again when it came back from a
+    # preemption that had let it pack a first row
+    assert len(reqs) <= d["state_starts"] <= len(reqs) + d["preemptions"]
+    assert 0 < d["gdn_rows_live"] < d["gdn_rows_computed"]
+
+
 # ---- the row bound: the mixed program's matmuls run P packed rows ----
 
 #: token_budget -> the rows the mixed program computes (float32: sublanes
