@@ -352,6 +352,18 @@ ENGINE_STAT_SCHEMA = {
                     "fetch: admission, packing, operand staging and "
                     "dispatch, token banking (_host_overlap() runs while "
                     "the device works and is not host time)"),
+    "attn_row_pages_live": ("counter",
+                            "Mixed steps' attention, one layer's launch a "
+                            "step: (row, KV page) pairs in which a live "
+                            "row sees the page (prefill_census of the "
+                            "staged q_lens and lengths)"),
+    "attn_row_pages_computed": ("counter",
+                                "(row, KV page) pairs the ragged prefill "
+                                "kernel multiplied in those launches: "
+                                "token rows of the sub-tiles it worked x "
+                                "the pages each was worked at (over this, "
+                                "attn_row_pages_live = the kernel's live "
+                                "share)"),
     # linear-attention layers (a step program with per-slot recurrent
     # state, docs/hybrid_serving.md; the dense program counts none of it)
     "gdn_rows_computed": ("counter",

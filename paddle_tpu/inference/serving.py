@@ -3474,6 +3474,8 @@ class ContinuousBatchingEngine:
                 poison=jnp.asarray(self._poison))
             self._phase("serving/host_overlap")
             self._host_overlap()   # journal upkeep rides the launch
+            # and so does the count of what its attention kernel works
+            of_program.update(self._mixed_attn_census(q_lens, pos, active))
             self._phase("serving/fetch")
             bad_np = np.asarray(bad)    # [B] emit-row guard flags
         except FaultInjected as e:
@@ -3555,6 +3557,29 @@ class ContinuousBatchingEngine:
                            prefill_rows, of_program)
         self._maybe_audit()
         return True
+
+    def _mixed_attn_census(self, q_lens, pos, active) -> dict:
+        """What the mixed step's attention kernel worked this launch, from
+        the operands the step staged (``prefill_census``; a count of one
+        layer's launch, the same for every layer): row-pages that carried
+        a token against the row-pages of the sub-tiles the kernel
+        multiplied."""
+        from ..ops.pallas.paged_attention import prefill_census
+
+        cfg = self._body_cfg
+        # what mixed_one hands the kernel as seq_lens: an inactive lane
+        # attends one stale position
+        seq_base = np.where(active & (pos < self.max_seq), pos, 0)
+        seq_now = np.minimum(seq_base + np.where(active, q_lens, 1),
+                             self.max_seq)
+        census = prefill_census(
+            q_lens, seq_now, self._prefill_chunk,
+            cfg.num_attention_heads // cfg.num_key_value_heads,
+            self.block_size, max_blocks=self._table.shape[1],
+            nkv=cfg.num_key_value_heads, hd=cfg.head_dim, dtype=cfg.dtype,
+            kv_quant=self.kv_quant, live=active)
+        return {"attn_row_pages_live": census["row_pages_live"],
+                "attn_row_pages_computed": census["row_pages_computed"]}
 
     def _consume_token(self, slot: int, req: Request, tok: int, t0: float):
         """Bank one generated token on a slot (mixed-step emit): append,

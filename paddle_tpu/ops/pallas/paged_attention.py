@@ -61,6 +61,13 @@ plus the chunk's own tokens up to and including itself (the causal in-chunk
 mask), never the later rows.  Unlike verify it also carries the decode
 kernel's dequant-on-read for int8 / packed-int4 KV pages (a KV-quantized
 pool must be prefillable through the same kernel family that decodes it).
+Its work follows what a lane carries (docs/chunked_prefill.md "What the
+kernel skips"): a grid step holds one page with as many of its KV heads as
+VMEM holds — grid ``(slots, kv_heads / heads_per_step, logical_pages)`` —
+and multiplies only the aligned sub-tiles of the lane's rows that
+``q_lens[b]`` says are live, at the pages those rows can see; a lane with
+``q_lens[b] == 0`` works no page and leaves zeros.
+:func:`prefill_census` counts the same from the host's numbers.
 Separate KERNEL/FALLBACK counters; decode and verify stay byte-untouched.
 
 Tensor-parallel serving (docs/tp_serving.md) needs NO kernel variant: the
@@ -145,11 +152,15 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
 from . import interpret_mode, kernel_disabled
+# the tile-body idiom of the flash kernels (PR 29): products in the operands'
+# own dtype, row statistics kept 128 lanes wide
+from .flash_attention import _LANES, _dot, _lanes
 
 _VMEM = pltpu.VMEM
 
@@ -1166,114 +1177,319 @@ def paged_attention_verify(q, key_cache, value_cache, block_tables, seq_lens,
 # ragged chunked prefill (stall-free continuous batching)
 # ---------------------------------------------------------------------------
 
+#: what the chip's compiler lets one kernel hold in VMEM (v5e: 16 MiB
+#: scoped) and the part of it the prefill kernel's blocks and scratch may
+#: take — the rest is the tile bodies' own intermediates (s, p, selects)
+_VMEM_LIMIT = 16 * 1024 * 1024
+_PREFILL_VMEM_BUDGET = 12 * 1024 * 1024
+#: rows of a chunk lane's sub-tile: one MXU pass of q rows against a page
+_PREFILL_SUB_ROWS = 128
+
+
+def _prefill_tiles(T: int, rep: int, dtype) -> tuple[int, int, int]:
+    """(R, head_rows, sub_rows) of a launch whose lanes carry up to ``T``
+    rows of ``rep`` grouped heads in ``dtype``: the q tile's padded rows,
+    the FIRST sub-tile a lane of few rows works alone (``rep`` rows in
+    whole sublanes of the dtype — a decode lane's one token), and the
+    aligned sub-tiles a longer lane is worked in.  ``R`` is a whole number
+    of either."""
+    sublanes = 32 // jnp.dtype(dtype).itemsize
+    head_rows = _round_up(rep, sublanes)
+    R = _round_up(T * rep, head_rows)
+    if R <= _PREFILL_SUB_ROWS:
+        return R, head_rows, R
+    sub_rows = _round_up(_PREFILL_SUB_ROWS, head_rows)
+    return _round_up(R, sub_rows), head_rows, sub_rows
+
+
+def _prefill_vmem_bytes(heads: int, R: int, hd: int, bs: int, hd_store: int,
+                        q_dtype, kv_dtype) -> int:
+    """What ``heads`` KV heads a grid step hold in VMEM: q and the output
+    block double-buffered, the float32 accumulator, m and l at 128 lanes,
+    and the K and the V page double-buffered."""
+    qb = jnp.dtype(q_dtype).itemsize
+    per_head = (R * hd * (2 * qb + 2 * qb + 4) + 2 * R * _LANES * 4
+                + 2 * 2 * bs * hd_store * jnp.dtype(kv_dtype).itemsize)
+    return heads * per_head
+
+
+def _prefill_heads_per_step(nkv: int, R: int, hd: int, bs: int,
+                            hd_store: int, q_dtype, kv_dtype) -> int:
+    """KV heads of a page one grid step takes: the largest divisor of
+    ``nkv`` whose blocks and scratch fit the budget (a page with all its
+    heads is one contiguous block of the pool)."""
+    for heads in range(nkv, 1, -1):
+        if nkv % heads == 0 and _prefill_vmem_bytes(
+                heads, R, hd, bs, hd_store, q_dtype,
+                kv_dtype) <= _PREFILL_VMEM_BUDGET:
+            return heads
+    return 1
+
+
+def _sub_tile_sees(i, sub, qlen, length, rep, maximum):
+    """KV positions the LAST live row of sub-tile ``i`` (rows
+    ``[i*sub, (i+1)*sub)`` of a lane's tile) sees: row ``t`` sees
+    ``length - (qlen-1-t)``, so a page ``j`` with ``j*bs`` at or past this
+    holds nothing any row of the sub-tile may read.  One expression for
+    the kernel (traced scalars, ``jnp.maximum``) and for
+    :func:`prefill_census` (numpy)."""
+    return length - maximum(qlen - 1 - ((i + 1) * sub - 1) // rep, 0)
+
+
+def prefill_census(q_lens, seq_lens, T: int, rep: int, bs: int, *,
+                   max_blocks: int, nkv: int, hd: int, dtype,
+                   kv_quant: str | None = None, live=None) -> dict:
+    """What one launch of the prefill kernel works, from the host's
+    numbers (the like of ``flash_attention.tile_census``): counted by the
+    predicates the kernel branches on, so here and not at run time.
+
+    ``q_lens`` / ``seq_lens`` are the kernel's own operands (numpy, [b]);
+    ``live`` [b] bool marks the lanes whose rows carry a token (an
+    inactive lane of the mixed step is given ``q_lens == 1`` over one
+    stale position: the kernel works it, nothing reads it).  Returns
+    ``row_pages_live`` — sum over the live lanes' rows of the pages a row
+    sees (row t: ``cdiv(seq_lens - (q_lens-1-t), bs)``), the least any
+    kernel of this layout works; ``row_pages_computed`` — sum over lanes
+    of the rows of the sub-tiles worked, in token rows, x the pages each
+    is worked at; and ``grid_steps``."""
+    q_lens = np.asarray(q_lens, np.int64)
+    seq_lens = np.asarray(seq_lens, np.int64)
+    live = (np.ones(q_lens.shape, bool) if live is None
+            else np.asarray(live, bool))
+    R, head_rows, sub_rows = _prefill_tiles(T, rep, dtype)
+    heads = _prefill_heads_per_step(
+        nkv, R, hd, bs, hd // 2 if kv_quant == "int4" else hd, dtype,
+        jnp.int8 if kv_quant else dtype)
+
+    def pages(visible):
+        return np.clip(-(-visible // bs), 0, max_blocks)
+
+    runs = q_lens > 0
+    short = runs & (q_lens * rep <= head_rows)
+    computed = np.where(short, -(-head_rows // rep) * pages(seq_lens), 0)
+    for i in range(R // sub_rows):
+        worked = runs & ~short & (i * sub_rows < q_lens * rep)
+        computed += np.where(
+            worked, -(-sub_rows // rep) * pages(_sub_tile_sees(
+                i, sub_rows, q_lens, seq_lens, rep, np.maximum)), 0)
+
+    def pages_upto(n):
+        # sum of cdiv(v, bs) over v = 1..n
+        k, r = np.divmod(np.maximum(n, 0), bs)
+        return bs * k * (k + 1) // 2 + r * (k + 1)
+
+    # a live row sees the pages up to its own position: rows' visibilities
+    # are the consecutive integers seq_lens - q_lens + 1 .. seq_lens
+    seen = pages_upto(seq_lens) - pages_upto(seq_lens - q_lens)
+    return {
+        "row_pages_live": int(np.where(live & runs, seen, 0).sum()),
+        "row_pages_computed": int(computed.sum()),
+        "grid_steps": int(q_lens.size * (nkv // heads) * max_blocks),
+    }
+
+
 def _prefill_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
-                    *rest, scale, bs, rep, kv_quant):
-    """Grid: (slots, kv_heads, logical_pages) — identical page walk to
-    :func:`_paged_kernel`/:func:`_verify_kernel`.  The q tile carries
-    ``R = pad(T * rep)`` rows (row ``t*rep + g`` = chunk row t, grouped head
-    g) under the verify kernel's per-row causal law — row t sees
-    ``lens[b] - (qlens[b]-1-t)`` KV positions, i.e. the already-written
-    prefix plus the chunk's own tokens through itself — and, unlike verify,
-    the decode kernel's dequant-on-read so a quantized KV pool prefills
-    through the same page stream that decodes it.  Scalar-prefetch refs:
+                    *rest, scale, bs, rep, kv_quant, head_rows, sub_rows):
+    """Grid: (slots, kv_heads / heads_per_step, logical_pages), pages
+    innermost (sequential).  One grid step holds one physical page with
+    ``heads_per_step`` of its KV heads (a contiguous block of the pool)
+    and the lane's q tile for those heads, ``R = pad(T * rep)`` rows (row
+    ``t*rep + g`` = chunk row t, grouped head g), under the verify
+    kernel's per-row causal law — row t sees ``lens[b] - (qlens[b]-1-t)``
+    KV positions — with the decode kernel's dequant-on-read.
+
+    What a step WORKS follows what the lane carries, read from the
+    prefetched scalars.  A lane's live rows are a prefix of its tile, so:
+    a lane of at most ``head_rows`` rows (a decode lane's one token: its
+    ``rep`` grouped heads in whole sublanes) works the tile's first
+    ``head_rows`` rows and nothing else; a longer lane is worked in
+    aligned sub-tiles of ``sub_rows`` rows, ``cdiv(qlens[b]*rep,
+    sub_rows)`` of them, and a sub-tile skips a page that lies wholly
+    past its last row's visibility (a chunk is causal by sub-tile, not
+    only by mask); a lane with ``qlens[b] <= 0`` works no page whatever
+    ``lens[b]`` says.  Rows nothing worked — past ``q_lens`` — leave as
+    zeros, masked rows of a worked sub-tile too.  Scalar-prefetch refs:
     tables [b, max_blocks], lens [b] (TOTAL written length incl. this
-    chunk), qlens [b] (live chunk rows, 1..T)."""
+    chunk), qlens [b] (live chunk rows, 0..T)."""
     if kv_quant:
         ks_ref, vs_ref = rest[0], rest[1]
         rest = rest[2:]
     o_ref, m_scr, l_scr, acc_scr = rest
+    heads, R = q_ref.shape[1], q_ref.shape[2]
     b = pl.program_id(0)
-    h = pl.program_id(1)
+    hb = pl.program_id(1)
     j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
+    first, last = j == 0, j == pl.num_programs(2) - 1
     length = lens_ref[b]
     qlen = qlens_ref[b]
+    rows_live = qlen * rep
 
-    @pl.when(j * bs < length)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                   # [R, hd]
-        k = _dequant_page(k_ref[0, 0],
-                          _head_scale(ks_ref, h) if kv_quant else None,
-                          kv_quant)                           # [bs, hd]
-        v = _dequant_page(v_ref[0, 0],
-                          _head_scale(vs_ref, h) if kv_quant else None,
-                          kv_quant)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # [R, bs]
-        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        t = rows // rep                                       # chunk row idx
-        cols = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        # chunk row t sits at absolute position length - qlen + t and sees
-        # everything up to and including itself (the causal in-chunk mask
-        # over the trailing qlen positions, the full prefix below).  Rows
-        # past the slot's live chunk (incl. sublane padding) see nothing —
-        # their l stays 0 and _finalize emits zeros.
-        row_len = jnp.where(t < qlen, length - (qlen - 1 - t), 0)
-        s = jnp.where(cols < row_len, s, NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(s > 0.5 * NEG_INF, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.where(m_prev > 0.5 * NEG_INF,
-                          jnp.exp(m_prev - m_new), 0.0)
-        l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
+    def each_head(body, unroll):
+        # heads are independent chains of two products and two lane
+        # reductions each: unrolled, the scheduler runs them side by side
+        def group(g, carry):
+            for k in range(unroll):
+                body(g * unroll + k)
+            return carry
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finalize():
-        l = l_scr[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        if unroll == heads:
+            group(0, 0)
+        else:
+            jax.lax.fori_loop(0, heads // unroll, group, 0)
+
+    def sub_tile(r0, rows, visible, unroll):
+        """Rows ``[r0, r0 + rows)`` of every head at this page: the
+        online-softmax recurrence over the rows' slices of the scratch.
+        ``visible`` is what the sub-tile's last live row sees; every
+        branch is the lane's, none a head's."""
+        at = pl.ds(r0, rows)
+
+        @pl.when(first)
+        def _init():
+            def init(h):
+                m_scr[h, at] = jnp.full((rows, _LANES), NEG_INF, jnp.float32)
+                l_scr[h, at] = jnp.zeros((rows, _LANES), jnp.float32)
+                acc_scr[h, at] = jnp.zeros((rows, acc_scr.shape[-1]),
+                                           jnp.float32)
+
+            each_head(init, unroll)
+
+        @pl.when(j * bs < visible)
+        def _compute():
+            # chunk row t sits at absolute position length - qlen + t and
+            # sees everything up to and including itself; rows past the
+            # lane's live chunk (incl. sublane padding) see nothing — their
+            # l stays 0 and the finalize emits zeros
+            iota = functools.partial(jax.lax.broadcasted_iota, jnp.int32,
+                                     (rows, bs))
+            t = (r0 + iota(0)) // rep
+            cols = j * bs + iota(1)
+            seen = cols < jnp.where(t < qlen, length - (qlen - 1 - t), 0)
+
+            def compute(h):
+                q = q_ref[0, h, at]                           # [rows, hd]
+                k, v = k_ref[0, h], v_ref[0, h]               # [bs, hd_store]
+                if kv_quant:
+                    k = _dequant_page(
+                        k, _head_scale(ks_ref, hb * heads + h), kv_quant)
+                    v = _dequant_page(
+                        v, _head_scale(vs_ref, hb * heads + h), kv_quant)
+                s = jnp.where(seen, _dot(q, k, ((1,), (1,))) * scale,
+                              NEG_INF)                        # [rows, bs]
+                # m and l are kept [rows, 128] with all lanes alike, as
+                # they come off the lane reductions: nothing is spread
+                # back over the lanes to meet s or the accumulator
+                m_prev = m_scr[h, at]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                # exactly 0 for masked entries even when the running max
+                # is itself NEG_INF (avoids exp(-inf + inf) = 1)
+                p = jnp.where(seen, jnp.exp(s - _lanes(m_new, bs)), 0.0)
+                alpha = jnp.where(m_prev > 0.5 * NEG_INF,
+                                  jnp.exp(m_prev - m_new), 0.0)
+                l_scr[h, at] = alpha * l_scr[h, at] + jnp.sum(
+                    p, axis=-1, keepdims=True)
+                acc_scr[h, at] = (
+                    acc_scr[h, at] * _lanes(alpha, acc_scr.shape[-1])
+                    + _dot(p.astype(v.dtype), v, ((1,), (0,))))
+                m_scr[h, at] = m_new
+
+            each_head(compute, unroll)
+
+        @pl.when(last)
+        def _finalize():
+            def finalize(h):
+                l = l_scr[h, at]
+                l_safe = jnp.where(l == 0.0, 1.0, l)
+                o_ref[0, h, at] = (acc_scr[h, at] / _lanes(
+                    l_safe, acc_scr.shape[-1])).astype(o_ref.dtype)
+
+            each_head(finalize, unroll)
+
+    @pl.when(last)
+    def _zeros():
+        o_ref[:] = jnp.zeros_like(o_ref)
+
+    # a step with nothing to do — a dead page of the ragged tail (its DMA
+    # already elided by the index map), every page of an empty lane —
+    # costs its branch and nothing else
+    works = (qlen > 0) & ((j * bs < length) | first | last)
+    short = rows_live <= head_rows
+    # heads unrolled side by side in a short tile and in a sub-tile; the
+    # int4 unpack (an interleave of lanes) costs Mosaic seconds of compile
+    # a copy, more than in proportion (8 and 2 copies: 50 s; 2 and 1: 5 s)
+    few, many = (2, 1) if kv_quant == "int4" else (8, 2)
+
+    @pl.when(works & short)
+    def _few_rows():
+        sub_tile(0, head_rows, length, _unroll(heads, few))
+
+    @pl.when(works & ~short)
+    def _sub_tiles():
+        def one(i, carry):
+            pl.when(i * sub_rows < rows_live)(lambda: sub_tile(
+                pl.multiple_of(i * sub_rows, sub_rows), sub_rows,
+                _sub_tile_sees(i, sub_rows, qlen, length, rep, jnp.maximum),
+                _unroll(heads, many)))
+            return carry
+
+        jax.lax.fori_loop(0, R // sub_rows, one, 0)
+
+
+def _unroll(n: int, cap: int) -> int:
+    """Largest divisor of ``n`` that is at most ``cap``."""
+    return max(d for d in range(1, min(n, cap) + 1) if n % d == 0)
+
+
+def _prefill_page_index_map(bs: int, num_blocks: int):
+    # the decode kernel's physical-page resolution over a block of heads
+    def idx(b, hb, j, tables_ref, lens_ref, qlens_ref):
+        return (_resolve_page(b, j, tables_ref, lens_ref, bs, num_blocks),
+                hb, 0, 0)
+
+    return idx
 
 
 def _prefill_kernel_call(q, key_cache, value_cache, block_tables, seq_lens,
-                         q_lens, scale, rep, kv_quant, k_scale, v_scale):
-    """q: [b, nkv, R, hd] (R = T*rep padded to sublane rows, t-major).
+                         q_lens, scale, rep, kv_quant, k_scale, v_scale,
+                         head_rows, sub_rows):
+    """q: [b, nkv, R, hd] (R = T*rep padded to whole sub-tiles, t-major).
     Returns [b, nkv, R, hd]."""
     b, nkv, R, hd = q.shape
-    num_blocks, _, bs, _ = key_cache.shape
+    num_blocks, _, bs, hd_store = key_cache.shape
     max_blocks = block_tables.shape[1]
+    heads = _prefill_heads_per_step(nkv, R, hd, bs, hd_store, q.dtype,
+                                    key_cache.dtype)
 
     kernel = functools.partial(_prefill_kernel, scale=scale, bs=bs, rep=rep,
-                               kv_quant=kv_quant)
-    kv_spec = pl.BlockSpec((1, 1, bs, key_cache.shape[-1]),
-                           _verify_page_index_map(bs, num_blocks))
-    in_specs = [
-        pl.BlockSpec((1, 1, R, hd),
-                     lambda b, h, j, t, l, ql: (b, h, 0, 0)),
-        kv_spec,
-        kv_spec,
-    ]
+                               kv_quant=kv_quant, head_rows=head_rows,
+                               sub_rows=sub_rows)
+    page_map = _prefill_page_index_map(bs, num_blocks)
+    kv_spec = pl.BlockSpec((1, heads, bs, hd_store), page_map)
+    q_spec = pl.BlockSpec((1, heads, R, hd),
+                          lambda b, hb, j, t, l, ql: (b, hb, 0, 0))
+    in_specs = [q_spec, kv_spec, kv_spec]
     args = [q, key_cache, value_cache]
     if kv_quant:
-        sc_spec = _scale_spec(nkv, _verify_page_index_map(bs, num_blocks))
+        sc_spec = _scale_spec(nkv, page_map)
         in_specs += [sc_spec, sc_spec]
         args += [_scale_operand(k_scale), _scale_operand(v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, nkv, max_blocks),
+        grid=(b, nkv // heads, max_blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, R, hd),
-                               lambda b, h, j, t, l, ql: (b, h, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            _VMEM((R, 1), jnp.float32),
-            _VMEM((R, 1), jnp.float32),
-            _VMEM((R, hd), jnp.float32),
+            _VMEM((heads, R, _LANES), jnp.float32),
+            _VMEM((heads, R, _LANES), jnp.float32),
+            _VMEM((heads, R, hd), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, R, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         name="ragged_prefill_attn",
         interpret=interpret_mode(),
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
@@ -1345,15 +1561,16 @@ def paged_attention_prefill(q, key_cache, value_cache, block_tables,
         CONSECUTIVE positions (row t at position
         ``seq_lens[b] - q_lens[b] + t``): a prefill chunk of the slot's
         prompt, or a single pending decode token (``q_lens[b] == 1``) riding
-        the same launch.  Rows at or past ``q_lens[b]`` are padding whose
-        output is unspecified.
+        the same launch.  Rows at or past ``q_lens[b]`` are padding: never
+        multiplied where a whole sub-tile of them is dead, and zeros out.
       key_cache/value_cache: [num_blocks, num_kv_heads, block_size, head_dim]
         pages with every query row's K/V already written, or quantized
         storage per ``kv_quant`` ('int8' → int8 same shape, 'int4' → int8
         [..., head_dim // 2]; :func:`quantize_kv_cache`).
       block_tables: [b, max_blocks] int32 physical page ids.
       seq_lens: [b] int32 TOTAL valid KV length per slot (incl. the chunk).
-      q_lens: [b] int32 live chunk rows per slot (1..T).
+      q_lens: [b] int32 live chunk rows per slot (0..T; a lane with 0
+        works no page, whatever its ``seq_lens``, and returns zeros).
       k_scale/v_scale: [num_blocks, num_kv_heads] f32 (quantized caches).
 
     Returns [b, T, num_heads, head_dim] in q's dtype: row t is attention
@@ -1387,17 +1604,17 @@ def paged_attention_prefill(q, key_cache, value_cache, block_tables,
     PREFILL_KERNEL_CALLS += 1
 
     rep = nh // nkv
-    R = _round_up(qmax * rep, _MIN_GROUP_ROWS)
+    R, head_rows, sub_rows = _prefill_tiles(qmax, rep, q.dtype)
     # [b, T, nkv, rep, hd] -> [b, nkv, T*rep, hd], row = t*rep + g
     qg = q.reshape(b, qmax, nkv, rep, hd).transpose(0, 2, 1, 3, 4)
     qg = qg.reshape(b, nkv, qmax * rep, hd)
     if R != qmax * rep:
-        # padded rows index chunk row t >= T >= qlen: fully masked in the
-        # kernel (zero output), sliced off below
+        # padded rows index chunk row t >= T >= qlen: never worked or fully
+        # masked in the kernel (zero output), sliced off below
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, R - qmax * rep), (0, 0)))
     out = _prefill_kernel_call(qg, key_cache, value_cache, block_tables,
                                seq_lens, q_lens, scale, rep, kv_quant,
-                               k_scale, v_scale)
+                               k_scale, v_scale, head_rows, sub_rows)
     out = out[:, :, :qmax * rep].reshape(b, nkv, qmax, rep, hd)
     return out.transpose(0, 2, 1, 3, 4).reshape(b, qmax, nh, hd)
 

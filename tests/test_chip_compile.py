@@ -143,6 +143,30 @@ def test_paged_prefill_quant(one_chip, compiled_kernels, kv_quant):
              _LENS, _SCALES, _SCALES)
 
 
+@pytest.mark.parametrize("nh,nkv,pages", [(32, 8, 513), (30, 30, 769),
+                                          (8, 2, 513)],
+                         ids=["mistral7b", "olmo_hybrid", "mistral7b_tp4"])
+def test_paged_prefill_at_the_cells_shapes(one_chip, compiled_kernels, nh,
+                                           nkv, pages):
+    """The mixed step's launch as the serving cells make it: 32 lanes of a
+    128-row chunk, bf16, 64-position pages, a 64-page table.  All of a
+    page's KV heads ride one grid step (8, 30, and 2 under TP = 4), and
+    the blocks and scratch that takes stay under the module's own budget
+    of the chip's scoped VMEM."""
+    b, T, bs, max_blocks = 32, 128, 64, 64
+    rep = nh // nkv
+    R, head_rows, sub_rows = pa._prefill_tiles(T, rep, BF16)
+    assert (R, head_rows, sub_rows) == (T * rep, 16, 128)
+    heads = pa._prefill_heads_per_step(nkv, R, HD, bs, HD, BF16, BF16)
+    assert heads == nkv
+    assert (pa._prefill_vmem_bytes(heads, R, HD, bs, HD, BF16, BF16)
+            <= pa._PREFILL_VMEM_BUDGET < pa._VMEM_LIMIT)
+    pool = ((pages, nkv, bs, HD), BF16)
+    _compile(pa.paged_attention_prefill, one_chip, ((b, T, nh, HD), BF16),
+             pool, pool, ((b, max_blocks), jnp.int32), ((b,), jnp.int32),
+             ((b,), jnp.int32))
+
+
 @pytest.mark.parametrize("tp", [1, TP], ids=["full", "tp4_local"])
 def test_fused_decode_step(one_chip, compiled_kernels, tp):
     nh, nkv = NH // tp, NKV // tp

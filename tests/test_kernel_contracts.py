@@ -355,6 +355,45 @@ def test_sequential_and_splitk_kernels_verify_clean():
     assert sections[0]["race"] == "ok" and sections[0]["bounds"] == "ok"
 
 
+@pytest.mark.parametrize("nkv,R,hd,kv_quant", [(2, 16, 8, None),
+                                               (2, 16, 8, "int8"),
+                                               (16, 512, 128, None)],
+                         ids=["all_heads_a_step", "quant_scales",
+                              "four_head_blocks"])
+def test_prefill_kernel_whole_page_walk_verifies_clean(nkv, R, hd, kv_quant):
+    """``ragged_prefill_attn`` takes a page with as many of its KV heads as
+    VMEM holds (the third case: 16 heads of a 512-row float32 tile in four
+    blocks of 4).  Its page index map reads ``tables`` and ``lens`` and
+    ignores ``qlens``; under every adversarial valuation of the three — zeros, a
+    ramp, +BIG, -BIG — nothing leaves the pool, the table or the scales,
+    and the output's revisits along the page walk are consecutive."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    b, bs, nb, mb = 2, 8, 10, 4
+    sds = jax.ShapeDtypeStruct
+    pool = sds((nb, nkv, bs, hd), jnp.int8 if kv_quant else jnp.float32)
+    args = [sds((b, nkv, R, hd), jnp.float32), pool, pool,
+            sds((b, mb), jnp.int32), sds((b,), jnp.int32),
+            sds((b,), jnp.int32)]
+    if kv_quant:
+        args += [sds((nb, nkv), jnp.float32)] * 2
+    heads = pa._prefill_heads_per_step(nkv, R, hd, bs, hd, jnp.float32,
+                                       pool.dtype)
+    assert heads == (4 if nkv == 16 else nkv)
+
+    def call(q, kc, vc, tbl, lens, qlens, ks=None, vs=None):
+        return pa._prefill_kernel_call(q, kc, vc, tbl, lens, qlens, 1.0, 1,
+                                       kv_quant, ks, vs, 8, min(R, 128))
+
+    jaxpr = jax.make_jaxpr(call)(*args)
+    findings, sections = check_kernel_contracts(jaxpr)
+    assert [f for f in findings if f.severity != Severity.INFO] == []
+    (sec,) = sections
+    assert sec["kernel"] == "ragged_prefill_attn"
+    assert sec["bounds"] == "ok" and sec["race"] == "ok"
+    assert tuple(sec["grid"]) == (b, nkv // heads, mb)
+
+
 def test_fused_kernel_alias_overlap_detected_and_allowlisted():
     """The fused decode step's in-register append: the pool is read AND
     written in place — the verifier must DETECT the cross-grid-point

@@ -974,6 +974,116 @@ def test_paged_prefill_quantized_kv(mode):
                                      - np.asarray(fp)[b_, :ql]))) < tol
 
 
+# launches that hold every lane class at once (ISSUE 33): q_lens 0 over
+# lens > 0, a decode lane's one row, one row short of and one past a
+# sub-tile edge, a full T; lens ending in the first page, on a page edge
+# and mid-page.  (name, nh, nkv, T, dtype, kv_quant): at rep 4 in float32
+# the first sub-tile is 8 rows = 2 tokens and the aligned ones 128 rows =
+# 32 tokens; at rep 1 in bfloat16 16 rows = 16 tokens and 128 = 128
+_LANE_CLASS_CASES = [
+    ("rep4_nkv8", 32, 8, 40, jnp.float32, None),
+    ("rep4_nkv2", 8, 2, 40, jnp.float32, None),
+    ("rep1_nkv30", 30, 30, 136, jnp.bfloat16, None),
+    ("rep4_int8", 8, 2, 40, jnp.float32, "int8"),
+    ("rep4_int4", 8, 2, 40, jnp.float32, "int4"),
+    ("verify_T5", 8, 2, 5, jnp.float32, "int8"),
+]
+
+
+def _lane_class_case(nh, nkv, T, dtype, kv_quant):
+    rep = nh // nkv
+    R, head_rows, sub_rows = pa._prefill_tiles(T, rep, dtype)
+    edge = sub_rows // rep                     # tokens of one sub-tile
+    few = head_rows // rep                     # tokens of the first one
+    bs, max_blocks = 16, 12
+    # (q_len, len): lens in the first page, on a page edge, mid-page
+    lanes = [(0, 37), (1, 1), (1, 48), (few, few), (few + 1, 90),
+             (min(edge - 1, T), 64 + min(edge - 1, T)),
+             (min(edge + 1, T), 150), (T, T), (T, 176), (0, 0)]
+    qlens = [q for q, _ in lanes]
+    lens = [n for _, n in lanes]
+    rs = np.random.RandomState(60)
+    q, kc, vc, tables, lens, qlens = _prefill_case(
+        rs, b=len(lanes), nh=nh, nkv=nkv, hd=32, bs=bs,
+        max_blocks=max_blocks, lens=lens, qmax=T, qlens=qlens, dtype=dtype)
+    kw = {}
+    if kv_quant:
+        kc, ks = pa.quantize_kv_cache(kc, kv_quant)
+        vc, vs = pa.quantize_kv_cache(vc, kv_quant)
+        kw = dict(kv_quant=kv_quant, k_scale=ks, v_scale=vs)
+    return (q, kc, vc, tables, lens, qlens), kw, (R, head_rows, sub_rows)
+
+
+@pytest.mark.parametrize("name,nh,nkv,T,dtype,kv_quant", _LANE_CLASS_CASES,
+                         ids=[c[0] for c in _LANE_CLASS_CASES])
+def test_paged_prefill_lane_classes(name, nh, nkv, T, dtype, kv_quant):
+    """The kernel's work follows what a lane carries and its numbers do
+    not: every lane class in one launch against the gather oracle, live
+    rows to tolerance and every row past ``q_lens`` exactly zero (a lane
+    with ``q_lens == 0`` works no page, whatever ``lens`` says)."""
+    args, kw, _ = _lane_class_case(nh, nkv, T, dtype, kv_quant)
+    qlens = np.asarray(args[5])
+    before = pa.PREFILL_KERNEL_CALLS
+    out = np.asarray(pa.paged_attention_prefill(*args, **kw), np.float32)
+    assert pa.PREFILL_KERNEL_CALLS > before, "prefill kernel path not taken"
+    ref = np.asarray(pa.paged_prefill_reference(*args, **kw), np.float32)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-3
+    for b_, ql in enumerate(qlens):
+        np.testing.assert_allclose(out[b_, :ql], ref[b_, :ql], rtol=tol,
+                                   atol=tol)
+        assert not out[b_, ql:].any(), f"lane {b_}: rows past q_lens"
+
+
+def _brute_census(q_lens, seq_lens, rep, bs, max_blocks, tiles):
+    """Row by row, page by page: what the kernel's predicates let through."""
+    R, head_rows, sub_rows = tiles
+    live = computed = 0
+    for ql, n in zip(q_lens, seq_lens):
+        sees = lambda t: n - (ql - 1 - t)
+        for t in range(ql):
+            live += sum(1 for j in range(max_blocks) if j * bs < sees(t))
+        if ql <= 0:
+            continue
+        if ql * rep <= head_rows:
+            subs = [(0, head_rows)]
+        else:
+            subs = [(i * sub_rows, sub_rows) for i in range(R // sub_rows)
+                    if i * sub_rows < ql * rep]
+        for r0, rows in subs:
+            t_last = min((r0 + rows - 1) // rep, ql - 1)
+            computed += -(-rows // rep) * sum(
+                1 for j in range(max_blocks) if j * bs < sees(t_last))
+    return live, computed
+
+
+@pytest.mark.parametrize("name,nh,nkv,T,dtype,kv_quant", _LANE_CLASS_CASES,
+                         ids=[c[0] for c in _LANE_CLASS_CASES])
+def test_prefill_census_matches_brute_force(name, nh, nkv, T, dtype,
+                                            kv_quant):
+    """``prefill_census`` (closed forms over numpy vectors, what the
+    engine adds to its counters a step) against a row-by-row count on the
+    lane-class launches; the live share can never pass 1."""
+    args, _, tiles = _lane_class_case(nh, nkv, T, dtype, kv_quant)
+    lens, qlens = np.asarray(args[4]), np.asarray(args[5])
+    bs, max_blocks = args[1].shape[2], args[3].shape[1]
+    got = pa.prefill_census(qlens, lens, T, nh // nkv, bs,
+                            max_blocks=max_blocks, nkv=nkv, hd=32,
+                            dtype=dtype, kv_quant=kv_quant)
+    live, computed = _brute_census(qlens, lens, nh // nkv, bs, max_blocks,
+                                   tiles)
+    assert (got["row_pages_live"], got["row_pages_computed"]) == (
+        live, computed)
+    assert 0 < live <= computed
+    assert got["grid_steps"] == len(qlens) * max_blocks   # all heads a step
+    # a lane the engine marks dead still costs what the kernel works
+    dead = pa.prefill_census(qlens, lens, T, nh // nkv, bs,
+                             max_blocks=max_blocks, nkv=nkv, hd=32,
+                             dtype=dtype, kv_quant=kv_quant,
+                             live=np.arange(len(qlens)) % 2 == 0)
+    assert dead["row_pages_computed"] == computed
+    assert dead["row_pages_live"] < live
+
+
 def test_paged_prefill_disable_env_routes_to_oracle(monkeypatch):
     rs = np.random.RandomState(55)
     q, kc, vc, tables, lens, qlens = _prefill_case(
