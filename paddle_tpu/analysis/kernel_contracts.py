@@ -90,6 +90,10 @@ _BIG = 1 << 20
 #: ceiling on enumerated grid corners when sampling (2^ndim corners on a
 #: high-rank grid would otherwise eat the whole sample budget)
 _CORNER_CAP = 256
+#: the extent a grid axis whose bound is run-time data is enumerated at:
+#: index maps see grid indices, never the bound, so what the first steps
+#: of such an axis fetch and write is what any longer run of it does there
+_DYNAMIC_EXTENT = 8
 
 
 def verify_samples_cap() -> int:
@@ -181,7 +185,7 @@ def _prefetch_valuations(eqn, n_prefetch: int):
     evaluation suffices)."""
     if not n_prefetch:
         return [("static", [])]
-    avals = [v.aval for v in eqn.invars[:n_prefetch]]
+    avals = [v.aval for v in _operands(eqn)[:n_prefetch]]
 
     def fill(val):
         return [np.full(a.shape, val, dtype=np.dtype(a.dtype))
@@ -232,6 +236,14 @@ def _block_steps(bm):
     in block units (element offset = idx * size); squeezed/mapped dims
     (entries without a ``block_size``) index single elements (step 1)."""
     return tuple(int(getattr(d, "block_size", 1)) for d in bm.block_shape)
+
+
+def _operands(eqn) -> list:
+    """A launch's operands in the order its grid mapping and
+    ``input_output_aliases`` count them: the scalar-prefetch refs first.
+    The values of run-time grid bounds lead the equation's inputs."""
+    nd = eqn.params["grid_mapping"].num_dynamic_grid_bounds
+    return list(eqn.invars[nd:])
 
 
 def _operand_label(bms, k: int, n_inputs: int) -> str:
@@ -417,7 +429,8 @@ def _check_alias_pair(kname, where, target, eqn, gm, bms, gi, oj,
     bm_in, bm_out = bms[in_k], bms[n_in + oj]
     in_label = _operand_label(bms, in_k, n_in)
     out_label = _operand_label(bms, n_in + oj, n_in)
-    a_in = getattr(eqn.invars[gi], "aval", None)
+    operands = _operands(eqn)
+    a_in = getattr(operands[gi], "aval", None)
     a_out = getattr(eqn.outvars[oj], "aval", None)
     if (a_in is not None and a_out is not None
             and (tuple(a_in.shape) != tuple(a_out.shape)
@@ -442,7 +455,7 @@ def _check_alias_pair(kname, where, target, eqn, gm, bms, gi, oj,
     # readers of the SAME buffer: the aliased input itself, plus any other
     # input operand bound to the same traced value (the pool passed twice)
     readers = [in_k] + [k for k in range(n_in) if k != in_k
-                        and eqn.invars[npf + k] is eqn.invars[gi]]
+                        and operands[npf + k] is operands[gi]]
     out_key = n_in + oj
     for rk in readers:
         hit = None
@@ -516,13 +529,17 @@ def _verify_eqn(eqn, target: str, cap: int):
                "grid_points": 0, "points_checked": 0, "sampled": False,
                "data_dependent": False, "bounds": "ok", "race": "ok",
                "alias": "ok", "findings": 0}
-    if getattr(gm, "num_dynamic_grid_bounds", 0) or any(d < 0 for d in grid):
-        section.update(bounds="skipped", race="skipped", alias="skipped")
-        return [Finding(
+    findings: list[Finding] = []
+    if any(d < 0 for d in grid):
+        # an axis whose extent is run-time data: enumerated at a fixed
+        # extent, and said so (the section keeps -1 for it)
+        grid = tuple(_DYNAMIC_EXTENT if d < 0 else d for d in grid)
+        findings.append(Finding(
             rule="kernel_bounds", severity=Severity.INFO,
-            message=(f"pallas kernel {kname}: dynamic grid bounds — "
-                     f"contracts cannot be enumerated statically"),
-            where=where, target=target)], section
+            message=(f"pallas kernel {kname}: a grid bound is run-time "
+                     f"data — that axis is enumerated at its first "
+                     f"{_DYNAMIC_EXTENT} steps"),
+            where=where, target=target))
 
     pts, sampled, total = _sample_grid(grid, cap)
     lin = (np.ravel_multi_index(pts.T, grid) if grid
@@ -539,7 +556,6 @@ def _verify_eqn(eqn, target: str, cap: int):
     aliased_outs = {o for _, o in aliases}
     reads_out = _outputs_read(eqn, gm)
 
-    findings: list[Finding] = []
     # evaluate every mapping under every valuation once; all checks share
     # the result table
     results: dict = {}

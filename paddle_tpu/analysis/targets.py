@@ -197,7 +197,7 @@ def _t_serving_decode_step() -> AnalysisTarget:
 
     # the PRE-fusion decode program (rope + KV scatters + sequential paged
     # kernel): its lint shape stays pinned even though production now
-    # defaults to the fused/split-K path (serving_flash_decode_step below)
+    # defaults to the fused path (serving_flash_decode_step below)
     eng = _serving_engine(_disable_pallas=("flash_decode",
                                            "fused_decode_step"))
     B = eng.max_batch
@@ -218,9 +218,10 @@ def _t_serving_flash_decode_step() -> AnalysisTarget:
     import jax.numpy as jnp
 
     # the production-default decode program (ISSUE 10): fused rope +
-    # KV-append + split-K attention with the log-sum-exp combine.  The
-    # gate polices it like every hot path: the combine's f32 online-
-    # softmax dots are the ONLY allowlisted upcasts (allowlist.toml), and
+    # KV-append + paged attention, a page with all its KV heads a grid
+    # step.  The gate polices it like every hot path: the kernel's f32
+    # online-softmax statistics are the ONLY allowlisted upcasts
+    # (allowlist.toml), and
     # any other collective/upcast that sneaks into the fused step fails CI.
     eng = _serving_engine()
     assert eng._fused, "flash target must build the fused decode engine"

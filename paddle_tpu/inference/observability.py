@@ -364,6 +364,17 @@ ENGINE_STAT_SCHEMA = {
                                 "the pages each was worked at (over this, "
                                 "attn_row_pages_live = the kernel's live "
                                 "share)"),
+    "decode_attn_pages_live": ("counter",
+                               "Decode steps' fused attention, one layer's "
+                               "launch a token: (lane, KV page) steps that "
+                               "hold KV a live lane attends (decode_census "
+                               "of the staged positions)"),
+    "decode_attn_pages_walked": ("counter",
+                                 "(lane, KV page) steps the fused decode "
+                                 "kernel's walk visited in those launches: "
+                                 "every lane to the launch's longest lane "
+                                 "(over this, decode_attn_pages_live = the "
+                                 "walk's live share)"),
     # linear-attention layers (a step program with per-slot recurrent
     # state, docs/hybrid_serving.md; the dense program counts none of it)
     "gdn_rows_computed": ("counter",

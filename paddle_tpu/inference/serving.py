@@ -52,12 +52,13 @@ on resume, which makes the recompute exact for greedy AND sampled decode).
 
 Long-context flash-decode + the fused decode step (docs/paged_attention.md)
 are the paged decode path's pure-speed levers, both on by default and both
-token-identical to the paths they replace: decode attention dispatches
-split-K (a long slot's page walk runs as S parallel shards merged by an
-exact log-sum-exp combine — ``PADDLE_TPU_DISABLE_PALLAS=flash_decode``
-restores the sequential walk), and the whole per-layer decode prologue —
-RoPE, the two KV-append scatters and the attention kernel — runs as ONE
-fused Pallas launch (``PADDLE_TPU_DISABLE_PALLAS=fused_decode_step``
+token-identical to the paths they replace: the unfused decode attention
+dispatches split-K (a long slot's page walk runs as S parallel shards
+merged by an exact log-sum-exp combine — ``PADDLE_TPU_DISABLE_PALLAS=
+flash_decode`` restores the sequential walk), and the whole per-layer
+decode prologue — RoPE, the two KV-append scatters and the attention
+kernel — runs as ONE fused Pallas launch that takes a page with all its KV
+heads a grid step and walks to the launch's longest lane (``PADDLE_TPU_DISABLE_PALLAS=fused_decode_step``
 rebuilds the unfused engine byte-identically; in fused mode the pool
 carries one extra SPILL page dropped writes land on, since a Pallas output
 index map cannot drop).  Verify/prefill/mixed programs are byte-untouched;
@@ -1152,8 +1153,8 @@ class ContinuousBatchingEngine:
         [B, nkv, max_seq, hd] view of each slot's pages (the reference's
         block_multihead_attention memory model; the gather fuses into the
         attention contraction).  On the fused default (``self._fused``,
-        docs/paged_attention.md) rope + the page append + split-K
-        attention run as ONE Pallas launch per layer instead — dropped
+        docs/paged_attention.md) rope + the page append + the page walk
+        run as ONE Pallas launch per layer instead — dropped
         writes land on the pool's spill page."""
         from .. import inference as _inf
         from ..ops.pallas import rope as rope_mod
@@ -1264,7 +1265,7 @@ class ContinuousBatchingEngine:
                 return out, view
 
             if self._fused and use_kernel:
-                # decode megastep stage 1: rope + page append + split-K
+                # decode megastep stage 1: rope + page append + paged
                 # attention in ONE Pallas launch per layer (docs/
                 # paged_attention.md "Fused decode step").  Dropped writes
                 # (inactive lanes, pos >= max_seq) land on the pool's
@@ -3268,6 +3269,8 @@ class ContinuousBatchingEngine:
             # late as possible (docs/async_runtime.md)
             self._phase("serving/host_overlap")
             self._host_overlap()
+            # and so does the count of what its attention kernel walks
+            of_program.update(self._decode_attn_census(active_np, k))
             self._phase("serving/fetch")
             bad_np = np.asarray(bad)    # [k, B] guard flags
         except FaultInjected as e:
@@ -3608,6 +3611,31 @@ class ContinuousBatchingEngine:
             kv_quant=self.kv_quant, live=active)
         return {"attn_row_pages_live": census["row_pages_live"],
                 "attn_row_pages_computed": census["row_pages_computed"]}
+
+    def _decode_attn_census(self, active, k: int) -> dict:
+        """What the decode step's fused attention kernel walked this
+        launch, from the positions the step staged (``decode_census``; a
+        count of one layer's launch a decoded token, the same for every
+        layer): (lane, page) steps that held KV the lane attends against
+        the steps of the walk.  Only the fp fused kernel walks this way;
+        an engine on another decode path counts nothing."""
+        if not (self.paged and self._fused and self.kv_quant is None):
+            return {}
+        from ..ops.pallas.paged_attention import decode_census
+
+        live_pages = walked = 0
+        for i in range(k):
+            # the chunk's i-th token, as the program hands the kernel its
+            # lengths: a lane that does not write attends from length 0
+            pos = self._pos + i
+            live = active & (pos < self.max_seq)
+            census = decode_census(np.where(live, pos, 0), self.block_size,
+                                   max_blocks=self._table.shape[1],
+                                   live=live)
+            live_pages += census["pages_live"]
+            walked += census["pages_walked"]
+        return {"decode_attn_pages_live": live_pages,
+                "decode_attn_pages_walked": walked}
 
     def _consume_token(self, slot: int, req: Request, tok: int, t0: float):
         """Bank one generated token on a slot (mixed-step emit): append,

@@ -149,7 +149,7 @@ def paged_decode_attention(q, key_cache, value_cache, block_tables, seq_lens,
 
 def fused_paged_decode_step(q, k_new, v_new, cos, sin, key_cache,
                             value_cache, block_tables, seq_lens, write_blk,
-                            writeable, scale=None, num_shards=None):
+                            writeable, scale=None):
     """Fused RoPE + KV-append + paged attention for one decode token per
     slot — decode megastep stage 1 (docs/paged_attention.md "Fused decode
     step"; the MPK paper's answer to per-layer dispatch tax).  The unfused
@@ -173,7 +173,7 @@ def fused_paged_decode_step(q, k_new, v_new, cos, sin, key_cache,
 
     return _pa.fused_decode_step(
         q, k_new, v_new, cos, sin, key_cache, value_cache, block_tables,
-        seq_lens, write_blk, writeable, scale=scale, num_shards=num_shards)
+        seq_lens, write_blk, writeable, scale=scale)
 
 
 def fused_paged_quant_decode_step(q, k_new, v_new, cos, sin, key_codes,

@@ -337,10 +337,10 @@ def _logits(q, k, refs, q_idx, kv_idx, *, edge, scale, causal, bq, bkv,
 
 
 def _lanes(x, n):
-    """[rows, _LANES] whose lanes are all alike -> [rows, n]."""
+    """[..., rows, _LANES] whose lanes are all alike -> [..., rows, n]."""
     if n > _LANES:
-        x = jnp.tile(x, (1, pl.cdiv(n, _LANES)))
-    return x[:, :n]
+        x = jnp.tile(x, (1,) * (x.ndim - 1) + (pl.cdiv(n, _LANES),))
+    return x[..., :n]
 
 
 def _exp(s, shift, edge):

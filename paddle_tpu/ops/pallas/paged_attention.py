@@ -98,14 +98,17 @@ family out to the gather path).
 
 Decode megastep stage 1 (docs/paged_attention.md "Fused decode step") is
 :func:`_fused_decode_kernel`: RoPE application, the KV-page append and the
-split-K paged attention of ONE decode token fused into a single Pallas
-launch per layer (the MPK paper's case against per-op dispatch, PAPERS.md).
-The kernel takes PRE-rope q/k, rotates them in-kernel against per-slot
-cos/sin rows, inserts the roped k (and raw v) into the slot's write page
-in-register BEFORE the score dot — so attention sees the appended token
-without a separate scatter — and commits the updated page through an
+paged attention of ONE decode token fused into a single Pallas launch per
+layer (the MPK paper's case against per-op dispatch, PAPERS.md).  Its grid
+takes a page with all its KV heads a step, as the prefill kernel's does,
+and its page axis ends at the launch's longest lane, an extent read from
+``seq_lens`` at run time.  The kernel takes PRE-rope q/k, rotates them
+in-kernel against per-slot cos/sin rows, inserts the roped k (and raw v)
+into the slot's write page in-register, so attention sees the appended
+token without a separate scatter, and commits the updated page through an
 ALIASED pool output whose index map targets exactly the write page (one
-page write per (slot, head), the same bytes the scatter wrote).  Lanes that
+page write per (slot, head block), the same bytes the scatter wrote).
+Lanes that
 must not write (inactive, or past max_seq) direct their page flush at a
 dedicated SPILL page the caller appends to the pool — Pallas output index
 maps cannot drop, so the drop semantics of ``.at[].set(mode='drop')``
@@ -1213,17 +1216,22 @@ def _prefill_vmem_bytes(heads: int, R: int, hd: int, bs: int, hd_store: int,
     return heads * per_head
 
 
-def _prefill_heads_per_step(nkv: int, R: int, hd: int, bs: int,
-                            hd_store: int, q_dtype, kv_dtype) -> int:
-    """KV heads of a page one grid step takes: the largest divisor of
-    ``nkv`` whose blocks and scratch fit the budget (a page with all its
-    heads is one contiguous block of the pool)."""
+def _heads_that_fit(nkv: int, per_head: int) -> int:
+    """KV heads of a page one grid step of a paged kernel takes: the
+    largest divisor of ``nkv`` whose blocks and scratch, ``per_head`` bytes
+    a head, fit the budget (a page with all its heads is one contiguous
+    block of the pool).  The prefill and the fused decode kernel both."""
     for heads in range(nkv, 1, -1):
-        if nkv % heads == 0 and _prefill_vmem_bytes(
-                heads, R, hd, bs, hd_store, q_dtype,
-                kv_dtype) <= _PREFILL_VMEM_BUDGET:
+        if nkv % heads == 0 and heads * per_head <= _PREFILL_VMEM_BUDGET:
             return heads
     return 1
+
+
+def _prefill_heads_per_step(nkv: int, R: int, hd: int, bs: int,
+                            hd_store: int, q_dtype, kv_dtype) -> int:
+    """KV heads of a page one prefill grid step takes."""
+    return _heads_that_fit(nkv, _prefill_vmem_bytes(
+        1, R, hd, bs, hd_store, q_dtype, kv_dtype))
 
 
 def _sub_tile_sees(i, sub, qlen, length, rep, maximum):
@@ -1324,19 +1332,6 @@ def _prefill_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
     qlen = qlens_ref[b]
     rows_live = qlen * rep
 
-    def each_head(body, unroll):
-        # heads are independent chains of two products and two lane
-        # reductions each: unrolled, the scheduler runs them side by side
-        def group(g, carry):
-            for k in range(unroll):
-                body(g * unroll + k)
-            return carry
-
-        if unroll == heads:
-            group(0, 0)
-        else:
-            jax.lax.fori_loop(0, heads // unroll, group, 0)
-
     def sub_tile(r0, rows, visible, unroll):
         """Rows ``[r0, r0 + rows)`` of every head at this page: the
         online-softmax recurrence over the rows' slices of the scratch.
@@ -1352,7 +1347,7 @@ def _prefill_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
                 acc_scr[h, at] = jnp.zeros((rows, acc_scr.shape[-1]),
                                            jnp.float32)
 
-            each_head(init, unroll)
+            _each_head(init, heads, unroll)
 
         @pl.when(j * bs < visible)
         def _compute():
@@ -1394,7 +1389,7 @@ def _prefill_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
                     + _dot(p.astype(v.dtype), v, ((1,), (0,))))
                 m_scr[h, at] = m_new
 
-            each_head(compute, unroll)
+            _each_head(compute, heads, unroll)
 
         @pl.when(last)
         def _finalize():
@@ -1404,7 +1399,7 @@ def _prefill_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
                 o_ref[0, h, at] = (acc_scr[h, at] / _lanes(
                     l_safe, acc_scr.shape[-1])).astype(o_ref.dtype)
 
-            each_head(finalize, unroll)
+            _each_head(finalize, heads, unroll)
 
     @pl.when(last)
     def _zeros():
@@ -1434,6 +1429,23 @@ def _prefill_kernel(tables_ref, lens_ref, qlens_ref, q_ref, k_ref, v_ref,
             return carry
 
         jax.lax.fori_loop(0, R // sub_rows, one, 0)
+
+
+def _each_head(body, heads: int, unroll: int):
+    """``body(h)`` for the ``heads`` KV heads of a grid step, ``unroll`` of
+    them side by side: heads are independent chains of two products and
+    two lane reductions each, so the scheduler overlaps an unrolled group,
+    and a ``fori_loop`` over the groups keeps the body's size that of one
+    group whatever the head count."""
+    def group(g, carry):
+        for k in range(unroll):
+            body(g * unroll + k)
+        return carry
+
+    if unroll == heads:
+        group(0, 0)
+    else:
+        jax.lax.fori_loop(0, heads // unroll, group, 0)
 
 
 def _unroll(n: int, cap: int) -> int:
@@ -1624,127 +1636,192 @@ def paged_attention_prefill(q, key_cache, value_cache, block_tables,
 # ---------------------------------------------------------------------------
 
 def _rotate_half_rows(x, half: int):
-    """rotate-half along the last (head_dim) axis of a 2-D tile."""
-    return jnp.concatenate([-x[:, half:], x[:, :half]], axis=-1)
+    """rotate-half along the last (head_dim) axis of a tile of any rank."""
+    return jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+
+
+def _decode_walk(seq_lens, bs: int, max_blocks: int, xp):
+    """The fused decode walk's two predicates, from its PRE-append lengths:
+    each lane's live pages ``cdiv(seq_lens + 1, bs)`` (its write page is
+    the last of them), and the walk's bound, the longest of those over the
+    launch's lanes, at least 1 and at most the table's width.  One
+    expression for the kernel's wrapper (``xp`` = jnp, traced: the bound
+    is the grid's extent) and for :func:`decode_census` (numpy)."""
+    pages = (seq_lens + bs) // bs
+    return pages, xp.clip(xp.max(pages), 1, max_blocks)
+
+
+def decode_census(seq_lens, bs: int, *, max_blocks: int, live=None) -> dict:
+    """What one launch of the fused decode kernel walks, from the host's
+    numbers (the like of :func:`prefill_census`), by the predicates the
+    kernel's grid and branches read (:func:`_decode_walk`).
+
+    ``seq_lens`` [b] are the kernel's own operand (the PRE-append lengths);
+    ``live`` [b] bool marks the lanes whose row carries a token (the engine
+    hands every other lane length 0).  Returns ``pages_live`` — sum over
+    the live lanes of the pages each attends, the least any kernel of this
+    layout walks — and ``pages_walked`` — the (lane, page) steps of the
+    grid for one block of KV heads: every lane walks to the launch's
+    bound, and a step past the lane's own last page is a branch and no
+    copy."""
+    seq_lens = np.asarray(seq_lens, np.int64)
+    live = (np.ones(seq_lens.shape, bool) if live is None
+            else np.asarray(live, bool))
+    pages, bound = _decode_walk(seq_lens, bs, max_blocks, np)
+    return {"pages_live": int(np.where(live, pages, 0).sum()),
+            "pages_walked": int(seq_lens.size * bound)}
+
+
+#: KV heads a fused decode step's grid step works side by side at most
+_DECODE_UNROLL = 8
 
 
 def _fused_decode_kernel(tables_ref, lens_ref, wblk_ref, wable_ref,
                          q_ref, k_ref, v_ref, cos_ref, sin_ref,
                          kp_ref, vp_ref,
-                         m_ref, l_ref, acc_ref, kp_out_ref, vp_out_ref,
-                         m_scr, l_scr, acc_scr, q_scr,
-                         *, scale, bs, pages_per_shard):
-    """Grid: (slots, kv_heads, shards, pages_per_shard) — the split-K page
-    walk with the whole decode-token prologue folded in:
+                         o_ref, kp_out_ref, vp_out_ref,
+                         m_scr, l_scr, acc_scr, q_scr, kn_scr, vn_scr,
+                         *, scale, bs, unroll):
+    """Grid: (slots, kv_heads / heads_per_step, pages), pages innermost
+    (sequential), the page extent the launch's longest lane
+    (:func:`_decode_walk`, run-time data).  One grid step holds one
+    physical page with ``heads_per_step`` of its KV heads (a contiguous
+    block of the pool) and those heads' padded q groups, with the whole
+    decode-token prologue folded in:
 
-    - RoPE: q (the slot's padded head group) is rotated ONCE per (slot,
-      head) into f32 scratch at the first grid step; the new k row is
-      rotated at the write step.  cos/sin arrive as per-slot rows (the
-      caller gathers them from its position table — a [b, hd] operand, not
-      a launch).
-    - append: at the write step (logical page ``lens // bs``) the roped k
-      and raw v are inserted into the fetched page tile IN-REGISTER before
-      the score dot — attention sees the appended token without a separate
-      scatter — and the updated tile is committed through the ALIASED pool
-      output, whose index map pins the slot's write page (``wblk``).  One
-      page write per (slot, head): the same bytes the XLA scatter wrote.
+    - RoPE: the q groups are rotated ONCE per (slot, head block) at the
+      first page, in the INPUT dtype; the new k rows at the write page.
+      cos/sin arrive as per-slot rows (a [b, hd] operand, not a launch).
+    - append: the write page is the lane's last live page (``lens // bs``);
+      there the roped k and raw v rows are inserted into the fetched page
+      of every head at once and the page is committed through the ALIASED
+      pool output, whose index map pins the slot's write page (``wblk``):
+      one page write per (slot, head block), the bytes the XLA scatter
+      wrote.  The attention of that step reads the inserted rows, so it
+      sees the appended token without a separate scatter.
     - lanes with ``wable == 0`` (inactive / past max_seq) never insert;
       their pool-output flush lands on the caller's SPILL page (``wblk`` =
       spill) and commits ZEROS — the materialized form of ``mode='drop'``,
       kept deterministic so a sentinel-page gather can never read
       uninitialized (possibly NaN) bits off the spill page.
+    - attention: the online-softmax recurrence of each head over the
+      lane's pages, q and k in their own dtype, the softmax weights in
+      float32 against v, ``m`` and ``l`` kept 128 lanes wide in float32
+      scratch; the output is written once, at the lane's last page.  A
+      step past the lane's last page (a shorter lane under the launch's
+      bound) works nothing, and its index maps name the lane's last page
+      again, so no copy is issued either.
 
     Scalar-prefetch refs: tables [b, max_blocks], lens [b] PRE-append
     length (the append position), wblk [b] physical write page (spill when
     dropped), wable [b] 0/1.  Attention masks columns < lens + 1."""
+    heads, group = q_ref.shape[1], q_ref.shape[2]
+    hd = acc_scr.shape[-1]
     b = pl.program_id(0)
-    s_id = pl.program_id(2)
-    p = pl.program_id(3)
-    j = s_id * pages_per_shard + p                        # logical page
-    length = lens_ref[b] + 1                              # incl. appended tok
-    half = q_scr.shape[-1] // 2
+    j = pl.program_id(2)
+    lens = lens_ref[b]
+    last = lens // bs                       # the write page, the last live
+    w_on = wable_ref[b] == 1
+    half = hd // 2
 
-    @pl.when((s_id == 0) & (p == 0))
-    def _rope_q():
-        # rope in the INPUT dtype, exactly like the unfused path's
+    def rope(x):
+        # in the INPUT dtype, exactly like the unfused path's
         # apply_rotary_pos_emb (bf16 operands -> bf16 math): the fused
         # program must feed the score dot the same rounded values the
         # kill-switched program reads, or near-tied argmaxes could flip
-        q = q_ref[0, 0]                                   # [group, hd]
-        cos = cos_ref[0]                                  # [1, hd]
-        sin = sin_ref[0]
-        q_r = (q * cos + _rotate_half_rows(q, half) * sin).astype(q.dtype)
-        q_scr[:] = q_r.astype(jnp.float32)
+        cos, sin = cos_ref[0], sin_ref[0]                 # [1, hd]
+        return (x * cos + _rotate_half_rows(x, half) * sin).astype(x.dtype)
 
-    @pl.when(p == 0)
+    @pl.when(j == 0)
     def _init():
+        q_scr[:] = rope(q_ref[0]).astype(q_scr.dtype)    # [heads, group, hd]
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(j * bs < length)
-    def _compute():
-        k_page = kp_ref[0, 0].astype(jnp.float32)         # [bs, hd]
-        v_page = vp_ref[0, 0].astype(jnp.float32)
-        w_on = wable_ref[b] == 1
-        is_wpage = j == lens_ref[b] // bs                 # walked by EVERY
-        is_wstep = w_on & is_wpage                        # lane (lens 0 -> 0)
-        # rope the new k in the INPUT dtype (matching apply_rotary_pos_emb)
-        # and round through the POOL dtype before the dot: the fused score
-        # must see exactly the bytes the unfused path would read back from
-        # its scatter — not an unrounded f32 row
-        cos = cos_ref[0]                                  # [1, hd]
-        sin = sin_ref[0]
-        k_new = k_ref[0, 0]                               # [1, hd]
-        k_roped = (k_new * cos + _rotate_half_rows(k_new, half) * sin
-                   ).astype(k_new.dtype).astype(kp_ref.dtype)
-        v_new = v_ref[0, 0].astype(vp_ref.dtype)          # [1, hd]
-        rows = jax.lax.broadcasted_iota(jnp.int32, k_page.shape, 0)
-        ins = is_wstep & (rows == lens_ref[b] % bs)
-        k_eff = jnp.where(ins, k_roped.astype(jnp.float32), k_page)
-        v_eff = jnp.where(ins, v_new.astype(jnp.float32), v_page)
+    @pl.when(j == last)
+    def _append():
+        # the new k roped in the input dtype and rounded through the POOL
+        # dtype: the page (and the score dot) holds exactly the bytes the
+        # unfused path's scatter writes, not an unrounded f32 row
+        kn = rope(k_ref[0]).astype(kp_ref.dtype)          # [heads, 1, hd]
+        vn = v_ref[0].astype(vp_ref.dtype)
+        kn_scr[:] = kn
+        vn_scr[:] = vn
+        at = jax.lax.broadcasted_iota(jnp.int32, (heads, bs, hd), 1)
+        ins = at == lens % bs
+        # dropped lanes write ZEROS: their flush lands on the spill page,
+        # and the output VMEM buffer would otherwise carry uninitialized
+        # bits on hardware — a NaN pattern parked on the spill page would
+        # poison every later sentinel-page gather through the masked
+        # softmax's 0*NaN (the guarantee jnp.take(..., fill_value=0) gave)
+        kp_out_ref[0] = jnp.where(w_on, jnp.where(ins, kn, kp_ref[0]),
+                                  0).astype(kp_out_ref.dtype)
+        vp_out_ref[0] = jnp.where(w_on, jnp.where(ins, vn, vp_ref[0]),
+                                  0).astype(vp_out_ref.dtype)
 
-        @pl.when(is_wpage)
-        def _commit():
-            # non-inserted rows round-trip f32 exactly (bf16/f32 storage)
-            # and the inserted row was roped in the input dtype and rounded
-            # through the pool dtype above — the committed page holds the
-            # same values the unfused path's scatter wrote (modulo FMA
-            # contraction choices the compiler makes per program).
-            # Dropped lanes (w_on == 0) write ZEROS: their flush lands on
-            # the caller's spill page, and the output VMEM buffer would
-            # otherwise carry uninitialized bits on hardware — a NaN
-            # pattern parked on the spill page would poison every later
-            # sentinel-page gather through the masked softmax's 0*NaN
-            # (the guarantee jnp.take(..., fill_value=0) used to give).
-            zero = jnp.zeros_like(k_eff)
-            kp_out_ref[0, 0] = jnp.where(w_on, k_eff,
-                                         zero).astype(kp_out_ref.dtype)
-            vp_out_ref[0, 0] = jnp.where(w_on, v_eff,
-                                         zero).astype(vp_out_ref.dtype)
+    @pl.when(j <= last)
+    def _attend():
+        # the appended row, at the write page of a lane that writes
+        ins = ((j == last) & w_on
+               & (jax.lax.broadcasted_iota(jnp.int32, (bs, hd), 0)
+                  == lens % bs))
+        seen = (j * bs + jax.lax.broadcasted_iota(jnp.int32, (group, bs), 1)
+                < lens + 1)
 
-        _online_softmax_update(q_scr[:], k_eff, v_eff, j, bs, length,
-                               m_scr, l_scr, acc_scr, scale)
+        def attend(h):
+            k = jnp.where(ins, kn_scr[h], kp_ref[0, h])   # [bs, hd]
+            v = jnp.where(ins, vn_scr[h], vp_ref[0, h])
+            q = q_scr[h].astype(q_ref.dtype)              # [group, hd]
+            s = jnp.where(seen, _dot(q, k, ((1,), (1,))) * scale,
+                          NEG_INF)                        # [group, bs]
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # exactly 0 for masked entries even when the running max is
+            # itself NEG_INF (avoids exp(-inf + inf) = 1)
+            p = jnp.where(seen, jnp.exp(s - _lanes(m_new, bs)), 0.0)
+            alpha = jnp.where(m_prev > 0.5 * NEG_INF,
+                              jnp.exp(m_prev - m_new), 0.0)
+            l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=-1, keepdims=True)
+            # p stays float32 (v is widened to meet it): the weights are
+            # not rounded to the pool's dtype before they sum the values
+            acc_scr[h] = acc_scr[h] * _lanes(alpha, hd) + jax.lax.dot_general(
+                p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
 
-    @pl.when(p == pages_per_shard - 1)
-    def _emit_partial():
-        m_ref[0, 0, 0] = m_scr[:]
-        l_ref[0, 0, 0] = l_scr[:]
-        acc_ref[0, 0, 0] = acc_scr[:]
+        _each_head(attend, heads, unroll)
+
+    @pl.when(j == last)
+    def _finalize():
+        l = l_scr[:]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_scr[:] / _lanes(l_safe, hd)).astype(o_ref.dtype)
 
 
-def _fused_walk_page(b, s, p, tables_ref, lens_ref, bs: int, nbp: int,
-                     pages_per_shard: int):
-    """The fused walk's physical-page resolution over length + 1 (the walk
-    must include the append page); sentinel table entries clip to nbp - 1
-    — the caller's SPILL page in fused pools, so an unseated lane's reads
-    can never alias a live slot's write page.  The table column is clamped
-    to the table width like _resolve_page (the kernel-contract bounds
-    rule: j = s*P + p exceeds max_blocks when S*P rounds up, and lens is
-    runtime data).  ONE implementation shared by the payload and scale
-    index maps — a page's codes and its scale can never diverge
-    mid-walk by construction, not by parallel edits."""
-    j = s * pages_per_shard + p
+def _decode_vmem_bytes(heads: int, group: int, hd: int, bs: int, q_dtype,
+                       kv_dtype) -> int:
+    """What ``heads`` KV heads a fused decode grid step hold in VMEM: the
+    prefill kernel's account at ``group`` rows (q and output blocks, the
+    accumulator, m and l, the K and V page) plus the aliased K and V
+    output pages, double-buffered, and the roped q."""
+    kvb = jnp.dtype(kv_dtype).itemsize
+    return heads * (2 * 2 * bs * hd * kvb + group * hd * 4) + (
+        _prefill_vmem_bytes(heads, group, hd, bs, hd, q_dtype, kv_dtype))
+
+
+def _fused_walk_page(b, j, tables_ref, lens_ref, bs: int, nbp: int):
+    """The fused walk's physical-page resolution of logical page ``j`` over
+    length + 1 (the walk must include the append page): a page past the
+    lane's live count names its last live page again (no copy is issued
+    when consecutive steps fetch the same block); sentinel table entries
+    clip to nbp - 1 — the caller's SPILL page in fused pools, so an
+    unseated lane's reads can never alias a live slot's write page.  The
+    table column is clamped to the table width like _resolve_page (the
+    kernel-contract bounds rule: lens is runtime data).  ONE
+    implementation shared by the fp and quant kernels' payload and scale
+    index maps — a page's codes and its scale can never diverge mid-walk
+    by construction, not by parallel edits."""
     n_live = jnp.maximum((lens_ref[b] + 1 + bs - 1) // bs, 1)
     j_eff = jnp.clip(jnp.minimum(j, n_live - 1), 0,
                      tables_ref.shape[1] - 1)
@@ -1752,25 +1829,26 @@ def _fused_walk_page(b, s, p, tables_ref, lens_ref, bs: int, nbp: int,
 
 
 def _fused_page_index_map(bs: int, nbp: int, pages_per_shard: int):
+    # the quant kernel's split-K walk: logical page j = s*P + p
     def idx(b, h, s, p, tables_ref, lens_ref, wblk_ref, wable_ref):
-        return (_fused_walk_page(b, s, p, tables_ref, lens_ref, bs, nbp,
-                                 pages_per_shard), h, 0, 0)
+        return (_fused_walk_page(b, s * pages_per_shard + p, tables_ref,
+                                 lens_ref, bs, nbp), h, 0, 0)
 
     return idx
 
 
-def _fused_small_in_specs(group: int, hd: int):
+def _fused_small_in_specs(group: int, hd: int, heads: int = 1):
     """The five small per-slot operands every fused decode launch streams
-    whole — q group, new k/v rows, cos/sin (shapes: see
-    :func:`_fused_small_operands`).  ONE spec set shared by the fp and
-    quant call builders (like ``_fused_walk_page`` for the page maps): a
-    geometry or clamp fix lands in both by construction."""
-    row = pl.BlockSpec((1, 1, 1, hd),
-                       lambda b, h, s, p, t, l, w, a: (b, h, 0, 0))
-    rope = pl.BlockSpec((1, 1, hd), lambda b, h, s, p, t, l, w, a: (b, 0, 0))
+    whole — q groups, new k/v rows, cos/sin (shapes: see
+    :func:`_fused_small_operands`), for ``heads`` KV heads a grid step.
+    ONE spec set shared by the fp and quant launch wrappers (like
+    ``_fused_walk_page`` for the page maps), whatever their grids' rank
+    (the slot and head block lead both): a geometry or clamp fix lands in
+    both by construction."""
+    row = pl.BlockSpec((1, heads, 1, hd), lambda b, h, *_: (b, h, 0, 0))
+    rope = pl.BlockSpec((1, 1, hd), lambda b, *_: (b, 0, 0))
     return [
-        pl.BlockSpec((1, 1, group, hd),
-                     lambda b, h, s, p, t, l, w, a: (b, h, 0, 0)),
+        pl.BlockSpec((1, heads, group, hd), lambda b, h, *_: (b, h, 0, 0)),
         row, row, rope, rope,
     ]
 
@@ -1786,74 +1864,72 @@ def _fused_small_operands(qg, k_new, v_new, cos, sin):
             cos[:, None, :], sin[:, None, :]]
 
 
-def _fused_partials(b: int, nkv: int, S: int, group: int, hd: int):
-    """Split-K partial plumbing shared by the fused decode call builders:
-    (m, l, acc) out specs, their shapes, and the m/l/acc/roped-q VMEM
-    scratch both kernels park their recurrence in."""
-    part_spec = pl.BlockSpec((1, 1, 1, group, 1),
-                             lambda b, h, s, p, t, l, w, a: (b, h, s, 0, 0))
-    acc_spec = pl.BlockSpec((1, 1, 1, group, hd),
-                            lambda b, h, s, p, t, l, w, a: (b, h, s, 0, 0))
-    out_shapes = [
-        jax.ShapeDtypeStruct((b, nkv, S, group, 1), jnp.float32),
-        jax.ShapeDtypeStruct((b, nkv, S, group, 1), jnp.float32),
-        jax.ShapeDtypeStruct((b, nkv, S, group, hd), jnp.float32),
-    ]
-    scratch = [
-        _VMEM((group, 1), jnp.float32),
-        _VMEM((group, 1), jnp.float32),
-        _VMEM((group, hd), jnp.float32),
-        _VMEM((group, hd), jnp.float32),    # roped q
-    ]
-    return [part_spec, part_spec, acc_spec], out_shapes, scratch
-
-
 def _fused_write_page_map(nbp: int):
     """Index map of the ALIASED pool output, pinned to the slot's write
-    page.  The page id is runtime data: clamp it to the pool like every
-    other data-dependent index — the engine always passes a valid page
-    (own page or spill), but the kernel-contract bounds rule
+    page, for either kernel's grid (the prefetch refs end ``wblk``,
+    ``wable``).  The page id is runtime data: clamp it to the pool like
+    every other data-dependent index — the engine always passes a valid
+    page (own page or spill), but the kernel-contract bounds rule
     (analysis/kernel_contracts.py) requires the map itself to be safe for
     ALL prefetch values, not safe-by-caller-convention."""
-    return lambda b, h, s, p, t, l, w, a: (jnp.clip(w[b], 0, nbp - 1),
-                                           h, 0, 0)
+    def idx(b, h, *rest):
+        wblk_ref = rest[-2]
+        return (jnp.clip(wblk_ref[b], 0, nbp - 1), h, 0, 0)
+
+    return idx
 
 
 def _fused_decode_kernel_call(qg, k_new, v_new, cos, sin, key_cache,
                               value_cache, block_tables, seq_lens,
-                              write_blk, writeable, scale, num_shards):
+                              write_blk, writeable, scale):
     """qg: [b, nkv, group, hd] PRE-rope (group padded to sublane rows);
     k_new/v_new: [b, nkv, hd]; cos/sin: [b, hd]; pools [nbp, nkv, bs, hd].
-    Returns (m, l, acc partials, new key pool, new value pool)."""
+    Returns (out [b, nkv, group, hd], new key pool, new value pool)."""
     b, nkv, group, hd = qg.shape
     nbp, _, bs, _ = key_cache.shape
-    max_blocks = block_tables.shape[1]
-    S = num_shards
-    P = -(-max_blocks // S)                               # pages per shard
+    heads = _heads_that_fit(nkv, _decode_vmem_bytes(
+        1, group, hd, bs, qg.dtype, key_cache.dtype))
+    _, pages = _decode_walk(seq_lens.astype(jnp.int32), bs,
+                            block_tables.shape[1], jnp)
 
     kernel = functools.partial(_fused_decode_kernel, scale=scale, bs=bs,
-                               pages_per_shard=P)
-    kv_spec = pl.BlockSpec((1, 1, bs, hd), _fused_page_index_map(bs, nbp, P))
-    pool_out_spec = pl.BlockSpec((1, 1, bs, hd), _fused_write_page_map(nbp))
-    part_specs, part_shapes, scratch = _fused_partials(b, nkv, S, group, hd)
+                               unroll=_unroll(heads, _DECODE_UNROLL))
+
+    def walk(b, hb, j, tables_ref, lens_ref, wblk_ref, wable_ref):
+        return (_fused_walk_page(b, j, tables_ref, lens_ref, bs, nbp), hb,
+                0, 0)
+
+    kv_spec = pl.BlockSpec((1, heads, bs, hd), walk)
+    pool_out_spec = pl.BlockSpec((1, heads, bs, hd),
+                                 _fused_write_page_map(nbp))
+    small = _fused_small_in_specs(group, hd, heads)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, nkv, S, P),
-        in_specs=_fused_small_in_specs(group, hd) + [kv_spec, kv_spec],
-        out_specs=part_specs + [pool_out_spec, pool_out_spec],
-        scratch_shapes=scratch,
+        grid=(b, nkv // heads, pages),
+        in_specs=small + [kv_spec, kv_spec],
+        out_specs=[small[0], pool_out_spec, pool_out_spec],
+        scratch_shapes=[
+            _VMEM((heads, group, _LANES), jnp.float32),   # m
+            _VMEM((heads, group, _LANES), jnp.float32),   # l
+            _VMEM((heads, group, hd), jnp.float32),       # acc
+            _VMEM((heads, group, hd), jnp.float32),       # roped q
+            _VMEM((heads, 1, hd), key_cache.dtype),       # roped new k
+            _VMEM((heads, 1, hd), value_cache.dtype),     # new v
+        ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=part_shapes + [
+        out_shape=[
+            jax.ShapeDtypeStruct(qg.shape, qg.dtype),
             jax.ShapeDtypeStruct(key_cache.shape, key_cache.dtype),
             jax.ShapeDtypeStruct(value_cache.shape, value_cache.dtype),
         ],
         # pool inputs (global operand indices 9/10: four scalar-prefetch
         # refs then five small operands precede them) alias the pool
         # outputs — the append is in-place, no pool copy materializes
-        input_output_aliases={9: 3, 10: 4},
+        input_output_aliases={9: 1, 10: 2},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         name="fused_decode_attn",
         interpret=interpret_mode(),
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
@@ -1897,9 +1973,9 @@ def fused_decode_step_reference(q, k_new, v_new, cos, sin, key_cache,
 
 def fused_decode_step(q, k_new, v_new, cos, sin, key_cache, value_cache,
                       block_tables, seq_lens, write_blk, writeable,
-                      scale=None, num_shards=None):
-    """Fused RoPE + KV-page append + split-K paged attention for ONE decode
-    token per slot — the serving engine's decode-path megastep stage 1
+                      scale=None):
+    """Fused RoPE + KV-page append + paged attention for ONE decode token
+    per slot — the serving engine's decode-path megastep stage 1
     (docs/paged_attention.md "Fused decode step").
 
     Args:
@@ -1911,9 +1987,12 @@ def fused_decode_step(q, k_new, v_new, cos, sin, key_cache, value_cache,
         pools.  In the serving engine nbp = num_blocks + 1: the last page
         is the SPILL page dropped writes land on (Pallas output index maps
         cannot drop).  kv_quant pools are not supported here — appending
-        would dirty the per-page scale (quant stays on the unfused path).
+        would dirty the per-page scale (:func:`fused_quant_decode_step`).
       block_tables: [b, max_blocks] int32 physical page ids.
-      seq_lens: [b] int32 PRE-append lengths (the append position).
+      seq_lens: [b] int32 PRE-append lengths (the append position), with
+        ``seq_lens + 1 <= max_blocks * block_size``; the longest lane bounds
+        every lane's walk, so a lane that does not write should be given 0
+        (the engine does).
       write_blk: [b] int32 physical append page — the slot's own private
         page for writeable lanes, the spill page otherwise.
       writeable: [b] bool/int32 — 0 drops the append (inactive lane or
@@ -1926,7 +2005,7 @@ def fused_decode_step(q, k_new, v_new, cos, sin, key_cache, value_cache,
     ``PADDLE_TPU_DISABLE_PALLAS=fused_decode_step`` opt-out (or an
     unsupported shape) routes to the unfused reference composition.
     Forward-only: serving never differentiates through the KV cache."""
-    global FUSED_KERNEL_CALLS, FUSED_FALLBACK_CALLS, LAST_FLASH_SHARDS
+    global FUSED_KERNEL_CALLS, FUSED_FALLBACK_CALLS
     b, nh, hd = q.shape
     nbp, nkv, bs, hd_store = key_cache.shape
     assert hd_store == hd, (hd_store, hd)
@@ -1940,22 +2019,14 @@ def fused_decode_step(q, k_new, v_new, cos, sin, key_cache, value_cache,
             seq_lens, write_blk, writeable, scale=scale)
     FUSED_KERNEL_CALLS += 1
 
-    # the fused walk shares the split-K fan-out (S == 1 when flash_decode
-    # is killed: sequential walk, trivially-merged single partial)
-    S = 1
-    if not kernel_disabled("flash_decode"):
-        S = flash_decode_shards(block_tables.shape[1], num_shards)
-    if S > 1:
-        LAST_FLASH_SHARDS = S
     rep = nh // nkv
     group = _round_up(rep, _MIN_GROUP_ROWS)
     qg = q.reshape(b, nkv, rep, hd)
     if group != rep:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, group - rep), (0, 0)))
-    m, l, acc, kc, vc = _fused_decode_kernel_call(
+    out, kc, vc = _fused_decode_kernel_call(
         qg, k_new, v_new, cos, sin, key_cache, value_cache, block_tables,
-        seq_lens, write_blk, writeable, scale, S)
-    out = _flash_combine(m, l, acc).astype(q.dtype)
+        seq_lens, write_blk, writeable, scale)
     return out[:, :, :rep].reshape(b, nh, hd), kc, vc
 
 
@@ -2103,7 +2174,23 @@ def _fused_quant_decode_kernel_call(qg, k_new, v_new, cos, sin, kq, ksc,
     pool_out_spec = pl.BlockSpec((1, 1, bs, hd_store),
                                  _fused_write_page_map(nbp))
     scale_out_spec = _scale_spec(nkv, _fused_write_page_map(nbp))
-    part_specs, part_shapes, scratch = _fused_partials(b, nkv, S, group, hd)
+    # the split-K partials (m, l, acc) the combine pass merges
+    part_spec = pl.BlockSpec((1, 1, 1, group, 1),
+                             lambda b, h, s, p, t, l, w, a: (b, h, s, 0, 0))
+    acc_spec = pl.BlockSpec((1, 1, 1, group, hd),
+                            lambda b, h, s, p, t, l, w, a: (b, h, s, 0, 0))
+    part_specs = [part_spec, part_spec, acc_spec]
+    part_shapes = [
+        jax.ShapeDtypeStruct((b, nkv, S, group, 1), jnp.float32),
+        jax.ShapeDtypeStruct((b, nkv, S, group, 1), jnp.float32),
+        jax.ShapeDtypeStruct((b, nkv, S, group, hd), jnp.float32),
+    ]
+    scratch = [
+        _VMEM((group, 1), jnp.float32),
+        _VMEM((group, 1), jnp.float32),
+        _VMEM((group, hd), jnp.float32),
+        _VMEM((group, hd), jnp.float32),        # roped q
+    ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(b, nkv, S, P),

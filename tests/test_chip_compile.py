@@ -22,6 +22,7 @@ module imported.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -175,6 +176,35 @@ def test_fused_decode_step(one_chip, compiled_kernels, tp):
              ((B, nkv, HD), BF16), ((B, nkv, HD), BF16), ((B, HD), BF16),
              ((B, HD), BF16), _pool(nkv=nkv), _pool(nkv=nkv), _TABLES, _LENS,
              _LENS, _LENS)
+
+
+@pytest.mark.parametrize("b,nh,nkv,pages,max_blocks", [
+    (32, 32, 8, 513, 64), (32, 30, 30, 4 * 769, 64),
+    (128, 20, 1, 2 * 12_289, 128), (32, 8, 2, 513, 64)],
+    ids=["mistral7b", "olmo_hybrid", "jamba2", "mistral7b_tp4"])
+def test_fused_decode_at_the_cells_shapes(one_chip, compiled_kernels, b, nh,
+                                          nkv, pages, max_blocks):
+    """The decode step's launch as the serving cells make it (the hybrid
+    models hand it their full layers' pools as one flat array): a page
+    with all its KV heads a grid step (8, 30, 1, and 2 under TP = 4)
+    within the module's VMEM budget, at most eight heads side by side,
+    and the page axis's extent run-time data — one program for every
+    length."""
+    group = -(-(nh // nkv) // 8) * 8
+    per_head = pa._decode_vmem_bytes(1, group, HD, BS, BF16, BF16)
+    assert pa._heads_that_fit(nkv, per_head) == nkv
+    assert nkv * per_head <= pa._PREFILL_VMEM_BUDGET < pa._VMEM_LIMIT
+    assert pa._unroll(nkv, pa._DECODE_UNROLL) == {8: 8, 30: 6, 1: 1,
+                                                   2: 2}[nkv]
+    pool = ((pages, nkv, BS, HD), BF16)
+    compiled = _compile(
+        pa.fused_decode_step, one_chip, ((b, nh, HD), BF16),
+        ((b, nkv, HD), BF16), ((b, nkv, HD), BF16), ((b, HD), BF16),
+        ((b, HD), BF16), pool, pool, ((b, max_blocks), jnp.int32),
+        ((b,), jnp.int32), ((b,), jnp.int32), ((b,), jnp.bool_))
+    launches = re.findall(r"%fused_decode_attn[.\w]* = .*custom-call",
+                          compiled.as_text())
+    assert len(launches) == 1
 
 
 @pytest.mark.parametrize("kv_quant", ["int8", "int4"])

@@ -175,44 +175,76 @@ def _fused_case(rs, *, lens, nbl=12, nkv=2, bs=8, hd=16, nh=4, mb=6):
             jnp.asarray(wable, jnp.int32))
 
 
-@pytest.mark.parametrize("shards", [None, 1, 3])
-def test_fused_step_matches_reference(shards):
+#: (num_heads, num_kv_heads) of the models that serve through the fused
+#: step: Mistral's rep 4 over 8 KV heads, Olmo-Hybrid's rep 1 over 30,
+#: Jamba's rep 20 over one (its q group padded to 24 rows)
+_HEADS = {"rep4_nkv8": (32, 8), "rep1_nkv30": (30, 30), "rep20_nkv1": (20, 1)}
+
+#: lane populations of one launch (lens None: an inactive lane, which
+#: writes nothing and is handed length 0), at 8-position pages and a
+#: 6-page table
+_LANES = {
+    # the longest lane ends mid-page (19: page 2 of 3), a fresh-page
+    # append (8: offset 0 of page 1) walks one dead step, a dropped lane
+    "mid_page": [19, 8, None],
+    # a lane at the table's last position (47: page 5 of 6, the walk's
+    # bound is max_blocks), a lane at position 0, and two inactive lanes
+    "full_table": [47, 0, None, 3, None],
+    # nothing writes: the walk is one page and the spill page takes zeros
+    "all_dropped": [None, None],
+}
+
+
+@pytest.mark.parametrize("lanes", list(_LANES))
+@pytest.mark.parametrize("heads", list(_HEADS))
+def test_fused_step_matches_reference(heads, lanes):
     """Fused rope+append+attend vs the unfused reference composition:
-    outputs match on active lanes, the appended row lands (k roped, v raw),
-    untouched pages are byte-preserved, and dropped lanes write nothing
-    into the allocator's range.  Covers a mid-page append, a fresh-page
-    (offset 0) append, and an inactive lane in one launch."""
+    outputs match on writeable lanes, the appended row lands (k roped, v
+    raw), untouched pages are byte-preserved, and dropped lanes write
+    nothing into the allocator's range but zeros on the spill page.  The
+    walk takes a page with all its KV heads a grid step and ends at the
+    launch's longest lane, whatever the head count and grouping."""
+    nh, nkv = _HEADS[heads]
     rs = np.random.RandomState(5)
-    case = _fused_case(rs, lens=[19, 8, None])
+    case = _fused_case(rs, lens=_LANES[lanes], nbl=16, nkv=nkv, nh=nh)
     (q, kn, vn, cos, sin, kc, vc, tables, lens, wblk, wable) = case
     o_ref, kc_ref, vc_ref = pa.fused_decode_step_reference(*case)
+    pa.reset_kernel_counters()
     o, kc2, vc2 = da.fused_paged_decode_step(q, kn, vn, cos, sin, kc, vc,
-                                             tables, lens, wblk, wable,
-                                             num_shards=shards)
+                                             tables, lens, wblk, wable)
+    assert pa.FUSED_KERNEL_CALLS == 1
     nbl = kc.shape[0] - 1
     act = np.asarray(wable).astype(bool)
     np.testing.assert_allclose(np.asarray(o)[act], np.asarray(o_ref)[act],
                                atol=1e-5)
+    assert np.isfinite(np.asarray(o)).all()
     # every REAL page matches the scatter path byte-for-byte except the
     # appended rows, which match at rope-math tolerance
     np.testing.assert_allclose(np.asarray(kc2)[:nbl],
                                np.asarray(kc_ref)[:nbl], atol=1e-5)
     np.testing.assert_allclose(np.asarray(vc2)[:nbl],
                                np.asarray(vc_ref)[:nbl], atol=1e-5)
-    # the appended v row is the RAW v (no rope), exactly
-    b0_page, b0_off = int(wblk[0]), int(lens[0]) % kc.shape[2]
-    np.testing.assert_allclose(np.asarray(vc2)[b0_page, :, b0_off],
-                               np.asarray(vn)[0], atol=1e-6)
+    if not act.all():
+        # a dropped write lands on the spill page, as zeros
+        assert not np.asarray(kc2)[nbl].any()
+        assert not np.asarray(vc2)[nbl].any()
+    for b in np.flatnonzero(act):
+        # the appended v row is the RAW v (no rope), exactly
+        page, off = int(wblk[b]), int(lens[b]) % kc.shape[2]
+        np.testing.assert_allclose(np.asarray(vc2)[page, :, off],
+                                   np.asarray(vn)[b], atol=1e-6)
 
 
-def test_fused_step_bf16_rope_matches_reference():
+@pytest.mark.parametrize("heads", list(_HEADS))
+def test_fused_step_bf16_rope_matches_reference(heads):
     """bf16 operands (the production pool dtype): the kernel ropes in the
     INPUT dtype and rounds the appended row through the pool dtype, so the
     committed page must EXACTLY equal the reference's scatter bytes and
     the output must match at bf16 tolerance — the near-tied-argmax guard
     behind the engine-level token-identity assertion."""
+    nh, nkv = _HEADS[heads]
     rs = np.random.RandomState(8)
-    case = _fused_case(rs, lens=[19, 8])
+    case = _fused_case(rs, lens=[19, 8], nkv=nkv, nh=nh)
     bf = lambda x: x.astype(jnp.bfloat16)
     q, kn, vn, cos, sin, kc, vc, tables, lens, wblk, wable = case
     case16 = (bf(q), bf(kn), bf(vn), bf(cos), bf(sin), bf(kc), bf(vc),
@@ -249,6 +281,98 @@ def test_fused_step_kill_switch_and_fallback(monkeypatch):
     o_ref, kc_ref, vc_ref = pa.fused_decode_step_reference(*case)
     assert jnp.array_equal(o, o_ref)
     assert jnp.array_equal(kc2, kc_ref)
+
+
+@pytest.mark.parametrize("lens,live,counted", [
+    # lanes of 20, 9, 1 positions after their append: 3, 2 and 1 pages
+    # live, a lane that does not write (length 0) walks along unlive
+    ([19, 8, 0, 0], [True, True, False, True], (3 + 2 + 1, 4 * 3)),
+    # a lane at the table's last position bounds the walk at max_blocks
+    ([47, 3], [True, True], (6 + 1, 2 * 6)),
+    # nothing live: a one-page walk that holds no live page
+    ([0, 0], [False, False], (0, 2)),
+], ids=["mid_page", "full_table", "none_live"])
+def test_decode_census_counts_what_the_walk_visits(lens, live, counted):
+    """``decode_census`` counts (lane, page) steps by the kernel's own
+    predicates: a lane's live pages ``cdiv(len + 1, bs)``, and every lane
+    walking to the launch's longest lane — the bound the kernel's wrapper
+    hands ``pallas_call`` as the page axis's extent."""
+    got = pa.decode_census(lens, 8, max_blocks=6, live=live)
+    assert (got["pages_live"], got["pages_walked"]) == counted
+    _, bound = pa._decode_walk(jnp.asarray(lens, jnp.int32), 8, 6, jnp)
+    assert int(bound) * len(lens) == got["pages_walked"]
+
+
+def test_fused_step_page_axis_is_the_launchs_bound():
+    """The fused kernel's grid is (slots, head blocks, pages) with the
+    page extent run-time data, so one compiled launch serves every
+    length; all KV heads of a page ride one grid step here."""
+    from jax._src.pallas import core as pallas_core
+
+    rs = np.random.RandomState(3)
+    case = _fused_case(rs, lens=[19, 8, None], nkv=8, nh=32)
+    closed = jax.make_jaxpr(da.fused_paged_decode_step)(*case)
+    (eqn,) = [e for e in closed.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert eqn.params["name"] == "fused_decode_attn"
+    grid = eqn.params["grid_mapping"].grid
+    assert grid[:2] == (3, 1)
+    assert grid[2] is pallas_core.dynamic_grid_dim
+
+
+def test_engine_builds_one_decode_program_per_sampling_mode(monkeypatch):
+    """Greedy and sampled requests of many lengths through the dense paged
+    engine compile ONE decode program per sampling mode (the walk's bound
+    is data, never a variant per length), the decode steps count what the
+    kernel walked, and the decode program lowered for the TPU holds
+    exactly one ``fused_decode_attn`` launch (the layers are one scan
+    body)."""
+    from paddle_tpu.analysis import n_traces
+    from paddle_tpu.ops.pallas import rms_norm
+
+    monkeypatch.delenv("PADDLE_TPU_DISABLE_PALLAS", raising=False)
+    cfg = _tiny_cfg()
+    params = llama.init_params(cfg, jax.random.key(0))
+    kw = dict(max_batch=3, max_seq=64, paged=True, block_size=8,
+              enable_chunked_prefill=True, prefill_chunk=8)
+    eng = ContinuousBatchingEngine(cfg, params, **kw)
+    rs = np.random.RandomState(4)
+    eng.serve([Request(rid=i, max_new_tokens=n_new,
+                       prompt_ids=rs.randint(1, 128, n).astype(np.int32),
+                       temperature=0.0 if i % 2 == 0 else 0.8, seed=7 + i)
+               for i, (n, n_new) in enumerate(
+                   ((3, 20), (30, 12), (12, 28), (41, 9), (5, 17)))])
+    assert eng.stats["decode_steps"] > eng.stats["mixed_steps"] > 0
+    assert n_traces(eng._decode_greedy) == 1
+    assert n_traces(eng._decode_sampling) == 1
+    st = eng.stats
+    assert 0 < st["decode_attn_pages_live"] <= st["decode_attn_pages_walked"]
+
+    # a fresh engine, so the program is traced for the chip's kernels
+    fresh = ContinuousBatchingEngine(cfg, params, **kw)
+    for mod in (pa, rms_norm):
+        monkeypatch.setattr(mod, "interpret_mode", lambda: False)
+    B = kw["max_batch"]
+    i32 = jnp.zeros(B, jnp.int32)
+    lowered = fresh._decode_greedy.trace(
+        params, fresh.cache_k, fresh.cache_v, i32, i32, jnp.ones(B, bool),
+        jnp.zeros(B), jnp.ones(B), i32, jnp.asarray(fresh._table),
+        poison=jnp.zeros(B, bool)).lower(lowering_platforms=("tpu",))
+    assert lowered.as_text().count("fused_decode_attn") == 1
+
+
+def test_engine_off_the_fused_kernel_counts_no_walk(monkeypatch):
+    """An engine whose decode step does not run the fused fp kernel (here
+    killed by its switch) adds nothing to the two walk counters."""
+    monkeypatch.setenv("PADDLE_TPU_DISABLE_PALLAS", "fused_decode_step")
+    cfg = _tiny_cfg()
+    params = llama.init_params(cfg, jax.random.key(0))
+    eng = ContinuousBatchingEngine(cfg, params, max_batch=2, max_seq=64,
+                                   paged=True, block_size=8)
+    eng.serve([Request(rid=0, prompt_ids=np.arange(1, 9, dtype=np.int32),
+                       max_new_tokens=6)])
+    assert not eng._fused and eng.stats["decode_steps"] > 0
+    assert eng.stats["decode_attn_pages_walked"] == 0
+    assert eng.stats["decode_attn_pages_live"] == 0
 
 
 # ---------------------------------------------------------------------------

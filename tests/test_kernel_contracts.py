@@ -408,8 +408,8 @@ def test_fused_kernel_alias_overlap_detected_and_allowlisted():
     wblk = jnp.zeros((2,), jnp.int32)
     wable = jnp.ones((2,), jnp.int32)
     closed = jax.make_jaxpr(lambda *a: pa._fused_decode_kernel_call(
-        *a, scale=1.0, num_shards=2))(q, k_new, k_new, cos, cos, kc, vc,
-                                      tbl, lens, wblk, wable)
+        *a, scale=1.0))(q, k_new, k_new, cos, cos, kc, vc, tbl, lens, wblk,
+                        wable)
     findings, sections = check_kernel_contracts(closed)
     gating = [f for f in findings if f.severity != Severity.INFO]
     # exactly the two deliberate alias overlaps (k and v pool) — bounds

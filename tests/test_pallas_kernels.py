@@ -1157,6 +1157,48 @@ def test_fused_decode_at_one_kv_head_and_rep_20():
         np.testing.assert_array_equal(np.asarray(v2)[p], np.asarray(v_r)[p])
 
 
+def test_fused_decode_head_blocks_when_a_page_outgrows_vmem():
+    """A page whose KV heads do not all fit one grid step's VMEM budget
+    (64 heads of 128 in float32 at 64-position pages) is taken in blocks
+    of heads — the grid's second axis — each block walking and appending
+    its own heads; the result is the reference's on every writeable lane,
+    and the pools' real pages are the reference's."""
+    rs = np.random.RandomState(29)
+    nkv, nh, hd, bs, mb, nbl = 64, 128, 128, 64, 3, 6
+    heads = pa._heads_that_fit(nkv, pa._decode_vmem_bytes(
+        1, 8, hd, bs, jnp.float32, jnp.float32))
+    assert 1 < heads < nkv
+    lens = [130, 5, None]               # 3 pages (mid-page), 1, dropped
+    B = len(lens)
+    tables = np.full((B, mb), nbl, np.int32)
+    pool = list(rs.permutation(nbl))
+    wblk, wable, lens_i = [], [], []
+    for b, ln in enumerate(lens):
+        if ln is None:
+            wblk.append(nbl), wable.append(0), lens_i.append(0)
+            continue
+        pages = [pool.pop() for _ in range(ln // bs + 1)]
+        tables[b, :len(pages)] = pages
+        wblk.append(pages[-1]), wable.append(1), lens_i.append(ln)
+    f32 = lambda *shape: jnp.asarray(rs.randn(*shape), jnp.float32)
+    case = (f32(B, nh, hd), f32(B, nkv, hd), f32(B, nkv, hd), f32(B, hd),
+            f32(B, hd), f32(nbl + 1, nkv, bs, hd), f32(nbl + 1, nkv, bs, hd),
+            jnp.asarray(tables), jnp.asarray(lens_i, jnp.int32),
+            jnp.asarray(wblk, jnp.int32), jnp.asarray(wable, jnp.int32))
+    pa.reset_kernel_counters()
+    out, k2, v2 = pa.fused_decode_step(*case)
+    assert pa.FUSED_KERNEL_CALLS == 1
+    ref, k_r, v_r = pa.fused_decode_step_reference(*case)
+    live = np.asarray(wable).astype(bool)
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(ref)[live],
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(k2)[:nbl], np.asarray(k_r)[:nbl],
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(v2)[:nbl], np.asarray(v_r)[:nbl],
+                               atol=1e-5)
+    assert not np.asarray(k2)[nbl].any() and not np.asarray(v2)[nbl].any()
+
+
 # ---------------------------------------------------------------------------
 # requantized KV append (decode megastep stage 2 — ISSUE 15,
 # docs/paged_attention.md "Megastep stage 2")
